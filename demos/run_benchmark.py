@@ -22,13 +22,9 @@ def main() -> None:
     parser.add_argument("--platform", default=None, choices=["cpu", "tpu"])
     args = parser.parse_args()
 
-    # One owner for the platform write: route the flag through the env and
-    # the shared guarded helper (already-initialized backends tolerated).
+    # The flag only sets the variable JAX reads itself, before jax loads.
     if args.platform:
         os.environ["JAX_PLATFORMS"] = args.platform
-    from __graft_entry__ import _honor_platform_env
-
-    _honor_platform_env()
 
     import matplotlib
 

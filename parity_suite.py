@@ -191,13 +191,8 @@ def main() -> None:
         )
     sys.path.insert(0, REF_PATH)
 
-    import jax
-
-    if args.platform:
-        try:
-            jax.config.update("jax_platforms", args.platform)
-        except Exception:
-            pass
+    if args.platform:  # the variable JAX reads itself, before jax loads
+        os.environ["JAX_PLATFORMS"] = args.platform
 
     from vizier_tpu import benchmarks
     from vizier_tpu import pyvizier as vz

@@ -14,6 +14,7 @@ from __future__ import annotations
 
 import argparse
 import json
+import os
 import time
 
 import numpy as np
@@ -39,15 +40,13 @@ def main() -> None:
         "--platform",
         default=None,
         choices=["cpu", "tpu"],
-        help="Pin the JAX platform (use 'cpu' for smoke runs on machines "
-        "whose ambient TPU plugin would otherwise be picked up).",
+        help="Pin the JAX platform (sets JAX_PLATFORMS before jax loads; "
+        "use 'cpu' for smoke runs on a machine with an accelerator).",
     )
     args = parser.parse_args()
     s = args.scale
     if args.platform:
-        import jax
-
-        jax.config.update("jax_platforms", args.platform)
+        os.environ["JAX_PLATFORMS"] = args.platform
 
     from vizier_tpu import benchmarks
     from vizier_tpu import pyvizier as vz

@@ -62,10 +62,10 @@ class TestRealTree:
         # traffic-engine knobs, the 11 VIZIER_ADMISSION*
         # overload-protection knobs, the 4 VIZIER_COMPUTE_TIER*
         # disaggregated-compute knobs, and the VIZIER_NETCHAOS fault
-        # schedule) + 3 bench switches + the 2 reserved grpc constants.
+        # schedule) + 2 bench switches + the 2 reserved grpc constants.
         # Growing the tree means growing this registry.
-        assert len(registry.SWITCHES) == 87
-        assert len(registry.env_switch_names()) == 85
+        assert len(registry.SWITCHES) == 86
+        assert len(registry.env_switch_names()) == 84
 
     def test_known_switches_declared(self):
         for name in (

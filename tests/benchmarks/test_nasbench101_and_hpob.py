@@ -1,4 +1,4 @@
-"""NASBench-101 graph encoding + real HPO-B v3 layout (VERDICT r3 #3/#4).
+"""NASBench-101 graph encoding + real HPO-B v3 layout.
 
 Both are data-gated in production; these tests drive the encoding/parsing
 logic on synthetic fixtures: the NASBench-101 trial→spec→prune→hash path

@@ -1,0 +1,273 @@
+"""The main path's device programs compile for a TPU v5e, without the chip.
+
+The TPU compiler is installed in the sandbox and compiles for a chip that
+is described, not attached (`on-chip-measurement` guide, section 2.3). The
+programs are obtained the way the batch executor obtains them —
+``compute.registry.resolve`` → ``prepare`` → ``device_program`` — with the
+jitted flush program intercepted at its call, so the lowered arguments are
+exactly the executor's, at the reference's default widths: 20-D,
+``suggest(25)``, 75,000 acquisition evaluations, 4 ARD restarts × maxiter
+50, padded to the executor's batch of 8.
+
+A compile that passes is not a chip run: nothing executes, so this says
+nothing about results or times. ``chip_smoke.py`` is the chip run.
+
+The topology is described inside a module-scoped fixture and nowhere else:
+only one process may hold the TPU library, so nothing here touches
+``topologies`` at import, in a ``skipif`` or in ``parametrize``; every such
+test lives in this one file (about two minutes of compiling in all). Left
+out for that budget: the exact flush program at pad 1024 (~100 s), which
+the service does not reach with the sparse switch at 512.
+"""
+
+from __future__ import annotations
+
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from vizier_tpu import pyvizier as vz
+from vizier_tpu.algorithms import core as core_lib
+from vizier_tpu.compute import registry as compute_registry
+from vizier_tpu.designers import gp_bandit
+from vizier_tpu.designers import gp_ucb_pe
+from vizier_tpu.designers.gp import acquisitions
+from vizier_tpu.models import gp as gp_lib
+from vizier_tpu.models import kernels
+from vizier_tpu.surrogates import config as surrogate_config_lib
+
+DIM = 20
+COUNT = 25
+BATCH = 8  # ServingConfig.batch_max_size with batch_pad_partial
+HBM_BYTES = 16 * 1024**3
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler here: nothing to assert
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module", autouse=True)
+def _no_persistent_cache():
+    """A compile for a described chip is written to the persistent cache
+    but cannot be read back without one; keep the cache off around these."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    before = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", before)
+    compilation_cache.reset_cache()
+
+
+def _designer(num_trials: int, surrogate=None) -> gp_ucb_pe.VizierGPUCBPEBandit:
+    """The service DEFAULT at its shipped budget over a 20-D study."""
+    problem = vz.ProblemStatement()
+    for d in range(DIM):
+        problem.search_space.root.add_float_param(f"x{d}", 0.0, 1.0)
+    problem.metric_information.append(
+        vz.MetricInformation(name="obj", goal=vz.ObjectiveMetricGoal.MAXIMIZE)
+    )
+    designer = gp_ucb_pe.VizierGPUCBPEBandit(
+        problem, use_mesh=False, surrogate=surrogate
+    )
+    rng = np.random.default_rng(0)
+    x = rng.uniform(size=(num_trials, DIM))
+    y = -np.sum((x - 0.5) ** 2, axis=1)
+    trials = []
+    for i in range(num_trials):
+        t = vz.Trial(
+            id=i + 1, parameters={f"x{d}": float(x[i, d]) for d in range(DIM)}
+        )
+        t.complete(vz.Measurement(metrics={"obj": float(y[i])}))
+        trials.append(t)
+    designer.update(core_lib.CompletedTrials(trials))
+    return designer
+
+
+def _as_shapes(tree, sharding):
+    """Array leaves become shapes on ``sharding``; anything else — a model,
+    an optimizer, a Python scalar: the jit statics — passes through."""
+
+    def leaf(a):
+        if isinstance(a, (np.ndarray, np.generic, jax.Array, jax.ShapeDtypeStruct)):
+            return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding)
+        return a
+
+    return jax.tree_util.tree_map(leaf, tree)
+
+
+class _Captured(Exception):
+    pass
+
+
+def _lower_flush(monkeypatch, designer, flush_name: str, sharding):
+    """Lowers the flush program ``device_program`` would have dispatched."""
+    program, key = compute_registry.resolve(designer, COUNT)
+    item = program.prepare(designer, COUNT)
+    jitted = getattr(gp_ucb_pe, flush_name)
+    seen = {}
+
+    def capture(*args):
+        seen["args"] = args
+        raise _Captured
+
+    monkeypatch.setattr(gp_ucb_pe, flush_name, capture)
+    with pytest.raises(_Captured):
+        program.device_program([item], pad_to=BATCH)
+    monkeypatch.undo()
+    return key, jitted.lower(*_as_shapes(seen["args"], sharding))
+
+
+def _fits(compiled) -> None:
+    """The program's temporaries, arguments and outputs fit one v5e's HBM."""
+    mem = compiled.memory_analysis()
+    total = (
+        mem.temp_size_in_bytes
+        + mem.argument_size_in_bytes
+        + mem.output_size_in_bytes
+    )
+    print(
+        f"temp {mem.temp_size_in_bytes / 1e6:.1f} MB, arguments "
+        f"{mem.argument_size_in_bytes / 1e6:.1f} MB, outputs "
+        f"{mem.output_size_in_bytes / 1e6:.1f} MB"
+    )
+    assert total < HBM_BYTES, mem
+
+
+def test_exact_flush_pad512_compiles(monkeypatch, one_chip):
+    designer = _designer(400)
+    key, lowered = _lower_flush(
+        monkeypatch, designer, "_ucb_pe_flush_program", one_chip
+    )
+    assert key.kind == "gp_ucb_pe" and key.pad_trials == 512
+    assert designer._vec_opt.max_evaluations == 75_000
+    _fits(lowered.compile())
+
+
+def test_sparse_flush_pad1024_compiles(monkeypatch, one_chip):
+    # A bare designer has surrogate=None and would resolve 1,000 trials to
+    # the exact pad-1024 program; serving threads its SurrogateConfig in.
+    designer = _designer(1000, surrogate=surrogate_config_lib.SurrogateConfig())
+    key, lowered = _lower_flush(
+        monkeypatch, designer, "_sparse_ucb_pe_flush_program", one_chip
+    )
+    assert key.kind == "gp_ucb_pe_sparse" and key.pad_trials == 1024
+    assert designer._sparse_model().num_inducing == 128
+    _fits(lowered.compile())
+
+
+def _gp_state_shapes(designer, n_pad: int, sharding):
+    """Shapes of an [E=1] trained ensemble at ``n_pad`` rows, no training."""
+    model = designer._model
+    data = jax.eval_shape(
+        lambda: gp_lib.GPData(
+            continuous=jnp.zeros((n_pad, DIM), jnp.float32),
+            categorical=jnp.zeros((n_pad, 0), jnp.int32),
+            labels=jnp.zeros((n_pad,), jnp.float32),
+            row_mask=jnp.ones((n_pad,), bool),
+            cont_dim_mask=jnp.ones((DIM,), bool),
+            cat_dim_mask=jnp.ones((0,), bool),
+        )
+    )
+    params = jax.eval_shape(
+        lambda: model.param_collection().random_init_unconstrained(
+            jax.random.PRNGKey(0)
+        )
+    )
+    states = jax.eval_shape(
+        lambda p, d: jax.vmap(lambda q: model.precompute(q, d))(
+            jax.tree_util.tree_map(lambda a: a[None], p)
+        ),
+        params,
+        data,
+    )
+    return _as_shapes(data, sharding), _as_shapes(states, sharding)
+
+
+def test_ard_train_1024_compiles(one_chip):
+    """``_train_gp``: 4 restarts × L-BFGS maxiter 50 at 1024×20, warm row."""
+    designer = _designer(1)
+    model = designer._model
+    data, _ = _gp_state_shapes(designer, 1024, one_chip)
+    key = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=one_chip)
+    warm = _as_shapes(
+        jax.eval_shape(
+            lambda: model.param_collection().random_init_unconstrained(
+                jax.random.PRNGKey(0)
+            )
+        ),
+        one_chip,
+    )
+    assert (designer.ard_restarts, designer._ard.maxiter) == (4, 50)
+    lowered = gp_bandit._train_gp.lower(
+        model, designer._ard, data, key, designer.ard_restarts, 1, warm
+    )
+    _fits(lowered.compile())
+
+
+def test_sweep_75k_evaluations_compiles(one_chip):
+    """``_maximize_acquisition``: the 75,000-evaluation Eagle sweep over a
+    pad-1024 posterior, UCB + trust region, 25 candidates."""
+    designer = _designer(1)
+    data, states = _gp_state_shapes(designer, 1024, one_chip)
+    key = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=one_chip)
+    prior = kernels.MixedFeatures(
+        jax.ShapeDtypeStruct((10, DIM), jnp.float32, sharding=one_chip),
+        jax.ShapeDtypeStruct((10, 0), jnp.int32, sharding=one_chip),
+    )
+
+    def sweep(states, data, key, prior):
+        scoring = acquisitions.ScoringFunction(
+            predictive=gp_lib.EnsemblePredictive(states),
+            acquisition=acquisitions.UCB(1.8),
+            best_label=jnp.max(data.labels),
+            trust_region=acquisitions.TrustRegion.from_data(data),
+        )
+        return gp_bandit._maximize_acquisition(
+            designer._vec_opt, scoring, key, COUNT, prior
+        )
+
+    _fits(jax.jit(sweep).lower(states, data, key, prior).compile())
+
+
+def test_posterior_ucb_forward_compiles(one_chip):
+    """Precompute (Cholesky + L⁻¹) → posterior → UCB at 1024×20, 256 queries
+    — the forward step ``chip_smoke.py`` checks against float64."""
+    designer = _designer(1)
+    model = designer._model
+    data, _ = _gp_state_shapes(designer, 1024, one_chip)
+    params = _as_shapes(
+        jax.eval_shape(
+            lambda: model.param_collection().random_init_unconstrained(
+                jax.random.PRNGKey(0)
+            )
+        ),
+        one_chip,
+    )
+    query = kernels.MixedFeatures(
+        jax.ShapeDtypeStruct((256, DIM), jnp.float32, sharding=one_chip),
+        jax.ShapeDtypeStruct((256, 0), jnp.int32, sharding=one_chip),
+    )
+
+    def forward(params, data, query):
+        mean, stddev = model.precompute(params, data).predict(query)
+        return acquisitions.UCB(1.8)(mean, stddev, jnp.max(data.labels))
+
+    _fits(jax.jit(forward).lower(params, data, query).compile())

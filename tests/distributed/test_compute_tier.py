@@ -390,6 +390,8 @@ class TestSharedComputeFleet:
             lease_timeout_s=1.0,
             heartbeat_interval_s=0.1,
             compute_tier=True,
+            # The compute server inherits its platform; tests pin the CPU.
+            child_env={"JAX_PLATFORMS": "cpu"},
         )
         try:
             assert fleet.has_compute_tier()

@@ -267,3 +267,61 @@ class TestGracefulShutdown:
                     proc.kill()
             for endpoint in endpoints:
                 grpc_stubs.close_channel(endpoint)
+
+
+class TestOneProcessOwnsTheChip:
+    """Which child may take the accelerator: none of the frontends, and the
+    compute server by inheritance (``child_env`` pins it for tests)."""
+
+    @pytest.mark.parametrize(
+        "child_env, want_frontend, want_compute",
+        [
+            ({}, "cpu", "tpu"),
+            ({"JAX_PLATFORMS": "cpu"}, "cpu", "cpu"),
+        ],
+    )
+    def test_platform_of_each_child(
+        self, monkeypatch, tmp_path, child_env, want_frontend, want_compute
+    ):
+        fleet = object.__new__(subprocess_fleet.SubprocessReplicaManager)
+        fleet.config = subprocess_fleet.config_lib.DistributedConfig()
+        fleet._wal_root = str(tmp_path)
+        fleet._child_env = child_env
+        fleet._obs_dump_dir = ""
+        fleet._compute = None
+        fleet._peers_arg = "replica-0=localhost:1"
+        spawned = {}
+
+        def fake_popen(args, **kwargs):
+            spawned[args[2]] = kwargs["env"]
+
+        monkeypatch.setattr(subprocess_fleet.subprocess, "Popen", fake_popen)
+        monkeypatch.setenv("JAX_PLATFORMS", "tpu")  # a machine with a chip
+        rec = subprocess_fleet._ReplicaProcess(
+            "replica-0", 1, str(tmp_path / "replica-0")
+        )
+        fleet._spawn(rec, epoch=1)
+        fleet._spawn_compute(rec)
+        assert (
+            spawned["vizier_tpu.distributed.replica_main"]["JAX_PLATFORMS"]
+            == want_frontend
+        )
+        assert (
+            spawned["vizier_tpu.distributed.pythia_server_main"]["JAX_PLATFORMS"]
+            == want_compute
+        )
+
+    def test_manager_import_initializes_no_backend(self):
+        """The manager parent must stay off JAX's backends, or it would hold
+        the chip its compute server needs."""
+        code = (
+            "import vizier_tpu.distributed.subprocess_fleet\n"
+            "from jax._src import xla_bridge\n"
+            "assert not xla_bridge.backends_are_initialized()\n"
+        )
+        subprocess.run(
+            [sys.executable, "-c", code],
+            check=True,
+            env={**os.environ, "JAX_PLATFORMS": "cpu"},
+            timeout=120,
+        )

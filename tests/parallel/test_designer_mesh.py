@@ -1,4 +1,4 @@
-"""The sharded data plane IS the production designer path (VERDICT r1 #2).
+"""The sharded data plane IS the production designer path.
 
 Asserts that designers auto-build a mesh, route ARD restarts + acquisition
 pools through ``vizier_tpu.parallel``, and that an 8-device mesh suggest()

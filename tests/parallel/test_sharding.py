@@ -133,3 +133,21 @@ class TestMultihostInit:
             jax.random.PRNGKey(0), num_restarts=8, ensemble_size=1, mesh=mesh,
         )
         assert np.isfinite(np.asarray(states.chol)).all()
+
+    def test_one_host_never_touches_the_distributed_runtime(self, monkeypatch):
+        def boom(*args, **kwargs):
+            raise AssertionError("jax.distributed.initialize called on one host")
+
+        monkeypatch.setattr(jax.distributed, "initialize", boom)
+        mesh = parallel.initialize_multihost()
+        assert len(mesh.devices.flat) == len(jax.devices())
+
+    def test_a_failing_cluster_join_propagates(self, monkeypatch):
+        def refuse(**kwargs):
+            raise RuntimeError(f"no coordinator at {kwargs['coordinator_address']}")
+
+        monkeypatch.setattr(jax.distributed, "initialize", refuse)
+        with pytest.raises(RuntimeError, match="no coordinator at nowhere:1"):
+            parallel.initialize_multihost(
+                coordinator_address="nowhere:1", num_processes=2, process_id=0
+            )

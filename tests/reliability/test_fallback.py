@@ -94,6 +94,24 @@ class TestAlternateFailingDesignerPolicyPath:
         assert stats["designer_failures"] == 4
         assert stats["fallbacks"] == 4
 
+    def test_fallback_entry_names_the_exception_it_degraded_around(self):
+        """A chip-side compile error or OOM must stay legible behind the
+        counter: the flight-recorder entry carries its type and message."""
+        from vizier_tpu.observability import flight_recorder as recorder_lib
+
+        recorder = recorder_lib.FlightRecorder()
+        previous = recorder_lib.set_recorder(recorder)
+        try:
+            servicer, pythia, client = self._stack(ReliabilityConfig(breaker=False))
+            (trial,) = client.get_suggestions(1)
+        finally:
+            recorder_lib.set_recorder(previous)
+        assert is_fallback_suggestion(trial.metadata)
+        (event,) = recorder.events(kind="fallback")
+        assert event["attributes"]["reason"] == "designer_error:FailedSuggestError"
+        assert event["attributes"]["error_type"] == "FailedSuggestError"
+        assert "AlternateFailingDesigner" in event["attributes"]["error_message"]
+
     def test_cached_designer_alternates_through_fallback(self):
         """With a cached (stateful) designer the failures really alternate."""
         from vizier_tpu.designers import random as random_designer

@@ -1,5 +1,6 @@
 """Serving-path batching: config knobs, executor routing, compile cache."""
 
+import os
 import threading
 
 import jax
@@ -7,6 +8,7 @@ import pytest
 
 from vizier_tpu import pyvizier as vz
 from vizier_tpu.serving import ServingConfig, ServingRuntime
+from vizier_tpu.serving import compile_cache
 from vizier_tpu.service import proto_converters as pc
 from vizier_tpu.service import pythia_service, vizier_service
 from vizier_tpu.service.protos import study_pb2, vizier_service_pb2
@@ -128,24 +130,64 @@ class TestConfigKnobs:
 
 
 class TestCompilationCacheWiring:
+    @pytest.fixture(autouse=True)
+    def _restore_jax_cache_config(self, monkeypatch):
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        before = (
+            jax.config.jax_compilation_cache_dir,
+            jax.config.jax_persistent_cache_min_compile_time_secs,
+        )
+        yield
+        jax.config.update("jax_compilation_cache_dir", before[0])
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", before[1])
+
     def test_runtime_points_jax_at_the_cache_dir(self, tmp_path):
-        before = jax.config.jax_compilation_cache_dir
-        try:
-            runtime = ServingRuntime(
-                ServingConfig(
-                    batching=False, compilation_cache_dir=str(tmp_path)
-                )
-            )
-            assert runtime.compilation_cache_active
-            assert jax.config.jax_compilation_cache_dir == str(tmp_path)
-        finally:
-            jax.config.update("jax_compilation_cache_dir", before)
+        runtime = ServingRuntime(
+            ServingConfig(batching=False, compilation_cache_dir=str(tmp_path))
+        )
+        assert runtime.compilation_cache_active
+        assert runtime.compilation_cache_dir == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == str(tmp_path)
 
     def test_no_dir_leaves_jax_alone(self):
         before = jax.config.jax_compilation_cache_dir
         runtime = ServingRuntime(ServingConfig(batching=False))
         assert not runtime.compilation_cache_active
         assert jax.config.jax_compilation_cache_dir == before
+
+    @pytest.mark.parametrize(
+        "env_dir, config_dir, entry_point, want, sets_jax_config",
+        [
+            # JAX's own variable wins and nothing is set in code.
+            ("/env/dir", "/config/dir", True, "/env/dir", False),
+            ("/env/dir", None, False, "/env/dir", False),
+            # Then the repo's own setting.
+            (None, "/config/dir", True, "/config/dir", True),
+            (None, "/config/dir", False, "/config/dir", True),
+            # Then, for entry points only, the fixed checkout directory.
+            (None, None, True, compile_cache.CHECKOUT_CACHE_DIR, True),
+            (None, None, False, None, False),
+        ],
+    )
+    def test_precedence(
+        self, monkeypatch, env_dir, config_dir, entry_point, want, sets_jax_config
+    ):
+        if env_dir:
+            monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", env_dir)
+        before = jax.config.jax_compilation_cache_dir
+        got = compile_cache.configure(config_dir, entry_point=entry_point)
+        assert got == want
+        assert jax.config.jax_compilation_cache_dir == (
+            want if sets_jax_config else before
+        )
+
+    def test_entry_point_directory_is_fixed_in_the_checkout(self):
+        repo_root = os.path.dirname(
+            os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        )
+        assert compile_cache.CHECKOUT_CACHE_DIR == os.path.join(
+            repo_root, ".jax_cache"
+        )
 
 
 class TestServicePathBatching:

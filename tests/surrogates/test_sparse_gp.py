@@ -248,3 +248,36 @@ class TestTraining:
         assert mean.shape == (8,) and stddev.shape == (8,)
         assert bool(jnp.all(jnp.isfinite(mean)))
         assert bool(jnp.all(stddev > 0))
+
+
+class TestPosteriorMatmulPrecision:
+    """On a TPU the default matmul precision is one bf16 pass, and the
+    posterior variance — a difference of near-equal terms through L⁻¹ —
+    came out negative there (chip_smoke.py, PR 21). The CPU cannot show
+    that, so this pins what fixed it: every matmul traced from a posterior
+    query carries ``POSTERIOR_PRECISION``."""
+
+    @pytest.mark.parametrize("which", ["exact", "exact_joint", "sparse"])
+    def test_every_posterior_dot_is_pinned(self, which):
+        d = 3
+        base, sparse = _models(d, 8)
+        data = _data(24, d)
+        params = _mid_params(base.param_collection())
+        if which == "sparse":
+            state = sparse.precompute(
+                params, sparse_gp.select_inducing_kcenter(data, 8)
+            )
+        else:
+            state = base.precompute(params, data)
+        query = _queries(d)
+        fn = state.predict_joint if which == "exact_joint" else state.predict
+        dots = [
+            eqn
+            for eqn in jax.make_jaxpr(fn)(query).jaxpr.eqns
+            if eqn.primitive.name == "dot_general"
+        ]
+        assert len(dots) >= 2
+        want = jax.lax.Precision.HIGHEST
+        assert gp_lib.POSTERIOR_PRECISION == want
+        for eqn in dots:
+            assert eqn.params["precision"] in (want, (want, want)), eqn

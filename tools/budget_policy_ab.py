@@ -23,10 +23,6 @@ import time
 _REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, _REPO_ROOT)
 
-from __graft_entry__ import _honor_platform_env
-
-_honor_platform_env()
-
 import numpy as np
 
 

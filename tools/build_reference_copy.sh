@@ -7,8 +7,8 @@
 #    of equinox/tfp (those deps are not in this image and installs are
 #    banned, so the reference's GP stack cannot run; random / grid /
 #    quasi-random / NSGA2 / harmonica / eagle all work).
-# Used by parity_suite.py to measure the reference behaviorally (VERDICT r1
-# item #4 / BASELINE.md: the reference publishes no numbers, so it must be
+# Used by parity_suite.py to measure the reference behaviorally
+# (BASELINE.md: the reference publishes no numbers, so it must be
 # run as its own baseline).
 set -e
 

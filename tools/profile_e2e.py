@@ -15,10 +15,6 @@ import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-from __graft_entry__ import _honor_platform_env
-
-_honor_platform_env()
-
 import jax
 import numpy as np
 

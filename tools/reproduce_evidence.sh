@@ -10,14 +10,10 @@ echo "== 0. static analysis: lock order / JAX discipline / env registry (~2 s) =
 #    zero unbaselined violations (docs/guides/static_analysis.md)
 python tools/check_analysis.py
 
-echo "== 1. full test suite (~16 min; sharded recipe for 1-core boxes) =="
-#    On hardware where the single-process run no longer fits the tier-1
-#    wall (see ROADMAP.md "Tier-1 timing"), use the sharded recipe:
-#      bash tools/tier1_sharded.sh
-python -m pytest tests/ -q
+echo "== 1. full test suite (CPU; ~6 min with six xdist workers) =="
+python -m pytest tests/ -q -p xdist -n 6 --dist loadfile
 
-echo "== 2. full-scale CPU bench for the shipped default (~30 min) =="
-#    -> compare BENCH_CPU_FULLSCALE.json
+echo "== 2. full-scale CPU run of bench.py's control flow (~30 min; not a timing) =="
 JAX_PLATFORMS=cpu VIZIER_BENCH_SCALE=1.0 VIZIER_BENCH_WATCHDOG_S=14400 \
   python bench.py
 

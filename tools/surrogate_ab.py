@@ -20,7 +20,7 @@ Three measurements, one JSON report:
    20-D, 75k acquisition evals, batch 25): per repeat, ARD train + one
    full acquisition sweep, device-synchronized.
    - exact arm: the seed path — multi-restart L-BFGS over the exact GP's
-     O(n³) marginal likelihood (BENCH_CPU_FULLSCALE.json's 72 s p50);
+     O(n³) marginal likelihood;
    - sparse arm: the SAME restart budget over the SGPR collapsed bound
      with m inducing points (k-center-selected inside the program) —
      O(n·m²) train, O(m²) posterior queries in the sweep.
@@ -46,10 +46,6 @@ import time
 
 _REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, _REPO_ROOT)
-
-from __graft_entry__ import _honor_platform_env
-
-_honor_platform_env()
 
 import numpy as np
 

@@ -324,8 +324,6 @@ SWITCHES: Tuple[EnvSwitch, ...] = (
             "Global workload scale factor for bench.py.", "1.0"),
     _switch("VIZIER_BENCH_WATCHDOG_S", "float", "bench.py", _PERF_DOC,
             "bench.py watchdog timeout in seconds."),
-    _switch("VIZIER_PEAK_FLOPS", "float", "bench.py", _PERF_DOC,
-            "Hardware peak FLOP/s override for MFU accounting."),
     # -- reserved constants (NOT environment variables) --------------------
     _switch("VIZIER_METHODS", "constant", "service.grpc_stubs",
             "docs/guides/running_the_service.md",

@@ -17,10 +17,13 @@ clients use, so each study's read-back lands on the frontend that owns
 it. Connections are lazy: the tier may start before, after, or between
 frontend (re)starts.
 
-Unlike ``replica_main``, this process does NOT default
-``JAX_PLATFORMS=cpu`` — the compute tier is the process that is SUPPOSED
-to own the accelerators. Test/CI spawners pin cpu through the child
-environment instead (``SubprocessReplicaManager`` does).
+This is the process of a fleet that owns the accelerators: it takes the
+platform from its environment, and ``SubprocessReplicaManager`` passes
+that through untouched (frontends are launched with ``JAX_PLATFORMS=cpu``;
+tests pin the compute server too, through ``child_env``). Its persistent
+compile cache follows ``serving.compile_cache``:
+``JAX_COMPILATION_CACHE_DIR``, else ``VIZIER_COMPILE_CACHE_DIR``, else
+``<checkout>/.jax_cache``.
 
 The ``ReplicationService`` surface is served solely for its ``Heartbeat``
 method: the fleet manager health-checks the compute server with the same
@@ -125,6 +128,11 @@ def main(argv=None) -> None:
             ),
         )
 
+    # An entry point that runs on the chip: the compile cache lands at a
+    # fixed place unless the environment or the repo's own setting names one.
+    from vizier_tpu.serving import compile_cache
+
+    compile_cache.configure_entry_point()
     pythia = pythia_service.PythiaServicer(vizier_backend)
 
     server = grpc.server(futures.ThreadPoolExecutor(max_workers=args.max_workers))

@@ -407,7 +407,9 @@ class SubprocessReplicaManager:
             args += ["--compute-endpoint", self._compute.endpoint]
         if self._obs_dump_dir:
             args += ["--obs-dump-dir", self._obs_dump_dir]
-        self._popen(rec, args)
+        # Frontends serve studies on the CPU: a chip belongs to one process,
+        # and N replicas cannot share it. (``child_env`` may still override.)
+        self._popen(rec, args, {"JAX_PLATFORMS": "cpu"})
 
     def _spawn_compute(self, rec: _ReplicaProcess) -> None:
         args = [
@@ -423,9 +425,15 @@ class SubprocessReplicaManager:
         ]
         if self._obs_dump_dir:
             args += ["--obs-dump-dir", self._obs_dump_dir]
-        self._popen(rec, args)
+        # The compute server is the one process of the fleet that owns the
+        # accelerators: it inherits the platform from the environment
+        # (``child_env`` pins it for tests). This manager parent must never
+        # initialize a JAX backend itself, or it would hold the chip.
+        self._popen(rec, args, {})
 
-    def _popen(self, rec: _ReplicaProcess, args: List[str]) -> None:
+    def _popen(
+        self, rec: _ReplicaProcess, args: List[str], role_env: Dict[str, str]
+    ) -> None:
         os.makedirs(self._wal_root, exist_ok=True)
         log = open(rec.log_path, "ab")
         try:
@@ -434,11 +442,7 @@ class SubprocessReplicaManager:
                 stdout=subprocess.PIPE,
                 stderr=log,
                 text=True,
-                env={
-                    **os.environ,
-                    "JAX_PLATFORMS": "cpu",
-                    **self._child_env,
-                },
+                env={**os.environ, **role_env, **self._child_env},
             )
         finally:
             log.close()

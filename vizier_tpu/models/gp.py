@@ -36,6 +36,13 @@ Params = params_lib.Params
 
 _LOG_2PI = 1.8378770664093453
 _JITTER = 1e-5
+# The posterior's matmuls run at full f32 precision. At the TPU's default
+# (one bf16 pass) the variance — a difference of near-equal terms through
+# L⁻¹, whose entries grow with the Gram's condition number — came out
+# NEGATIVE on a v5e at 400×20-D (chip_smoke.py, PR 21): the clamp then
+# zeroes the stddev and the UCB/PE terms with it. Whether a cheaper
+# precision suffices per matmul is ROADMAP S2's measurement to make.
+POSTERIOR_PRECISION = jax.lax.Precision.HIGHEST
 
 
 @flax.struct.dataclass
@@ -258,8 +265,9 @@ class GPState:
         model, p, data = self.model, self.params, self.data
         k_star = model._kernel(p, query, data.features(), data)  # [M, N]
         k_star = jnp.where(data.row_mask[None, :], k_star, 0.0)
-        mean = k_star @ self.alpha
-        v = self.linv @ k_star.T  # [N, M] — pure matmul in the hot loop
+        mean = jnp.matmul(k_star, self.alpha, precision=POSTERIOR_PRECISION)
+        # [N, M] — pure matmul in the hot loop
+        v = jnp.matmul(self.linv, k_star.T, precision=POSTERIOR_PRECISION)
         prior_var = p["amplitude"] * p["amplitude"]
         var = prior_var - jnp.sum(v * v, axis=0)
         if include_noise:
@@ -275,10 +283,10 @@ class GPState:
         model, p, data = self.model, self.params, self.data
         k_star = model._kernel(p, query, data.features(), data)  # [M, N]
         k_star = jnp.where(data.row_mask[None, :], k_star, 0.0)
-        mean = k_star @ self.alpha
-        v = self.linv @ k_star.T  # [N, M]
+        mean = jnp.matmul(k_star, self.alpha, precision=POSTERIOR_PRECISION)
+        v = jnp.matmul(self.linv, k_star.T, precision=POSTERIOR_PRECISION)  # [N, M]
         k_qq = model._kernel(p, query, query, data)  # [M, M]
-        cov = k_qq - v.T @ v
+        cov = k_qq - jnp.matmul(v.T, v, precision=POSTERIOR_PRECISION)
         # Symmetrize + jitter for downstream Cholesky.
         cov = 0.5 * (cov + cov.T) + 1e-6 * jnp.eye(cov.shape[0], dtype=cov.dtype)
         return mean, cov

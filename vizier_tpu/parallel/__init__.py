@@ -64,25 +64,6 @@ def create_mesh(
     return Mesh(np.asarray(devices), (axis_name,))
 
 
-def _distributed_initialized() -> bool:
-    """Whether the jax distributed runtime is already up.
-
-    ``jax.distributed.is_initialized`` only exists on newer jax; on
-    releases without it (0.4.37 ships only initialize/shutdown) the
-    coordination client on the private global state carries the same bit.
-    Neither path touches devices, so the backend stays uninitialized.
-    """
-    is_init = getattr(jax.distributed, "is_initialized", None)
-    if is_init is not None:
-        return bool(is_init())
-    try:
-        from jax._src import distributed as _distributed_src
-
-        return _distributed_src.global_state.client is not None
-    except Exception:
-        return False
-
-
 def initialize_multihost(
     coordinator_address: Optional[str] = None,
     num_processes: Optional[int] = None,
@@ -99,28 +80,23 @@ def initialize_multihost(
     so cross-host traffic is one final top-k gather; everything else
     rides ICI within each host's slice.
 
-    On TPU pods the arguments are auto-detected from the runtime
-    environment and may be omitted.
+    Without a ``coordinator_address`` this is one host: the distributed
+    runtime is not touched at all and the mesh is the local devices. (A
+    pod that relies on JAX's own cluster detection calls
+    ``jax.distributed.initialize()`` itself first, and sees its failure
+    itself.) With one, the cluster is joined and a failure propagates — a
+    silently absent cluster would shard per-host and corrupt results.
 
     MUST run before any JAX call that initializes the XLA backend
     (including ``jax.devices()``): ``jax.distributed.initialize`` refuses
-    to run afterwards. Initialization state is checked via
-    ``_distributed_initialized`` — never by touching devices.
+    to run afterwards.
     """
-    if not _distributed_initialized():
-        if coordinator_address is not None:
-            # Explicit cluster spec: failures must propagate — a silently
-            # absent cluster would shard per-host and corrupt results.
-            jax.distributed.initialize(
-                coordinator_address=coordinator_address,
-                num_processes=num_processes,
-                process_id=process_id,
-            )
-        else:
-            try:
-                jax.distributed.initialize()  # TPU-pod auto-detection
-            except Exception:
-                pass  # plain single host: fall through to a local mesh
+    if coordinator_address is not None and not jax.distributed.is_initialized():
+        jax.distributed.initialize(
+            coordinator_address=coordinator_address,
+            num_processes=num_processes,
+            process_id=process_id,
+        )
     return create_mesh()
 
 

@@ -638,7 +638,10 @@ class PythiaServicer:
                 breaker.record_failure()
             if reliability.fallback_on:
                 return self._fallback_response(
-                    config, request, f"designer_error:{type(e).__name__}"
+                    config,
+                    request,
+                    f"designer_error:{type(e).__name__}",
+                    error=e,
                 )
             response.error = errors_lib.format_op_error(e)
             return response
@@ -655,8 +658,14 @@ class PythiaServicer:
         config: vz.StudyConfig,
         request: pythia_service_pb2.PythiaSuggestRequest,
         reason: str,
+        error: Optional[BaseException] = None,
     ) -> pythia_service_pb2.PythiaSuggestResponse:
-        """Graceful degradation: seeded quasi-random, stamped + counted."""
+        """Graceful degradation: seeded quasi-random, stamped + counted.
+
+        ``error`` (the designer exception being degraded around, if any)
+        rides the event, the flight-recorder entry and the log line, so a
+        device-side compile error or OOM stays legible behind the counter.
+        """
         response = pythia_service_pb2.PythiaSuggestResponse()
         try:
             suggestions = fallback_lib.suggest_fallback(
@@ -679,18 +688,24 @@ class PythiaServicer:
             )
             return response
         self._serving.stats.increment("fallbacks", len(suggestions))
+        cause = (
+            {"error_type": type(error).__name__, "error_message": str(error)[:500]}
+            if error is not None
+            else {}
+        )
         tracing_lib.add_current_event(
-            "fallback.served", reason=reason, count=len(suggestions)
+            "fallback.served", reason=reason, count=len(suggestions), **cause
         )
         self._serving.flight_recorder.record(
             request.study_name, "fallback", reason=reason,
-            count=len(suggestions),
+            count=len(suggestions), **cause,
         )
         _logger.warning(
-            "Serving %d quasi-random fallback suggestion(s) for %s (%s).",
+            "Serving %d quasi-random fallback suggestion(s) for %s (%s)%s.",
             len(suggestions),
             request.study_name,
             reason,
+            f": {cause['error_message']}" if cause else "",
         )
         for s in suggestions:
             response.suggestions.add().CopyFrom(pc.trial_suggestion_to_proto(s))

@@ -17,7 +17,8 @@ cold-train-per-request behavior without code changes):
   the padding-bucket grid when the first study of a shape arrives
   (default off: prewarm is explicit via ``ServingRuntime.prewarm_batching``).
 - ``VIZIER_COMPILE_CACHE_DIR=/path`` — persist XLA compilations across
-  process restarts (``jax_compilation_cache_dir``).
+  process restarts (``jax_compilation_cache_dir``); JAX's own
+  ``JAX_COMPILATION_CACHE_DIR`` outranks it (``serving.compile_cache``).
 """
 
 from __future__ import annotations
@@ -71,7 +72,8 @@ class ServingConfig:
     batching_prewarm_max_trials: int = 32
 
     # JAX persistent compilation cache directory (applied at runtime init
-    # via ``jax_compilation_cache_dir``); None leaves jax's default alone.
+    # by ``serving.compile_cache.configure``, which yields to
+    # JAX_COMPILATION_CACHE_DIR); None leaves jax's default alone.
     compilation_cache_dir: Optional[str] = None
 
     @classmethod

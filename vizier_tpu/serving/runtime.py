@@ -13,7 +13,6 @@ latency), all dumped together by :meth:`prometheus_text`.
 
 from __future__ import annotations
 
-import logging
 import threading
 from typing import Any, Callable, Dict, List, Optional, Sequence
 
@@ -25,36 +24,12 @@ from vizier_tpu.reliability import breaker as breaker_lib
 from vizier_tpu.reliability import config as reliability_config_lib
 from vizier_tpu.serving import admission as admission_lib
 from vizier_tpu.serving import coalescer as coalescer_lib
+from vizier_tpu.serving import compile_cache
 from vizier_tpu.serving import config as config_lib
 from vizier_tpu.serving import designer_cache as cache_lib
 from vizier_tpu.serving import speculative as speculative_lib
 from vizier_tpu.serving import stats as stats_lib
 from vizier_tpu.surrogates import config as surrogate_config_lib
-
-_logger = logging.getLogger(__name__)
-
-
-def _apply_compilation_cache(cache_dir: str) -> bool:
-    """Points jax's persistent compilation cache at ``cache_dir``.
-
-    Best-effort: an older jax without the option must not take serving
-    down. The min-compile-time floor is dropped to 0 so the small per-bucket
-    GP programs (often < 1s compiles on CPU) are cached too.
-    """
-    import jax
-
-    try:
-        jax.config.update("jax_compilation_cache_dir", cache_dir)
-        try:
-            jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-        except Exception:  # option renamed/missing: dir alone still helps
-            pass
-        return True
-    except Exception:
-        _logger.warning(
-            "Could not enable the JAX compilation cache at %r.", cache_dir
-        )
-        return False
 
 
 class ServingRuntime:
@@ -134,11 +109,11 @@ class ServingRuntime:
             )
         # JAX persistent compilation cache: survive process restarts so a
         # restarted server pays zero XLA compiles for known buckets.
-        self.compilation_cache_active = False
-        if self.config.compilation_cache_dir:
-            self.compilation_cache_active = _apply_compilation_cache(
-                self.config.compilation_cache_dir
-            )
+        # JAX_COMPILATION_CACHE_DIR outranks the repo's own setting; with
+        # neither, a bare runtime leaves the cache off (compile_cache).
+        self.compilation_cache_dir = compile_cache.configure(
+            self.config.compilation_cache_dir
+        )
         # Cross-study batch executor: concurrent same-bucket designer
         # computations share ONE vmapped device program. None = batching
         # off (VIZIER_BATCHING=0): the exact per-study path. The mesh
@@ -199,6 +174,10 @@ class ServingRuntime:
         self._prewarmed_shapes: set = set()
         self._prewarm_lock = threading.Lock()
         self._prewarm_threads: List[threading.Thread] = []
+
+    @property
+    def compilation_cache_active(self) -> bool:
+        return self.compilation_cache_dir is not None
 
     # -- compile prewarm ----------------------------------------------------
 

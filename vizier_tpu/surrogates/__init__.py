@@ -1,8 +1,7 @@
 """Scalable surrogate tier: sparse-GP posteriors behind the designer seam.
 
-The exact GP's O(n³) Cholesky makes large, long-lived studies infeasible
-(BENCH_CPU_FULLSCALE.json: 72 s device-side suggest p50 at 1000 trials ×
-20-D). This package provides the sparse inducing-point alternative —
+The exact GP's O(n³) Cholesky makes large, long-lived studies expensive.
+This package provides the sparse inducing-point alternative —
 O(n·m²) training, O(m²) posterior — plus the :class:`SurrogateConfig`
 auto-switch that moves a study from the exact to the sparse path at a
 trial-count threshold (with hysteresis), serving-tier-wide via

@@ -1,11 +1,9 @@
 """Sparse inducing-point GP: SGPR collapsed bound, mask-safe, TPU-first.
 
 The exact GP (``models.gp``) pays O(n³) per ARD loss evaluation and O(n²)
-per posterior query — a 72 s device-side suggest at the 1000×20-D
-north-star scale (BENCH_CPU_FULLSCALE.json). This module is the
-inducing-point alternative ("Scalable Thompson Sampling using Sparse
-Gaussian Process Models", arXiv:2006.05356; Titsias' SGPR collapsed
-bound): m ≪ n pseudo-inputs Z summarize the data, training costs O(n·m²)
+per posterior query. This module is the inducing-point alternative
+("Scalable Thompson Sampling using Sparse Gaussian Process Models",
+arXiv:2006.05356; Titsias' SGPR collapsed bound): m ≪ n pseudo-inputs Z summarize the data, training costs O(n·m²)
 and each posterior query O(m²) — and because the collapsed bound
 marginalizes the inducing distribution in closed form, there is no
 variational optimization loop: the SAME multi-restart L-BFGS program that
@@ -324,9 +322,13 @@ class SparseGPState:
         model, p, sdata = self.model, self.params, self.sdata
         k_star = model.base._kernel(p, query, sdata.z_features(), sdata.data)
         k_star = jnp.where(sdata.inducing_mask[None, :], k_star, 0.0)  # [Q, M]
-        mean = k_star @ self.w
-        t1 = self.linv @ k_star.T  # [M, Q] — matmul-only hot loop
-        t2 = self.lb_linv @ k_star.T
+        # Full f32 precision, like the exact posterior (models.gp
+        # POSTERIOR_PRECISION): the same difference of near-equal terms.
+        precision = gp_lib.POSTERIOR_PRECISION
+        mean = jnp.matmul(k_star, self.w, precision=precision)
+        # [M, Q] — matmul-only hot loop
+        t1 = jnp.matmul(self.linv, k_star.T, precision=precision)
+        t2 = jnp.matmul(self.lb_linv, k_star.T, precision=precision)
         amp2 = p["amplitude"] * p["amplitude"]
         var = amp2 - jnp.sum(t1 * t1, axis=0) + jnp.sum(t2 * t2, axis=0)
         if include_noise:
