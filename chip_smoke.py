@@ -318,16 +318,15 @@ def executor_bucket_kinds(runtime) -> Dict[str, int]:
 
 
 def device_phases() -> Dict[str, float]:
-    """Device phases that ran (``vizier_jax_phase_seconds``), synced by
-    ``block_until_ready``: "phase/mode" → total seconds so far."""
-    from vizier_tpu.observability import metrics as metrics_lib
+    """Device phases that ran (the tracer's ``device.wait`` stage spans),
+    synced by ``block_until_ready``: "phase/mode" → total seconds so far."""
+    from vizier_tpu.observability import tracing as tracing_lib
 
-    hist = metrics_lib.default_registry().get("vizier_jax_phase_seconds")
     out: Dict[str, float] = {}
-    if hist is not None:
-        for key, (_, _, total) in hist.series_data().items():
-            labels = dict(key)
-            out[f"{labels['phase']}/{labels['mode']}"] = total
+    for span in tracing_lib.get_tracer().finished_spans():
+        if span.name == "device.wait":
+            key = f"{span.attributes['phase']}/{span.attributes['mode']}"
+            out[key] = out.get(key, 0.0) + span.duration_secs
     return out
 
 

@@ -46,3 +46,92 @@ def _gc_relief():
     gc.unfreeze()
     gc.collect()
     gc.freeze()
+
+
+@pytest.fixture
+def served_gp_stack():
+    """Builder of in-process served stacks for the stage-span tests.
+
+    ``build(num_studies, designer_factory=None, **serving_config)`` returns
+    ``(servicer, runtime, study_names)``: a ``VizierServicer`` wired to a
+    ``PythiaServicer`` whose serving runtime (designer cache, batch
+    executor) routes every study through ``CachedDesignerStatePolicy``;
+    each study is 2-D and holds six completed trials. The default designers
+    are GP-UCB-PE cut to test size. A fresh tracer is installed BEFORE the
+    runtime is built — the runtime binds its registry to the tracer — and
+    is reached as ``tracing.get_tracer()``.
+    """
+    import numpy as np
+
+    from vizier_tpu import pyvizier as vz
+    from vizier_tpu.designers import gp_ucb_pe
+    from vizier_tpu.observability import tracing as tracing_lib
+    from vizier_tpu.optimizers import lbfgs as lbfgs_lib
+    from vizier_tpu.service import proto_converters as pc
+    from vizier_tpu.service import pythia_service, vizier_client, vizier_service
+    from vizier_tpu.service.protos import vizier_service_pb2
+    from vizier_tpu.serving import config as serving_config_lib
+    from vizier_tpu.serving import policy as serving_policy
+
+    def fast_ucb_pe(problem, **kwargs):
+        return gp_ucb_pe.VizierGPUCBPEBandit(
+            problem,
+            ard_optimizer=lbfgs_lib.AdamOptimizer(maxiter=15),
+            ard_restarts=3,
+            max_acquisition_evaluations=200,
+            warm_start_min_trials=0,
+        )
+
+    class PolicyFactory:
+        runtime = None
+
+        def __init__(self, designer_factory):
+            self._designer_factory = designer_factory
+
+        def __call__(self, problem, algorithm, supporter, study_name):
+            return serving_policy.CachedDesignerStatePolicy(
+                supporter, self._designer_factory, self.runtime, study_name
+            )
+
+    old_tracer = tracing_lib.set_tracer(tracing_lib.Tracer())
+    pythias = []
+
+    def build(num_studies=1, designer_factory=None, **serving_config):
+        servicer = vizier_service.VizierServicer()
+        factory = PolicyFactory(designer_factory or fast_ucb_pe)
+        pythia = pythia_service.PythiaServicer(
+            servicer,
+            factory,
+            serving_config=serving_config_lib.ServingConfig(**serving_config),
+        )
+        pythias.append(pythia)
+        factory.runtime = pythia.serving_runtime
+        servicer.set_pythia(pythia)
+        config = vz.StudyConfig(algorithm="DEFAULT")
+        for d in range(2):
+            config.search_space.root.add_float_param(f"x{d}", 0.0, 1.0)
+        config.metric_information.append(
+            vz.MetricInformation(name="obj", goal=vz.ObjectiveMetricGoal.MAXIMIZE)
+        )
+        names = []
+        for i in range(num_studies):
+            name = f"owners/stages/studies/s{i}"
+            servicer.CreateStudy(
+                vizier_service_pb2.CreateStudyRequest(
+                    parent="owners/stages", study=pc.study_to_proto(config, name)
+                )
+            )
+            rng = np.random.default_rng(i)
+            loader = vizier_client.VizierClient(servicer, name, "loader")
+            for _ in range(6):
+                x = rng.uniform(size=2)
+                trial = vz.Trial(parameters={"x0": float(x[0]), "x1": float(x[1])})
+                trial.complete(vz.Measurement(metrics={"obj": float(-np.sum(x**2))}))
+                loader.create_trial(trial)
+            names.append(name)
+        return servicer, pythia.serving_runtime, names
+
+    yield build
+    for pythia in pythias:
+        pythia.shutdown()
+    tracing_lib.set_tracer(old_tracer)

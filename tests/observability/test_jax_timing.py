@@ -1,4 +1,5 @@
-"""device_phase: compile-vs-execute split, spans, disabled no-op."""
+"""device_phase: the ``device.wait`` stage span — compile-vs-execute split,
+the one stage histogram, disabled no-op."""
 
 import pytest
 
@@ -14,8 +15,9 @@ def fresh_state():
     tracer = tracing_lib.Tracer()
     old_tracer = tracing_lib.set_tracer(tracer)
     registry = metrics_lib.MetricsRegistry()
+    tracer.bind_registry(registry)  # as the serving runtime does
     old_registry_state = metrics_lib._default_registry
-    metrics_lib.set_default_registry(registry)
+    metrics_lib.set_default_registry(metrics_lib.MetricsRegistry())
     jax_timing.set_config(config_lib.ObservabilityConfig())
     jax_timing.reset_compile_tracking()
     yield tracer, registry
@@ -31,19 +33,23 @@ class TestDevicePhase:
         for _ in range(3):
             with jax_timing.device_phase("unit.phase"):
                 pass
-        hist = registry.get("vizier_jax_phase_seconds")
-        assert hist.count(phase="unit.phase", mode="compile") == 1
-        assert hist.count(phase="unit.phase", mode="execute") == 2
+        hist = registry.get("vizier_suggest_stage_seconds")
+        assert hist.count(stage="device.wait", path="sequential", per="request") == 3
+        modes = [s.attributes["mode"] for s in tracer.finished_spans()]
+        assert modes == ["compile", "execute", "execute"]
 
     def test_phase_names_tracked_independently(self, fresh_state):
-        _, registry = fresh_state
-        with jax_timing.device_phase("a"):
+        tracer, registry = fresh_state
+        with jax_timing.device_phase("a", stage="train"):
             pass
-        with jax_timing.device_phase("b"):
+        with jax_timing.device_phase("b", path="fused", per="flush"):
             pass
-        hist = registry.get("vizier_jax_phase_seconds")
-        assert hist.count(phase="a", mode="compile") == 1
-        assert hist.count(phase="b", mode="compile") == 1
+        hist = registry.get("vizier_suggest_stage_seconds")
+        assert hist.count(stage="device.wait", path="sequential", per="request") == 1
+        assert hist.count(stage="device.wait", path="fused", per="flush") == 1
+        by_phase = {s.attributes["phase"]: s.attributes for s in tracer.finished_spans()}
+        assert by_phase["a"]["mode"] == by_phase["b"]["mode"] == "compile"
+        assert by_phase["a"]["stage"] == "train" and "stage" not in by_phase["b"]
 
     def test_span_carries_mode_attribute(self, fresh_state):
         tracer, _ = fresh_state
@@ -51,7 +57,8 @@ class TestDevicePhase:
             pass
         with jax_timing.device_phase("unit.span"):
             pass
-        spans = [s for s in tracer.finished_spans() if s.name == "jax.unit.span"]
+        spans = [s for s in tracer.finished_spans() if s.name == "device.wait"]
+        assert [s.attributes["phase"] for s in spans] == ["unit.span"] * 2
         assert [s.attributes["mode"] for s in spans] == ["compile", "execute"]
         assert spans[0].attributes["first_call"] is True
         assert spans[1].attributes["first_call"] is False
@@ -68,9 +75,10 @@ class TestDevicePhase:
         with pytest.raises(RuntimeError):
             with jax_timing.device_phase("unit.err"):
                 raise RuntimeError("boom")
-        hist = registry.get("vizier_jax_phase_seconds")
-        # The failed phase was not observed (the family may not even exist).
-        assert hist is None or hist.count(phase="unit.err", mode="compile") == 0
+        # The failed phase was not observed: a stage whose body raised is
+        # not a sample of that stage's time.
+        hist = registry.get("vizier_suggest_stage_seconds")
+        assert hist.count(stage="device.wait", path="sequential", per="request") == 0
 
     def test_disabled_is_inert(self, fresh_state):
         tracer, registry = fresh_state
@@ -79,5 +87,8 @@ class TestDevicePhase:
             # No device sync requested, no histogram, no span.
             assert phase.block("anything") == "anything"
             assert not phase.enabled
-        assert registry.get("vizier_jax_phase_seconds") is None
+        hist = registry.get("vizier_suggest_stage_seconds")
+        assert hist.count(stage="device.wait", path="sequential", per="request") == 0
         assert tracer.finished_spans() == []
+        # Nothing on the served path observes into the process-global registry.
+        assert metrics_lib.default_registry().names() == []

@@ -86,7 +86,14 @@ class TestBreakdown:
 
 class TestSurrogateActivity:
     def _spans(self, names):
-        return [{"name": n, "duration_secs": 0.01} for n in names]
+        # A device phase is a ``device.wait`` stage span's ``phase``; any
+        # other name is a span of its own.
+        return [
+            {"name": "device.wait", "duration_secs": 0.01, "attributes": {"phase": n}}
+            if "." in n
+            else {"name": n, "duration_secs": 0.01}
+            for n in names
+        ]
 
     def test_exact_only(self):
         act = obs_report.surrogate_activity(

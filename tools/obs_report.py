@@ -4,7 +4,12 @@
 The input is what ``Tracer.dump_jsonl()`` (or the
 ``VIZIER_OBSERVABILITY_SPAN_LOG`` sink) writes: one span per line. The
 report groups spans by name and prints count, p50/p95/p99/max wall time,
-and total time — the "where does a suggest spend its time" table.
+and total time — the "where does a suggest spend its time" table. The
+stage spans (``service.read``, ``policy.load_trials``, ``designer.update``,
+``designer.prepare``, ``flush.stack``, ``device.wait``, ``designer.decode``,
+``service.write``: ``observability/tracing.py`` ``STAGES``) are the rows
+that split a suggest's host time; a ``device.wait`` row is split further by
+the device phase it waited for (its ``phase`` attribute).
 
 Usage:
     python tools/obs_report.py SPANS.jsonl              # per-phase table
@@ -72,22 +77,33 @@ def _percentile(sorted_values: List[float], q: float) -> float:
     return sorted_values[lo] * (1 - frac) + sorted_values[hi] * frac
 
 
+def _device_phase(span: dict) -> str:
+    """The device phase a span waited for: a ``device.wait`` stage span's
+    ``phase`` attribute ('' for every other span)."""
+    if span.get("name") != "device.wait":
+        return ""
+    return str((span.get("attributes") or {}).get("phase", ""))
+
+
 def phase_breakdown(spans: List[dict]) -> List[dict]:
-    """Per-span-name latency stats, sorted by total time descending."""
+    """Per-span-name latency stats, sorted by total time descending (a
+    ``device.wait`` row per device phase)."""
     by_name: Dict[str, List[float]] = {}
     occupancy: Dict[str, List[float]] = {}
     for span in spans:
         duration = span.get("duration_secs")
         if duration is None:
             continue
-        by_name.setdefault(span["name"], []).append(float(duration))
+        phase = _device_phase(span)
+        name = f"{span['name']} {phase}" if phase else span["name"]
+        by_name.setdefault(name, []).append(float(duration))
         # Cross-study batching occupancy: batch_executor.flush spans carry
         # how many real studies shared the dispatch; member suggest spans
         # carry batch_occupancy. Either way it rolls into a mean per phase.
         attrs = span.get("attributes") or {}
         occ = attrs.get("occupancy", attrs.get("batch_occupancy"))
         if isinstance(occ, (int, float)):
-            occupancy.setdefault(span["name"], []).append(float(occ))
+            occupancy.setdefault(name, []).append(float(occ))
     out = []
     for name, durations in by_name.items():
         durations.sort()
@@ -108,16 +124,15 @@ def phase_breakdown(spans: List[dict]) -> List[dict]:
     return out
 
 
-# Device-phase span prefixes per surrogate path, sourced from the
-# compute-IR program registry (each registered DesignerProgram declares
-# its device_phase + surrogate_family): a new program's phases classify
+# Device-phase prefixes (the ``phase`` of a ``device.wait`` span) per
+# surrogate path, sourced from the compute-IR program registry (each
+# registered DesignerProgram declares its device_phase +
+# surrogate_family): a new program's phases classify
 # correctly the moment it registers, no report edit. The static fallback
 # keeps this tool stdlib-runnable on span files from machines where the
 # runtime tree (jax) is not importable.
-_FALLBACK_SPARSE_PHASES = ("jax.sparse_gp.", "sparse_gp.")
-_FALLBACK_EXACT_PHASES = (
-    "jax.gp_bandit.", "jax.gp_ucb_pe.", "gp_bandit.", "gp_ucb_pe.",
-)
+_FALLBACK_SPARSE_PHASES = ("sparse_gp.",)
+_FALLBACK_EXACT_PHASES = ("gp_bandit.", "gp_ucb_pe.")
 # device_phase ("sparse_gp.ucb_pe_suggest_batched") -> program kind, for
 # the per-program-kind breakdown (populated from the registry; empty on
 # fallback).
@@ -134,9 +149,7 @@ def _phase_families():
             family = sparse if program.surrogate_family == "sparse" else exact
             prefix = program.device_phase.split(".")[0] + "."
             family.add(prefix)
-            family.add("jax." + prefix)
             _KIND_BY_PHASE[program.device_phase] = program.kind
-            _KIND_BY_PHASE["jax." + program.device_phase] = program.kind
         if sparse or exact:
             return tuple(sorted(sparse)), tuple(sorted(exact))
     except Exception:  # no jax / no tree: stay stdlib-runnable
@@ -147,17 +160,17 @@ def _phase_families():
 def surrogate_activity(spans: List[dict]) -> dict:
     """Which surrogate path(s) produced this span file's device phases.
 
-    Counts device-phase spans by family so every report says whether its
+    Counts ``device.wait`` spans by family so every report says whether its
     numbers came from the exact O(n³) path, the sparse inducing-point
     path, or a mix (auto-switched studies mid-file).
     """
     sparse_phases, exact_phases = _phase_families()
     counts = {"exact": 0, "sparse": 0}
     for span in spans:
-        name = span.get("name", "")
-        if any(name.startswith(p) for p in sparse_phases):
+        phase = _device_phase(span)
+        if any(phase.startswith(p) for p in sparse_phases):
             counts["sparse"] += 1
-        elif any(name.startswith(p) for p in exact_phases):
+        elif any(phase.startswith(p) for p in exact_phases):
             counts["exact"] += 1
     if counts["sparse"] and counts["exact"]:
         mode = "mixed"
@@ -173,7 +186,7 @@ def surrogate_activity(spans: List[dict]) -> dict:
 def program_kind_activity(spans: List[dict]) -> Dict[str, dict]:
     """Per-program-kind flush breakdown, keyed by registered kind.
 
-    Maps batched device-phase spans back to the DesignerProgram that
+    Maps a fused flush's ``device.wait`` span back to the DesignerProgram that
     emitted them via the registry (requires the runtime tree; empty dict
     on the stdlib fallback), so the report answers "which program kinds
     carried this workload, and how much device time each took".
@@ -183,7 +196,7 @@ def program_kind_activity(spans: List[dict]) -> Dict[str, dict]:
         return {}
     out: Dict[str, dict] = {}
     for span in spans:
-        kind = _KIND_BY_PHASE.get(span.get("name", ""))
+        kind = _KIND_BY_PHASE.get(_device_phase(span))
         if kind is None:
             continue
         duration = float(span.get("duration_secs") or 0.0)

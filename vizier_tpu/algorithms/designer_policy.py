@@ -91,10 +91,13 @@ class DesignerPolicy(policy_lib.Policy):
 
         tracer = tracing_lib.get_tracer()
         designer = self._designer_factory(request.study_config.to_problem())
-        completed = self._supporter.GetTrials(
-            status_matches=trial_.TrialStatus.COMPLETED
-        )
-        active = self._supporter.GetTrials(status_matches=trial_.TrialStatus.ACTIVE)
+        with tracer.span("policy.load_trials"):
+            completed = self._supporter.GetTrials(
+                status_matches=trial_.TrialStatus.COMPLETED
+            )
+            active = self._supporter.GetTrials(
+                status_matches=trial_.TrialStatus.ACTIVE
+            )
         with tracer.span(
             "designer.update",
             designer=type(designer).__name__,

@@ -19,7 +19,7 @@ on:
   walker and first-request latency pays its XLA compile;
 - ``program-missing-kind`` / ``program-missing-device-phase`` — the
   ``kind`` / ``device_phase`` class attributes are absent or not string
-  literals, so registry lookup / ``vizier_jax_phase_seconds`` tracing
+  literals, so registry lookup / ``device.wait`` span tracing
   cannot name the program;
 - ``program-missing-shard-axis`` — no literal ``shardable_batch_axis``
   declaration: the mesh execution plane (``parallel.mesh``) needs every
@@ -300,7 +300,7 @@ def run(project: common.Project, repo_root: str) -> ComputeIrResult:
                     message=(
                         f"DesignerProgram {reg.program_class} does not "
                         "declare a literal `device_phase` — its flushes "
-                        "would be invisible to vizier_jax_phase_seconds"
+                        "would carry no phase on their device.wait spans"
                     ),
                     path=info.path,
                     line=info.node.lineno,

@@ -17,7 +17,7 @@ Before this module those four stages were duck-typed methods copied onto
 every designer (``batch_bucket_key`` / ``batch_prepare`` /
 ``batch_execute`` / ``batch_finalize``), and each cross-cutting feature —
 the batch executor, the compile-prewarm walker, chaos slot isolation,
-``vizier_jax_phase_seconds`` device tracing, the speculative lane — had to
+``device.wait`` stage-span tracing, the speculative lane — had to
 be wired per copy. :class:`DesignerProgram` names the contract once;
 programs register in :mod:`vizier_tpu.compute.registry` and every feature
 consumes the registry generically. A designer that implements one program
@@ -78,7 +78,7 @@ class DesignerProgram(abc.ABC):
     #: Unique registry key; also the BucketKey.kind this program emits.
     kind: str = ""
     #: ``jax_timing.device_phase`` name the device body times itself under
-    #: (feeds ``vizier_jax_phase_seconds{phase}`` and tools/obs_report.py).
+    #: (the ``device.wait`` stage span's ``phase``; read by tools/obs_report.py).
     device_phase: str = ""
     #: Which surrogate family the device body trains ("exact" | "sparse");
     #: tools/obs_report.py builds its phase classification from this.

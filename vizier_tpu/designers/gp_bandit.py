@@ -41,6 +41,7 @@ from vizier_tpu.models import params as params_lib
 from vizier_tpu.optimizers import eagle as eagle_lib
 from vizier_tpu.optimizers import lbfgs as lbfgs_lib
 from vizier_tpu.observability import jax_timing
+from vizier_tpu.observability import tracing as tracing_lib
 from vizier_tpu.optimizers import vectorized as vectorized_lib
 from vizier_tpu.surrogates import config as surrogate_config_lib
 from vizier_tpu.surrogates import sparse_bandit
@@ -561,14 +562,15 @@ class VizierGPBandit(core_lib.Designer, core_lib.Predictor):
         same UCB/EI + trust-region eagle sweep over the sparse posterior.
         Consumes the RNG stream in the exact order of the exact path (train
         key, then acquisition key)."""
-        with profiler.timeit("convert_trials"):
+        tracer = tracing_lib.get_tracer()
+        with profiler.timeit("convert_trials"), tracer.span("designer.prepare"):
             data = gp_lib.GPData.from_model_data(self._warped_model_data())
         model = self._sparse_model()
         restarts = max(
             self._warm_restart_budget() or self.ard_restarts, self.ensemble_size
         )
         with profiler.timeit("train_gp"):
-            with jax_timing.device_phase("sparse_gp.train") as phase:
+            with jax_timing.device_phase("sparse_gp.train", stage="train") as phase:
                 states = sparse_bandit._train_sparse_gp(
                     model,
                     self._ard,
@@ -603,14 +605,18 @@ class VizierGPBandit(core_lib.Designer, core_lib.Predictor):
         )
         prior = self._prior_features(data)
         with profiler.timeit("acquisition_optimizer"):
-            with jax_timing.device_phase("sparse_gp.acquisition") as phase:
+            with jax_timing.device_phase(
+                "sparse_gp.acquisition", stage="acquire"
+            ) as phase:
                 result = sparse_bandit._maximize_sparse_acquisition(
                     self._vec_opt, scoring, self._next_rng(), count, prior
                 )
                 jax.block_until_ready(result.scores)
                 phase.block(result)
         self._surrogate_counts["sparse_suggests"] += 1
-        with profiler.timeit("best_candidates_to_trials"):
+        with profiler.timeit("best_candidates_to_trials"), tracer.span(
+            "designer.decode"
+        ):
             return self._decode_result(
                 result, count, kind=f"{self.acquisition}+sparse"
             )
@@ -777,14 +783,17 @@ class VizierGPBandit(core_lib.Designer, core_lib.Predictor):
         ):
             return self._suggest_sparse(count)
 
-        with profiler.timeit("convert_trials"):
+        tracer = tracing_lib.get_tracer()
+        with profiler.timeit("convert_trials"), tracer.span("designer.prepare"):
             data = gp_lib.GPData.from_model_data(self._warped_model_data())
         with profiler.timeit("train_gp"):
             # Device-phase timing: block the trained states INSIDE the span
             # so async dispatch cannot shift ARD device time onto whatever
             # later op first synchronizes; the first call per process is
             # recorded as compile, the rest as steady-state execute.
-            with jax_timing.device_phase("gp_bandit.train_gp") as phase:
+            with jax_timing.device_phase(
+                "gp_bandit.train_gp", stage="train"
+            ) as phase:
                 states = self._train(
                     data,
                     self._next_rng(),
@@ -852,11 +861,15 @@ class VizierGPBandit(core_lib.Designer, core_lib.Predictor):
         )
         prior = self._prior_features(data)
         with profiler.timeit("acquisition_optimizer"):
-            with jax_timing.device_phase("gp_bandit.acquisition") as phase:
+            with jax_timing.device_phase(
+                "gp_bandit.acquisition", stage="acquire"
+            ) as phase:
                 result = self._maximize(scoring, self._next_rng(), count, prior)
                 jax.block_until_ready(result.scores)
                 phase.block(result)
-        with profiler.timeit("best_candidates_to_trials"):
+        with profiler.timeit("best_candidates_to_trials"), tracer.span(
+            "designer.decode"
+        ):
             return self._decode_result(result, count, kind=self.acquisition)
 
     def _decode_result(
@@ -1232,16 +1245,21 @@ def _gp_bandit_demux(items, pad_to, states, warm_next, result, sparse: bool):
     slot and dominated the executor's wall time)."""
     from vizier_tpu.parallel import batch_executor
 
-    states, warm_next, result = jax.device_get((states, warm_next, result))
-    return [
-        dict(
-            states=batch_executor.slice_pytree(states, i),
-            warm_next=batch_executor.slice_pytree(warm_next, i),
-            result=batch_executor.slice_pytree(result, i),
-            sparse=sparse,
-        )
-        for i in range(len(items))
-    ]
+    # The per-flush half of the fused path's designer.decode stage
+    # (finalize is the per-slot half).
+    with tracing_lib.get_tracer().span(
+        "designer.decode", **tracing_lib.FUSED_FLUSH
+    ):
+        states, warm_next, result = jax.device_get((states, warm_next, result))
+        return [
+            dict(
+                states=batch_executor.slice_pytree(states, i),
+                warm_next=batch_executor.slice_pytree(warm_next, i),
+                result=batch_executor.slice_pytree(result, i),
+                sparse=sparse,
+            )
+            for i in range(len(items))
+        ]
 
 
 class GPBanditProgram(compute_ir.DesignerProgram):
@@ -1294,15 +1312,16 @@ class GPBanditProgram(compute_ir.DesignerProgram):
         from vizier_tpu.parallel import batch_executor
 
         d0: "VizierGPBandit" = items[0]["designer"]
-        stack = lambda name: batch_executor.place_batch(  # noqa: E731
-            batch_executor.stack_pytrees([it[name] for it in items], pad_to),
-            placement,
+        stacked = batch_executor.stack_members(
+            items, ("md", "rng_train", "rng_acq", "warm"), pad_to, placement
         )
-        with jax_timing.device_phase(self.device_phase) as phase:
+        with jax_timing.device_phase(
+            self.device_phase, **tracing_lib.FUSED_FLUSH
+        ) as phase:
             states, warm_next, result = _gp_bandit_flush_program(
                 d0._model, d0._ard, d0._vec_opt, d0._make_acquisition(),
-                stack("md"), stack("rng_train"), stack("rng_acq"),
-                stack("warm"),
+                stacked["md"], stacked["rng_train"], stacked["rng_acq"],
+                stacked["warm"],
                 items[0]["restarts"], d0.ensemble_size,
                 items[0]["count"], d0.use_trust_region,
             )
@@ -1332,7 +1351,7 @@ class GPBanditProgram(compute_ir.DesignerProgram):
 class GPBanditSparseProgram(compute_ir.DesignerProgram):
     """Sparse (SGPR) flush twin: same stages over the collapsed-bound
     posterior, one compiled program per (n-bucket, m-bucket) pair, its own
-    device-phase bucket so ``vizier_jax_phase_seconds`` separates sparse
+    device phase so the ``device.wait`` spans' ``phase`` separates sparse
     from exact time."""
 
     kind = "gp_bandit_sparse"
@@ -1380,16 +1399,17 @@ class GPBanditSparseProgram(compute_ir.DesignerProgram):
         from vizier_tpu.parallel import batch_executor
 
         d0: "VizierGPBandit" = items[0]["designer"]
-        stack = lambda name: batch_executor.place_batch(  # noqa: E731
-            batch_executor.stack_pytrees([it[name] for it in items], pad_to),
-            placement,
+        stacked = batch_executor.stack_members(
+            items, ("md", "rng_train", "rng_acq", "warm"), pad_to, placement
         )
-        with jax_timing.device_phase(self.device_phase) as phase:
+        with jax_timing.device_phase(
+            self.device_phase, **tracing_lib.FUSED_FLUSH
+        ) as phase:
             states, warm_next, result = sparse_bandit._sparse_flush_program(
                 d0._sparse_model(), d0._ard, d0._vec_opt,
                 d0._make_acquisition(),
-                stack("md"), stack("rng_train"), stack("rng_acq"),
-                stack("warm"),
+                stacked["md"], stacked["rng_train"], stacked["rng_acq"],
+                stacked["warm"],
                 items[0]["restarts"], d0.ensemble_size,
                 items[0]["count"], d0.use_trust_region,
             )

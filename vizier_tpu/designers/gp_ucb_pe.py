@@ -56,6 +56,7 @@ from vizier_tpu.models import kernels
 from vizier_tpu.models import multitask_gp as mtgp
 from vizier_tpu.models import output_warpers
 from vizier_tpu.observability import jax_timing
+from vizier_tpu.observability import tracing as tracing_lib
 from vizier_tpu.optimizers import eagle as eagle_lib
 from vizier_tpu.optimizers import vectorized as vectorized_lib
 from vizier_tpu.pyvizier import base_study_config
@@ -1042,18 +1043,12 @@ class VizierGPUCBPEBandit(gp_bandit.VizierGPBandit):
             base=self._model, num_inducing=base.num_inducing + count
         )
 
-    def _train_states_me(self) -> Tuple[gp_lib.GPState, List[gp_lib.GPData]]:
-        """Per-metric GP training: GPState with leading [M, E] + the datas.
-
-        Cached between calls until update() delivers new completed trials —
-        predict()/sample() right after a suggest() reuse the same fit.
-        """
-        if self._cached_states is not None:
-            return self._cached_states
+    def _encode_datas(self) -> List[gp_lib.GPData]:
+        """The host half of a train: the completed trials encoded, warped
+        and padded, one GPData per objective metric."""
         conv = self._converter
         raw = conv.metrics.encode(self._trials)  # [N, M_all], all-MAXIMIZE
         features, n_pad = self._padded_features(self._trials)
-        ensemble = max(self.ensemble_size, 1)
         datas = []
         self._metric_warpers = []
         self._warpers_fitted = raw.shape[0] > 0
@@ -1065,6 +1060,22 @@ class VizierGPUCBPEBandit(gp_bandit.VizierGPBandit):
                 types.ModelData(features, self._padded_labels(warped, n_pad))
             )
             datas.append(data)
+        return datas
+
+    def _train_states_me(
+        self, datas: Optional[List[gp_lib.GPData]] = None
+    ) -> Tuple[gp_lib.GPState, List[gp_lib.GPData]]:
+        """Per-metric GP training: GPState with leading [M, E] + the datas
+        (``_encode_datas()``'s, handed in by a caller that already has them).
+
+        Cached between calls until update() delivers new completed trials —
+        predict()/sample() right after a suggest() reuse the same fit.
+        """
+        if self._cached_states is not None:
+            return self._cached_states
+        if datas is None:
+            datas = self._encode_datas()
+        ensemble = max(self.ensemble_size, 1)
         if (
             len(datas) == 1
             and self._refresh_ucb_pe_surrogate_mode()
@@ -1263,36 +1274,41 @@ class VizierGPUCBPEBandit(gp_bandit.VizierGPBandit):
         if getattr(self, "_priors", None):
             return self._suggest_with_priors(count)
 
-        # The surrogate auto-switch decides the device-phase family up
-        # front (idempotent; ineligible designers always report exact).
-        sparse_mode = (
-            self._refresh_ucb_pe_surrogate_mode()
-            == surrogate_config_lib.MODE_SPARSE
-        )
+        tracer = tracing_lib.get_tracer()
+        # What the host does before the device can start: the completed
+        # trials' encode (skipped when the fit is cached) and everything of
+        # the sweeps' inputs that does not wait for the trained states.
+        with tracer.span("designer.prepare"):
+            # The surrogate auto-switch decides the device-phase family up
+            # front (idempotent; ineligible designers always report exact).
+            sparse_mode = (
+                self._refresh_ucb_pe_surrogate_mode()
+                == surrogate_config_lib.MODE_SPARSE
+            )
+            if self._cached_states is None:
+                datas = self._encode_datas()
+            else:
+                datas = self._cached_states[1]
+            all_data = self._all_points_data(count)
+            labels_mn = jnp.stack([d.labels for d in datas])  # [M, N1]
+            labels_mask = datas[0].row_mask
+            # Reference point: nadir − 0.1·range (Ishibuchi2011, shared helper).
+            ref_point = acquisitions.get_reference_point(labels_mn, labels_mask)
+            first_has_new = jnp.asarray(self._has_new_completed_trials())
+            has_completed = jnp.asarray(bool(self._trials))
+            prior_feats = self._prior_features(datas[0])
         with profiler.timeit("train_gp"):
             # Device-attributed ARD timing (compile vs. steady-state): see
             # gp_bandit.suggest for the rationale; no-op + no device sync
             # when observability is off.
             with jax_timing.device_phase(
-                "sparse_gp.ucb_pe_train_gp" if sparse_mode else "gp_ucb_pe.train_gp"
+                "sparse_gp.ucb_pe_train_gp" if sparse_mode else "gp_ucb_pe.train_gp",
+                stage="train",
             ) as phase:
-                states_me, datas = self._train_states_me()
+                states_me, datas = self._train_states_me(datas)
                 phase.block(states_me)
         is_mt = isinstance(states_me, mtgp.MultiTaskGPState)
         is_sparse = isinstance(states_me, sparse_gp.SparseGPState)
-        if is_mt:
-            self._last_predictive = _MetricZeroMTPredictive(states_me)
-        elif is_sparse:
-            member_states = jax.tree_util.tree_map(lambda a: a[0], states_me)
-            self._last_predictive = sparse_gp.SparseEnsemblePredictive(
-                member_states
-            )
-            self._last_sparse_state = member_states
-        else:
-            self._last_predictive = gp_lib.EnsemblePredictive(
-                jax.tree_util.tree_map(lambda a: a[0], states_me)
-            )
-        all_data = self._all_points_data(count)
         num_metrics = len(datas)
         if num_metrics > 1 and self.config.optimize_set_acquisition_for_exploration:
             raise ValueError(
@@ -1300,18 +1316,11 @@ class VizierGPUCBPEBandit(gp_bandit.VizierGPBandit):
                 "one objective metric."
             )
 
-        labels_mn = jnp.stack([d.labels for d in datas])  # [M, N1]
-        labels_mask = datas[0].row_mask
-        # Reference point: nadir − 0.1·range (Ishibuchi2011, shared helper).
-        ref_point = acquisitions.get_reference_point(labels_mn, labels_mask)
-
-        first_has_new = jnp.asarray(self._has_new_completed_trials())
-        has_completed = jnp.asarray(bool(self._trials))
-
         if (
             self.config.optimize_set_acquisition_for_exploration
             and count > 1
         ):
+            self._remember_fit(states_me)
             return self._suggest_with_set_acquisition(
                 count, states_me, all_data, labels_mn, labels_mask, ref_point,
                 first_has_new, has_completed, datas,
@@ -1337,14 +1346,14 @@ class VizierGPUCBPEBandit(gp_bandit.VizierGPBandit):
             all_data = sparse_gp.with_pending_capacity(sdata0, all_data, count)
         else:
             model = self._model
-        prior_feats = self._prior_features(datas[0])
         results: List[Tuple] = []  # [(result, aux, rows)]
         # Device-attributed sweep timing; the block_until_ready calls on the
         # batch scores below already pin device time inside this phase.
         with profiler.timeit("acquisition_optimizer"), jax_timing.device_phase(
             "sparse_gp.ucb_pe_acquisition"
             if is_sparse
-            else "gp_ucb_pe.acquisition"
+            else "gp_ucb_pe.acquisition",
+            stage="acquire",
         ):
             if self.acquisition_budget_policy == "first_pick_full" and count > 1:
                 # Full budget on the exploitation-critical first pick; one
@@ -1406,11 +1415,32 @@ class VizierGPUCBPEBandit(gp_bandit.VizierGPBandit):
                 results = [(batch, aux, count)]
         if is_sparse:
             self._surrogate_counts["sparse_suggests"] += 1
-        with profiler.timeit("best_candidates_to_trials"):
+        with profiler.timeit("best_candidates_to_trials"), tracer.span(
+            "designer.decode"
+        ):
+            # The fit's writeback belongs to the decode, as in the flush
+            # programs' finalize: a device slice per leaf, which the sweeps
+            # above do not wait for.
+            self._remember_fit(states_me)
             out: List[trial_.TrialSuggestion] = []
             for result, aux, rows in results:
                 out.extend(self._decode_ucb_pe(result, aux, rows))
             return out
+
+    def _remember_fit(self, states_me) -> None:
+        """Keeps metric 0's trained posterior for predict()/sample()."""
+        if isinstance(states_me, mtgp.MultiTaskGPState):
+            self._last_predictive = _MetricZeroMTPredictive(states_me)
+        elif isinstance(states_me, sparse_gp.SparseGPState):
+            member_states = jax.tree_util.tree_map(lambda a: a[0], states_me)
+            self._last_predictive = sparse_gp.SparseEnsemblePredictive(
+                member_states
+            )
+            self._last_sparse_state = member_states
+        else:
+            self._last_predictive = gp_lib.EnsemblePredictive(
+                jax.tree_util.tree_map(lambda a: a[0], states_me)
+            )
 
     def _suggest_with_set_acquisition(
         self, count, states_me, all_data, labels_mn, labels_mask, ref_point,
@@ -1640,31 +1670,44 @@ def _ucb_pe_prepare(
     )
 
 
+def _ucb_pe_stacked_names(two_phase: bool) -> Tuple[str, ...]:
+    """The prepared entries a UCB-PE flush stacks along the study axis."""
+    names = (
+        "md", "all_md", "rng_train", "rng_acq", "warm", "first_has_new",
+        "has_completed",
+    )
+    return names + ("rng_acq_rest",) if two_phase else names
+
+
 def _ucb_pe_demux(items, states, warm_next, data, segments, rows, sparse: bool):
     """ONE device->host fetch for everything the demux needs; per-slot
-    slices below are then free numpy views."""
+    slices below are then free numpy views. The per-flush half of the fused
+    path's ``designer.decode`` stage (``finalize`` is the per-slot half)."""
     from vizier_tpu.parallel import batch_executor
 
-    states, warm_next, data, segments = jax.device_get(
-        (states, warm_next, data, segments)
-    )
-    return [
-        dict(
-            states=batch_executor.slice_pytree(states, i),
-            warm_next=batch_executor.slice_pytree(warm_next, i),
-            data=batch_executor.slice_pytree(data, i),
-            segments=[
-                (
-                    batch_executor.slice_pytree(result, i),
-                    batch_executor.slice_pytree(aux, i),
-                    n,
-                )
-                for (result, aux), n in zip(segments, rows)
-            ],
-            sparse=sparse,
+    with tracing_lib.get_tracer().span(
+        "designer.decode", **tracing_lib.FUSED_FLUSH
+    ):
+        states, warm_next, data, segments = jax.device_get(
+            (states, warm_next, data, segments)
         )
-        for i in range(len(items))
-    ]
+        return [
+            dict(
+                states=batch_executor.slice_pytree(states, i),
+                warm_next=batch_executor.slice_pytree(warm_next, i),
+                data=batch_executor.slice_pytree(data, i),
+                segments=[
+                    (
+                        batch_executor.slice_pytree(result, i),
+                        batch_executor.slice_pytree(aux, i),
+                        n,
+                    )
+                    for (result, aux), n in zip(segments, rows)
+                ],
+                sparse=sparse,
+            )
+            for i in range(len(items))
+        ]
 
 
 class UCBPEProgram(compute_ir.DesignerProgram):
@@ -1718,22 +1761,23 @@ class UCBPEProgram(compute_ir.DesignerProgram):
         from vizier_tpu.parallel import batch_executor
 
         d0: "VizierGPUCBPEBandit" = items[0]["designer"]
-        stack = lambda name: batch_executor.place_batch(  # noqa: E731
-            batch_executor.stack_pytrees([it[name] for it in items], pad_to),
-            placement,
-        )
         count = items[0]["count"]
         two_phase = (
             d0.acquisition_budget_policy == "first_pick_full" and count > 1
         )
-        rng_a = stack("rng_acq")
-        with jax_timing.device_phase(self.device_phase) as phase:
+        stacked = batch_executor.stack_members(
+            items, _ucb_pe_stacked_names(two_phase), pad_to, placement
+        )
+        with jax_timing.device_phase(
+            self.device_phase, **tracing_lib.FUSED_FLUSH
+        ) as phase:
             states, warm_next, data, segments = _ucb_pe_flush_program(
                 d0._model, d0._ard, d0._vec_opt, d0._pick_vec_opt(count),
-                stack("md"), stack("all_md"),
-                stack("rng_train"), rng_a,
-                stack("rng_acq_rest") if two_phase else rng_a,
-                stack("warm"), stack("first_has_new"), stack("has_completed"),
+                stacked["md"], stacked["all_md"],
+                stacked["rng_train"], stacked["rng_acq"],
+                stacked["rng_acq_rest" if two_phase else "rng_acq"],
+                stacked["warm"], stacked["first_has_new"],
+                stacked["has_completed"],
                 items[0]["restarts"], d0._batch_ensemble(), count,
                 d0.config, d0.use_trust_region, two_phase,
             )
@@ -1822,23 +1866,24 @@ class UCBPESparseProgram(compute_ir.DesignerProgram):
         from vizier_tpu.parallel import batch_executor
 
         d0: "VizierGPUCBPEBandit" = items[0]["designer"]
-        stack = lambda name: batch_executor.place_batch(  # noqa: E731
-            batch_executor.stack_pytrees([it[name] for it in items], pad_to),
-            placement,
-        )
         count = items[0]["count"]
         two_phase = (
             d0.acquisition_budget_policy == "first_pick_full" and count > 1
         )
-        rng_a = stack("rng_acq")
-        with jax_timing.device_phase(self.device_phase) as phase:
+        stacked = batch_executor.stack_members(
+            items, _ucb_pe_stacked_names(two_phase), pad_to, placement
+        )
+        with jax_timing.device_phase(
+            self.device_phase, **tracing_lib.FUSED_FLUSH
+        ) as phase:
             states, warm_next, data, segments = _sparse_ucb_pe_flush_program(
                 d0._sparse_model(), d0._sparse_all_model(count),
                 d0._ard, d0._vec_opt, d0._pick_vec_opt(count),
-                stack("md"), stack("all_md"),
-                stack("rng_train"), rng_a,
-                stack("rng_acq_rest") if two_phase else rng_a,
-                stack("warm"), stack("first_has_new"), stack("has_completed"),
+                stacked["md"], stacked["all_md"],
+                stacked["rng_train"], stacked["rng_acq"],
+                stacked["rng_acq_rest" if two_phase else "rng_acq"],
+                stacked["warm"], stacked["first_has_new"],
+                stacked["has_completed"],
                 items[0]["restarts"], d0._batch_ensemble(), count,
                 d0.config, d0.use_trust_region, two_phase,
             )

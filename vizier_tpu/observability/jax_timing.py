@@ -1,20 +1,25 @@
-"""JAX-aware phase timing: device-attributed spans, compile vs. execute.
+"""JAX-aware phase timing: the ``device.wait`` stage span.
 
 JAX dispatch is asynchronous — wall-clocking a jitted call measures
 *enqueue*, not device work, and the cost silently lands on whatever later
-op first blocks. :func:`device_phase` wraps a designer hot-path stage in a
-span and has the caller ``block()`` the stage's outputs *inside* it, so
-device time is attributed to the right phase:
+op first blocks. :func:`device_phase` wraps a designer hot-path stage in
+the ``device.wait`` stage span (``observability/tracing.py``) and has the
+caller ``block()`` the stage's outputs *inside* it, so the time the host
+spends blocked on the chip is attributed to the right phase:
 
-    with jax_timing.device_phase("gp_bandit.train_gp") as phase:
+    with jax_timing.device_phase("gp_bandit.train_gp", stage="train") as phase:
         states = self._train(...)
         phase.block(states)
 
-The first occurrence of a phase name in the process is recorded as
-``mode="compile"`` (trace + lower + compile dominates it), later ones as
-``mode="execute"`` — the steady-state serving number. Both land in the
-global metrics registry as ``vizier_jax_phase_seconds{phase=...,mode=...}``
-and on the span as attributes.
+The span's attributes say which phase it was: ``phase`` (the name given
+here — for a flush program, its ``DesignerProgram.device_phase``), ``stage``
+(``train`` / ``acquire`` on the sequential path), ``path`` (``sequential`` /
+``fused``), ``per`` (``flush`` for a fused flush's one wait), ``first_call``
+and ``mode`` — the first occurrence of a phase name in the process is
+``mode="compile"`` (trace + lower + compile dominates it), later ones
+``mode="execute"``, the steady-state serving number. Like every stage span
+it is observed into ``vizier_suggest_stage_seconds{stage="device.wait",...}``
+in the serving runtime's registry and annotates the ``jax.profiler`` trace.
 
 With observability (or the JAX knob) off, the phase object is inert and —
 deliberately — does NOT ``block_until_ready``: the production path keeps
@@ -24,11 +29,9 @@ JAX's async pipelining, so the off switch costs nothing.
 from __future__ import annotations
 
 import threading
-import time
 from typing import Any, Optional, Set
 
 from vizier_tpu.observability import config as config_lib
-from vizier_tpu.observability import metrics as metrics_lib
 from vizier_tpu.observability import tracing as tracing_lib
 
 _seen_lock = threading.Lock()
@@ -89,34 +92,26 @@ _DISABLED_PHASE = _Phase("", enabled=False, first_call=False)
 
 
 class _PhaseCM:
-    __slots__ = ("_phase", "_registry", "_span_cm", "_span", "_t0")
+    __slots__ = ("_phase", "_span_cm")
 
-    def __init__(self, phase: _Phase, registry: metrics_lib.MetricsRegistry):
+    def __init__(self, phase: _Phase, path: str, per: str, stage: Optional[str]):
         self._phase = phase
-        self._registry = registry
+        attributes = {"stage": stage} if stage else {}
         self._span_cm = tracing_lib.get_tracer().span(
-            f"jax.{phase.name}",
-            jax_phase=phase.name,
+            "device.wait",
+            phase=phase.name,
+            path=path,
+            per=per,
             first_call=phase.first_call,
+            mode="compile" if phase.first_call else "execute",
+            **attributes,
         )
-        self._span = None
-        self._t0 = 0.0
 
     def __enter__(self) -> _Phase:
-        self._span = self._span_cm.__enter__()
-        self._t0 = time.perf_counter()
+        self._span_cm.__enter__()
         return self._phase
 
     def __exit__(self, exc_type, exc, tb) -> bool:
-        duration = time.perf_counter() - self._t0
-        mode = "compile" if self._phase.first_call else "execute"
-        if exc is None:
-            self._registry.histogram(
-                "vizier_jax_phase_seconds",
-                help="Designer JAX phase wall time, device-synced; "
-                "mode=compile is the first call per phase.",
-            ).observe(duration, phase=self._phase.name, mode=mode)
-        self._span.set_attribute("mode", mode)
         return self._span_cm.__exit__(exc_type, exc, tb)
 
 
@@ -134,10 +129,14 @@ _DISABLED_CM = _DisabledPhaseCM()
 
 
 def device_phase(
-    name: str, registry: Optional[metrics_lib.MetricsRegistry] = None
+    name: str,
+    *,
+    path: str = tracing_lib.PATH_SEQUENTIAL,
+    per: str = tracing_lib.PER_REQUEST,
+    stage: Optional[str] = None,
 ):
     """Times one device phase (see module docstring for the contract)."""
     if not _jax_profiling_on():
         return _DISABLED_CM
     phase = _Phase(name, enabled=True, first_call=_mark_seen(name))
-    return _PhaseCM(phase, registry or metrics_lib.default_registry())
+    return _PhaseCM(phase, path, per, stage)

@@ -13,9 +13,26 @@ only stamps the start for human-readable export). Finished spans land in a
 bounded ring buffer (no leak under sustained traffic) and can be dumped as
 JSON lines — no third-party deps anywhere.
 
+Every span is also a ``jax.profiler.TraceAnnotation`` of its name, on the
+thread that runs it: with a profiler session active the span lands in the
+trace's host planes, on the device trace's clock; with none active a TraceMe
+costs under a microsecond. A **stage span** is a span whose name is in
+:data:`STAGES` — the fixed vocabulary of what a served suggest spends its
+host time on. On a clean exit it also observes its duration into ONE
+histogram, ``vizier_suggest_stage_seconds{stage,path,per}``, in the registry
+the serving runtime bound with :meth:`Tracer.bind_registry` (none bound:
+nothing observed). ``path`` is the span's ``path`` attribute: ``fused`` at the
+sites inside a batch-executor flush, ``sequential`` everywhere else (the
+service and policy stages run the same code whichever way the designer
+computes); ``per`` is ``flush`` where a site runs once for all members of a
+fused flush, else ``request``.
+No bookkeeping of a span — annotation, histogram, export — can raise into
+the request: a failure there is dropped and counted in
+``vizier_tracing_errors_total``.
+
 With observability off, :func:`get_tracer` returns the singleton
 :data:`NOOP_TRACER` whose ``span()`` hands back a reusable no-op context
-manager: no allocation, no contextvar write, ≈ zero overhead.
+manager: no allocation, no contextvar write, no annotation, ≈ zero overhead.
 """
 
 from __future__ import annotations
@@ -30,6 +47,50 @@ import time
 from typing import Any, Dict, List, Optional, Union
 
 from vizier_tpu.observability import config as config_lib
+
+# The stages of a served suggest (docs/guides/observability.md has the table
+# of where each is opened). Once a request, except on the fused path
+# ``flush.stack``, ``device.wait`` and the demux half of ``designer.decode``:
+# once a flush (``per="flush"``).
+STAGES = frozenset(
+    {
+        "service.read",  # study fetch, open-trial claim, the Pythia request
+        "policy.load_trials",  # both GetTrials: datastore -> proto -> pyvizier
+        "designer.update",  # new trials into the designer
+        "designer.prepare",  # host encode / padding / RNG before the device
+        "flush.stack",  # host re-stack + upload of a fused flush's members
+        "device.wait",  # host blocked on the chip
+        "designer.decode",  # device results -> suggestions with metadata
+        "service.write",  # create_trial x count, metadata deltas, the op
+    }
+)
+STAGE_HISTOGRAM = "vizier_suggest_stage_seconds"
+ERRORS_COUNTER = "vizier_tracing_errors"  # rendered with the _total suffix
+PATH_SEQUENTIAL = "sequential"
+PATH_FUSED = "fused"
+# How often a stage site runs: once a request, or once a fused flush for
+# all of its members (the readers divide the two differently).
+PER_REQUEST = "request"
+PER_FLUSH = "flush"
+# The attributes of a stage site that runs once a fused flush.
+FUSED_FLUSH = {"path": PATH_FUSED, "per": PER_FLUSH}
+
+# jax.profiler.TraceAnnotation, imported on the first span: None = not yet
+# looked for, False = not importable (a stdlib-only process).
+_annotation_cls: Any = None
+
+
+def _trace_annotation_cls():
+    global _annotation_cls
+    if _annotation_cls is None:
+        try:
+            from jax.profiler import TraceAnnotation
+
+            _annotation_cls = TraceAnnotation
+        except Exception:
+            _annotation_cls = False
+    return _annotation_cls
+
 
 # The active span (or a remote SpanContext attached via use_context).
 _SPAN_VAR: contextvars.ContextVar = contextvars.ContextVar(
@@ -229,15 +290,17 @@ _NOOP_CM = _NoopSpanCM()
 class _SpanCM:
     """Context manager for one active span (cheaper than a generator CM)."""
 
-    __slots__ = ("_tracer", "_span", "_token")
+    __slots__ = ("_tracer", "_span", "_token", "_annotation")
 
     def __init__(self, tracer: "Tracer", span: Span):
         self._tracer = tracer
         self._span = span
         self._token = None
+        self._annotation = None
 
     def __enter__(self) -> Span:
         self._token = _SPAN_VAR.set(self._span)
+        self._annotation = self._tracer._annotate(self._span.name)
         return self._span
 
     def __exit__(self, exc_type, exc, tb) -> bool:
@@ -246,7 +309,7 @@ class _SpanCM:
         if exc is not None:
             self._span.record_exception(exc)
         self._span.end()
-        self._tracer._export(self._span)
+        self._tracer._finish(self._span, self._annotation)
         return False
 
 
@@ -289,6 +352,23 @@ class Tracer:
         )
         self._export_path = export_path or None
         self._export_file = None
+        self._stage_seconds = None  # the bound registry's stage histogram
+        self._errors = None  # ... and its dropped-bookkeeping counter
+
+    def bind_registry(self, registry: Any) -> None:
+        """The registry stage spans observe into and bookkeeping failures
+        are counted in: the serving runtime's, handed over when it is built
+        (the last runtime built in a process wins)."""
+        self._stage_seconds = registry.histogram(
+            STAGE_HISTOGRAM,
+            help="Host wall time of one stage of a served suggest "
+            "(stage spans; path=fused inside a batch-executor flush).",
+        )
+        self._errors = registry.counter(
+            ERRORS_COUNTER,
+            help="Span bookkeeping failures dropped instead of raised "
+            "into the request.",
+        )
 
     # -- span lifecycle ----------------------------------------------------
 
@@ -321,6 +401,55 @@ class Tracer:
         if isinstance(cur, SpanContext):
             return cur
         return None
+
+    # -- bookkeeping that must never raise into the request ----------------
+
+    def _dropped(self) -> None:
+        try:
+            if self._errors is not None:
+                self._errors.inc()
+        except Exception:
+            pass
+
+    def _annotate(self, name: str) -> Any:
+        """Enters a profiler annotation of ``name``; None when there is no
+        profiler to annotate for (or entering it failed)."""
+        try:
+            cls = _trace_annotation_cls()
+            if not cls:
+                return None
+            annotation = cls(name)
+            annotation.__enter__()
+            return annotation
+        except Exception:
+            self._dropped()
+            return None
+
+    def _finish(self, span: Span, annotation: Any) -> None:
+        if annotation is not None:
+            try:
+                annotation.__exit__(None, None, None)
+            except Exception:
+                self._dropped()
+        # A stage whose body raised is not a sample of that stage's time.
+        if (
+            self._stage_seconds is not None
+            and span.name in STAGES
+            and span.status == "ok"
+        ):
+            try:
+                self._stage_seconds.observe(
+                    span.duration_secs,
+                    stage=span.name,
+                    path=span.attributes.get("path", PATH_SEQUENTIAL),
+                    per=span.attributes.get("per", PER_REQUEST),
+                )
+            except Exception:
+                self._dropped()
+        try:
+            self._export(span)
+        except Exception:
+            self._dropped()
 
     # -- export ------------------------------------------------------------
 
@@ -375,6 +504,9 @@ class NoopTracer:
     """The off switch: same API, no state, no allocation per span."""
 
     enabled = False
+
+    def bind_registry(self, registry: Any) -> None:
+        pass
 
     def span(self, name: str, parent: Parent = None, **attributes: Any):
         return _NOOP_CM

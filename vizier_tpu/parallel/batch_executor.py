@@ -73,8 +73,8 @@ Batchable designers implement ONE :class:`~vizier_tpu.compute.ir.
 DesignerProgram` (bucket_key / prepare / device_program / finalize),
 registered in :mod:`vizier_tpu.compute.registry`; the executor resolves a
 designer's program there and consumes it generically — the same registry
-feeds the prewarm walker, chaos slot-isolation wrappers, the
-``vizier_jax_phase_seconds`` device phases, and the speculative lane.
+feeds the prewarm walker, chaos slot-isolation wrappers,
+the ``device.wait`` stage span's ``phase``, and the speculative lane.
 Designers carrying only the legacy duck-typed ``batch_*`` methods (test
 stubs, out-of-tree extensions) resolve to an adapter; anything else runs
 sequentially.
@@ -221,6 +221,43 @@ def place_batch(tree: Any, placement: Optional[Any] = None) -> Any:
     if placement is None:
         return tree
     return placement.shard(tree)
+
+
+def stack_members(
+    items: Sequence[dict],
+    names: Sequence[str],
+    pad_to: Optional[int] = None,
+    placement: Optional[Any] = None,
+) -> Dict[str, Any]:
+    """Every named entry of a flush's members, stacked along the study axis
+    and placed: the host re-stack and upload of a fused flush, once per
+    flush — the ``flush.stack`` stage, whose ``bytes`` attribute is the
+    total size of what was placed."""
+    with tracing_lib.get_tracer().span(
+        "flush.stack", members=len(items), **tracing_lib.FUSED_FLUSH
+    ) as span:
+        stacked = {
+            name: place_batch(
+                stack_pytrees([item[name] for item in items], pad_to), placement
+            )
+            for name in names
+        }
+        span.set_attribute("bytes", _tree_nbytes(stacked))
+    return stacked
+
+
+def _tree_nbytes(tree: Any) -> int:
+    """Total ``nbytes`` of a pytree's array leaves; -1 if it cannot be told
+    (a span attribute must never fail the flush it describes)."""
+    try:
+        import jax
+
+        return sum(
+            int(getattr(leaf, "nbytes", 0))
+            for leaf in jax.tree_util.tree_leaves(tree)
+        )
+    except Exception:
+        return -1
 
 
 def slice_pytree(tree: Any, index: int) -> Any:
@@ -400,7 +437,7 @@ class BatchExecutor:
             will_batch = bool(self._queues.get(key))
         if will_batch:
             try:
-                slot.item = program.prepare(designer, count)
+                slot.item = self._prepare(slot)
             except BaseException:
                 self._increment("batch_slot_errors")
                 raise
@@ -412,8 +449,20 @@ class BatchExecutor:
                 self._cond.notify_all()
         if closed:
             return designer.suggest(count)
-        slot.event.wait()
+        # Parked until the scheduler's verdict: the queue (timed by
+        # vizier_batch_queue_wait_seconds) and then the flush itself.
+        with tracer.span("batch_executor.queue_wait"):
+            slot.event.wait()
         return self._complete(slot)
+
+    @staticmethod
+    def _prepare(slot: _Slot) -> dict:
+        """A slot's host-side prepare, as a stage of ITS request: the slot
+        carries the submitter's span, whichever thread runs this."""
+        with tracing_lib.get_tracer().span(
+            "designer.prepare", parent=slot.span, path=tracing_lib.PATH_FUSED
+        ):
+            return slot.program.prepare(slot.designer, slot.count)
 
     def _complete(self, slot: _Slot) -> List[Any]:
         """Runs the scheduler's verdict on the waiting thread."""
@@ -421,9 +470,14 @@ class BatchExecutor:
             raise slot.error
         if slot.action == "batched":
             try:
-                suggestions = list(
-                    slot.program.finalize(slot.designer, slot.item, slot.output)
-                )
+                with tracing_lib.get_tracer().span(
+                    "designer.decode", path=tracing_lib.PATH_FUSED
+                ):
+                    suggestions = list(
+                        slot.program.finalize(
+                            slot.designer, slot.item, slot.output
+                        )
+                    )
                 check_finite_suggestions(suggestions)
             except BaseException:
                 self._increment("batch_slot_errors")
@@ -882,7 +936,7 @@ class BatchExecutor:
         for slot in slots:
             if slot.item is None:
                 try:
-                    slot.item = slot.program.prepare(slot.designer, slot.count)
+                    slot.item = self._prepare(slot)
                 except BaseException as e:
                     slot.error = e
                     self._increment("batch_slot_errors")

@@ -295,65 +295,105 @@ class VizierServicer:
                 )
             )
             return op
-        with self._study_locks[study_name]:
-            study = self.datastore.load_study(study_name)
-            if study.state != study_pb2.Study.ACTIVE:
-                raise ValueError(f"Study {study_name} is not ACTIVE.")
+        # The host half of the service hop is two stage spans around the
+        # Pythia dispatch: service.read (what is fetched and claimed before
+        # it) and service.write (what is persisted after it).
+        tracer = tracing_lib.get_tracer()
+        preq = None
+        failure: Optional[Exception] = None
+        with tracer.span("service.read", study=study_name):
+            with self._study_locks[study_name]:
+                study = self.datastore.load_study(study_name)
+                if study.state != study_pb2.Study.ACTIVE:
+                    raise ValueError(f"Study {study_name} is not ACTIVE.")
 
-            # Op dedup: an unfinished op for this client is returned as-is —
-            # unless it was orphaned by a server crash (persisted not-done
-            # but not in flight here), in which case it is failed and retried.
-            unfinished = self.datastore.list_suggestion_operations(
-                study_name, client_id, done=False
+                # Op dedup: an unfinished op for this client is returned
+                # as-is — unless it was orphaned by a server crash
+                # (persisted not-done but not in flight here), in which case
+                # it is failed and retried.
+                unfinished = self.datastore.list_suggestion_operations(
+                    study_name, client_id, done=False
+                )
+                for op in unfinished:
+                    if op.name in self._inflight_ops:
+                        return op
+                    op.done = True
+                    op.error = "Orphaned by server restart; retry."
+                    self.datastore.update_suggestion_operation(op)
+
+                op_number = self.datastore.max_suggestion_operation_number(
+                    study_name, client_id
+                ) + 1
+                sr = resources.StudyResource.from_name(study_name)
+                op = vizier_service_pb2.Operation(
+                    name=resources.SuggestionOperationResource(
+                        sr.owner_id, sr.study_id, client_id, op_number
+                    ).name
+                )
+                self.datastore.create_suggestion_operation(op)
+                self._inflight_ops.add(op.name)
+
+            # The Pythia dispatch runs OUTSIDE the study lock: the lock
+            # protects datastore read-modify-write windows, not the
+            # designer computation. Concurrent clients therefore reach
+            # Pythia with the same trial frontier and coalesce onto ONE
+            # computation (vizier_tpu.serving); a same-client retry
+            # meanwhile sees the not-done op above and polls GetOperation,
+            # the reference's long-running-operation contract.
+            #
+            # The client's deadline budget (request.deadline_secs, remaining
+            # seconds) becomes a Deadline here and is decremented across
+            # every hop below; transient failures are marked TRANSIENT: in
+            # op.error so client retry logic can tell them from permanent
+            # errors.
+            deadline = (
+                deadline_lib.Deadline.from_budget(request.deadline_secs)
+                if self._reliability.deadlines_on
+                else deadline_lib.Deadline.none()
             )
-            for op in unfinished:
-                if op.name in self._inflight_ops:
-                    return op
-                op.done = True
-                op.error = "Orphaned by server restart; retry."
-                self.datastore.update_suggestion_operation(op)
-
-            op_number = self.datastore.max_suggestion_operation_number(
-                study_name, client_id
-            ) + 1
-            sr = resources.StudyResource.from_name(study_name)
-            op = vizier_service_pb2.Operation(
-                name=resources.SuggestionOperationResource(
-                    sr.owner_id, sr.study_id, client_id, op_number
-                ).name
-            )
-            self.datastore.create_suggestion_operation(op)
-            self._inflight_ops.add(op.name)
-
-        # The Pythia dispatch runs OUTSIDE the study lock (see _suggest):
-        # the lock protects datastore read-modify-write windows, not the
-        # designer computation. Concurrent clients therefore reach Pythia
-        # with the same trial frontier and coalesce onto ONE computation
-        # (vizier_tpu.serving); a same-client retry meanwhile sees the
-        # not-done op above and polls GetOperation, the reference's
-        # long-running-operation contract.
-        #
-        # The client's deadline budget (request.deadline_secs, remaining
-        # seconds) becomes a Deadline here and is decremented across every
-        # hop below; transient failures are marked TRANSIENT: in op.error
-        # so client retry logic can tell them from permanent errors.
-        deadline = (
-            deadline_lib.Deadline.from_budget(request.deadline_secs)
-            if self._reliability.deadlines_on
-            else deadline_lib.Deadline.none()
-        )
+            trials: List[study_pb2.Trial] = []
+            try:
+                trials, preq = self._claim_or_request(
+                    study, study_name, client_id, request, deadline, op.name
+                )
+            except Exception as e:  # captured into the long-running op
+                failure = e
+        presp = None
         try:
-            trials = self._suggest(
-                study, study_name, client_id, request, deadline, op.name
-            )
-            op.response.trials.extend(trials)
-        except Exception as e:  # captured into the long-running op
-            op.error = errors_lib.format_op_error(e)
+            if preq is not None:
+                presp = self._ask_pythia(preq, deadline, op.name)
+        except Exception as e:
+            failure = e
         finally:
+            with tracer.span("service.write", study=study_name):
+                self._write_back(
+                    op, study_name, client_id, preq, presp, trials, failure
+                )
+        return op
+
+    def _write_back(
+        self, op, study_name: str, client_id: str, preq, presp,
+        trials: List[study_pb2.Trial], failure: Optional[Exception],
+    ) -> None:
+        """Persists what the request came to: Pythia's suggestions as
+        trials and its metadata deltas, then the finished operation —
+        carrying the trials, or the failure."""
+        try:
+            if presp is not None:
+                self._materialize(
+                    study_name, client_id, preq.count, presp, trials
+                )
+            # (A dispatch cut short by a BaseException leaves neither.)
+            if failure is None and (preq is None or presp is not None):
+                op.response.trials.extend(trials)
+        except Exception as e:
+            failure = e
+        finally:
+            if failure is not None:
+                op.error = errors_lib.format_op_error(failure)
             op.done = True
             self.datastore.update_suggestion_operation(op)
             self._inflight_ops.discard(op.name)
-        return op
 
     def _claim_open_trials(
         self, study_name: str, client_id: str, count: int, *, reuse_active: bool = True
@@ -398,23 +438,26 @@ class VizierServicer:
                 out.append(t)
         return out, False
 
-    def _suggest(
+    def _claim_or_request(
         self,
         study: study_pb2.Study,
         study_name: str,
         client_id: str,
         request: vizier_service_pb2.SuggestTrialsRequest,
-        deadline: deadline_lib.Deadline = deadline_lib.Deadline.none(),
-        operation_name: str = "",
-    ) -> List[study_pb2.Trial]:
+        deadline: deadline_lib.Deadline,
+        operation_name: str,
+    ):
+        """The open trials this client can be handed at once and, when they
+        fall short of the count, the Pythia request for the remainder
+        (else None)."""
         count = request.suggestion_count or 1
         with self._study_locks[study_name]:
             out, reused = self._claim_open_trials(study_name, client_id, count)
             if reused or len(out) >= count:
-                return out
+                return out, None
             max_id = self.datastore.max_trial_id(study_name)
 
-        # 3. Ask Pythia for the remainder — lock released, so concurrent
+        # Ask Pythia for the remainder — lock released, so concurrent
         # clients' identical requests can coalesce at the compute level.
         if self._pythia is None:
             raise RuntimeError("No Pythia endpoint connected to the Vizier service.")
@@ -430,10 +473,13 @@ class VizierServicer:
         preq.study_descriptor.config.CopyFrom(study.study_spec)
         preq.study_descriptor.guid = study_name
         preq.study_descriptor.max_trial_id = max_id
+        return out, preq
+
+    def _ask_pythia(self, preq, deadline: deadline_lib.Deadline, operation_name: str):
         tracer = tracing_lib.get_tracer()
         with tracer.span(
             "service.pythia_dispatch",
-            study=study_name,
+            study=preq.study_name,
             deadline_remaining_secs=(
                 deadline.remaining() if deadline.is_set else 0.0
             ),
@@ -448,7 +494,19 @@ class VizierServicer:
             if errors_lib.has_transient_marker(presp.error):
                 raise errors_lib.TransientError(f"Pythia error: {presp.error}")
             raise RuntimeError(f"Pythia error: {presp.error}")
+        return presp
 
+    def _materialize(
+        self,
+        study_name: str,
+        client_id: str,
+        wanted: int,
+        presp,
+        out: List[study_pb2.Trial],
+    ) -> None:
+        """Pythia's suggestions as trials of the study, appended to ``out``
+        (the trials already claimed) until ``wanted`` more are there."""
+        count = len(out) + wanted
         sr = resources.StudyResource.from_name(study_name)
         with self._study_locks[study_name]:
             # Re-drain first: a coalesced peer that shared this computation
@@ -504,7 +562,6 @@ class VizierServicer:
                     self.datastore.update_metadata(study_name, study_kvs, trial_kvs)
                 except datastore_lib.NotFoundError as e:
                     _logger.warning("Dropping policy metadata delta: %s", e)
-        return out
 
     def _dispatch_pythia(self, preq, deadline: deadline_lib.Deadline, operation_name: str):
         """Runs the Pythia Suggest, bounded by the remaining deadline.

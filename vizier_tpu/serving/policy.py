@@ -98,13 +98,19 @@ class CachedDesignerStatePolicy(policy_lib.Policy):
     ) -> List[trial_.TrialSuggestion]:
         designer = entry.designer
         tracer = tracing_lib.get_tracer()
-        completed = self._supporter.GetTrials(
-            status_matches=trial_.TrialStatus.COMPLETED
-        )
-        new_completed = [
-            t for t in completed if t.id not in entry.incorporated_trial_ids
-        ]
-        active = self._supporter.GetTrials(status_matches=trial_.TrialStatus.ACTIVE)
+        # Every completed trial of the study, datastore -> proto ->
+        # pyvizier, on every suggest: a stage of its own.
+        with tracer.span("policy.load_trials", study=self._study_name) as load:
+            completed = self._supporter.GetTrials(
+                status_matches=trial_.TrialStatus.COMPLETED
+            )
+            new_completed = [
+                t for t in completed if t.id not in entry.incorporated_trial_ids
+            ]
+            active = self._supporter.GetTrials(
+                status_matches=trial_.TrialStatus.ACTIVE
+            )
+            load.set_attribute("completed", len(completed))
         before = self._train_counts(designer)
         surrogate_before = self._surrogate_counts(designer)
         with tracer.span(

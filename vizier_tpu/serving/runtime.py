@@ -20,6 +20,7 @@ from vizier_tpu.observability import config as obs_config_lib
 from vizier_tpu.observability import flight_recorder as recorder_lib
 from vizier_tpu.observability import metrics as metrics_lib
 from vizier_tpu.observability import slo as slo_lib
+from vizier_tpu.observability import tracing as tracing_lib
 from vizier_tpu.reliability import breaker as breaker_lib
 from vizier_tpu.reliability import config as reliability_config_lib
 from vizier_tpu.serving import admission as admission_lib
@@ -63,6 +64,11 @@ class ServingRuntime:
         # passing pre-existing stats brings its registry along so counters
         # and histograms still land in one dump.
         self.metrics: metrics_lib.MetricsRegistry = self.stats.registry
+        # The tracer's stage spans observe into the same registry
+        # (vizier_suggest_stage_seconds), like the executor's and the
+        # coalescer's histograms below.
+        if self.observability.metrics_on:
+            tracing_lib.get_tracer().bind_registry(self.metrics)
         self.reliability = (
             reliability or reliability_config_lib.ReliabilityConfig.from_env()
         )

@@ -67,19 +67,6 @@ class _Storage:
 
 _storage = _Storage()
 
-_tracing_mod = None
-
-
-def _tracer():
-    """The observability tracer, lazily bound (no import cycle: the
-    observability package never imports utils.profiler)."""
-    global _tracing_mod
-    if _tracing_mod is None:
-        from vizier_tpu.observability import tracing as _tracing_mod_
-
-        _tracing_mod = _tracing_mod_
-    return _tracing_mod.get_tracer()
-
 
 def collect_events():
     """Context manager enabling collection; yields the event list."""
@@ -88,16 +75,12 @@ def collect_events():
 
 @contextlib.contextmanager
 def timeit(name: str, also_log: bool = False):
-    """Times a block (nested scopes join with ``::``).
-
-    Also opens a ``profiler.<name>`` span on the observability tracer, so
-    the per-phase timers that already annotate the designer hot path
-    (convert_trials, train_gp, acquisition_optimizer, ...) show up inside
-    the request's trace for free. A no-op CM when tracing is off.
-    """
+    """Times a block (nested scopes join with ``::``) into the event
+    store. The served path's spans are the stage spans of
+    ``observability/tracing.py``, opened at the same sites."""
     full = _storage.scoped_name(name)
     start = time.perf_counter()
-    with _storage.push_scope(name), _tracer().span(f"profiler.{name}"):
+    with _storage.push_scope(name):
         yield
     duration = time.perf_counter() - start
     _storage.add(
