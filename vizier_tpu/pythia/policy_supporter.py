@@ -7,7 +7,7 @@ from __future__ import annotations
 
 import abc
 import datetime
-from typing import Iterable, List, Optional
+from typing import Collection, Iterable, List, Optional, Tuple
 
 from vizier_tpu.pythia import errors
 from vizier_tpu.pyvizier import study_config as sc
@@ -33,6 +33,25 @@ class PolicySupporter(abc.ABC):
         include_intermediate_measurements: bool = True,
     ) -> List[trial_.Trial]:
         """Fetches trials matching the filters."""
+
+    def GetTrialDelta(
+        self, held_ids: Collection[int]
+    ) -> Tuple[List[trial_.Trial], List[trial_.Trial], int]:
+        """What a holder of the completed trials ``held_ids`` still lacks.
+
+        ``(new_completed, active, num_completed)``: the completed trials
+        not held, every ACTIVE trial, and the completed trials counted,
+        held or new (so ``num_completed - len(new_completed)`` is what the
+        holder reuses, never negative). The default reads everything once;
+        a supporter with a cheaper read behind it overrides this.
+        """
+        trials = self.GetTrials()
+        completed = [t for t in trials if t.status == trial_.TrialStatus.COMPLETED]
+        return (
+            [t for t in completed if t.id not in held_ids],
+            [t for t in trials if t.status == trial_.TrialStatus.ACTIVE],
+            len(completed),
+        )
 
     def CheckCancelled(self, note: str = "") -> None:
         """Raises CancelComputeError if the RPC was cancelled (default: no-op)."""

@@ -24,7 +24,7 @@ import datetime
 import logging
 import threading
 import time
-from typing import Dict, List, Optional, Tuple
+from typing import Dict, Iterable, List, Optional, Tuple
 
 import numpy as np
 
@@ -128,6 +128,35 @@ class VizierServicer:
             elif state == study_pb2.Trial.ACTIVE:
                 active.append(trial_id)
         return completed, active, max_id
+
+    def read_trials(
+        self,
+        study_name: str,
+        *,
+        trial_ids: Optional[Iterable[int]] = None,
+        states: Optional[tuple] = None,
+    ) -> List[study_pb2.Trial]:
+        """The datastore's own trial copies, id order (in-process, no RPC).
+
+        :meth:`trial_frontier`'s companion for the policy supporter: with
+        ``trial_ids``, those trials by name, an id the study no longer has
+        skipped (deleted since the caller learned it); otherwise a listing
+        filtered at the storage layer by ``states``. ``ListTrials`` would
+        copy every returned proto once more into its response.
+        """
+        if trial_ids is None:
+            return self.datastore.list_trials(study_name, states=states)
+        self.datastore.max_trial_id(study_name)  # NotFoundError: no such study
+        study = resources.StudyResource.from_name(study_name)
+        trials = []
+        for trial_id in sorted(set(trial_ids)):
+            try:
+                trials.append(
+                    self.datastore.get_trial(study.trial_resource(trial_id).name)
+                )
+            except datastore_lib.NotFoundError:
+                continue
+        return trials
 
     def _notify_trial_event(self, study_name: str) -> None:
         """Tells the in-process Pythia the study's frontier moved, so it

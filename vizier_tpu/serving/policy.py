@@ -8,6 +8,14 @@ with ``algorithms.designer_policy.DesignerPolicy`` (fresh designer + full
 trial replay per request — the reference shape) and
 ``InRamDesignerPolicy`` (lives only as long as the policy object the
 Pythia servicer happens to cache, no TTL/LRU/invalidation).
+
+The trial read is a **delta read** (``policy.load_trials``): the study's
+frontier as ids and states, the set difference against the entry's
+incorporated ids (a set, not a high-water mark: a lower id may complete
+after a higher one), and a fetch + proto -> pyvizier conversion of only the
+missing completed trials and the ACTIVE ones. ``designer.update`` receives
+what listing and converting the whole study twice would have handed it;
+``serving_stats()`` counts ``trials_fetched`` / ``trials_reused``.
 """
 
 from __future__ import annotations
@@ -98,19 +106,23 @@ class CachedDesignerStatePolicy(policy_lib.Policy):
     ) -> List[trial_.TrialSuggestion]:
         designer = entry.designer
         tracer = tracing_lib.get_tracer()
-        # Every completed trial of the study, datastore -> proto ->
-        # pyvizier, on every suggest: a stage of its own.
+        # A delta read: only the completed trials this designer does not
+        # hold yet are fetched and converted, plus the ACTIVE ones (read
+        # fresh every time: pending-point conditioning depends on them).
+        # A completed trial is fed to a designer once and never re-read (a
+        # later edit or delete of one goes unseen); an entry that starts
+        # empty (fresh, evicted, expired, invalidated) replays the study
+        # through this same read.
         with tracer.span("policy.load_trials", study=self._study_name) as load:
-            completed = self._supporter.GetTrials(
-                status_matches=trial_.TrialStatus.COMPLETED
+            new_completed, active, num_completed = self._supporter.GetTrialDelta(
+                entry.incorporated_trial_ids
             )
-            new_completed = [
-                t for t in completed if t.id not in entry.incorporated_trial_ids
-            ]
-            active = self._supporter.GetTrials(
-                status_matches=trial_.TrialStatus.ACTIVE
-            )
-            load.set_attribute("completed", len(completed))
+            fetched = len(new_completed) + len(active)
+            load.set_attribute("completed", num_completed)
+            load.set_attribute("fetched", fetched)
+        stats = self._runtime.stats
+        stats.increment("trials_fetched", fetched)
+        stats.increment("trials_reused", num_completed - len(new_completed))
         before = self._train_counts(designer)
         surrogate_before = self._surrogate_counts(designer)
         with tracer.span(

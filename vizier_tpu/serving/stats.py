@@ -38,6 +38,10 @@ class ServingStats:
         "coalesced_computations",  # leader runs that had >= 1 follower
         "warm_trains",
         "cold_trains",
+        # The policy's delta trial read (serving.policy): reused / (reused +
+        # fetched) is the share of a study a suggest did not re-read.
+        "trials_fetched",  # trial protos converted to pyvizier for an update
+        "trials_reused",  # completed trials the cached designer already held
         # Reliability (vizier_tpu.reliability): retry/fallback/breaker/deadline.
         "retries",  # client-side RPC / suggest retries
         "designer_failures",  # designer computations that raised
