@@ -24,6 +24,37 @@ from chipbench.lib import studies as studies_lib
 MAX_WARM_ROUNDS = 6
 
 
+def study_count(traffic: Dict[str, Any]) -> int:
+    """The studies a cell of this traffic opens (every cell: at most 64)."""
+    return traffic["clients"] * traffic["studies_per_client"]
+
+
+def check_data(config: Dict[str, Any], traffic: Dict[str, Any]) -> None:
+    """This generator's rules for a cell's files; an AssertionError says
+    which one they break. A study is retired before it leaves its bucket,
+    so its first and last suggest must share one, on the exact side, the
+    one the configuration states, with room for a window's rounds."""
+    start, count = traffic["start_trials"], traffic["suggest_count"]
+    rounds = studies_lib.rounds_in_bucket(start, count)
+    home = studies_lib.bucket(start, count)
+    assert home[0] == config["trial_padding_bucket"], (
+        f"a study that starts at {start} trials trains in the {home[0]} bucket, "
+        f"not the configuration's trial_padding_bucket {config['trial_padding_bucket']}")
+    last = start + (rounds - 1) * count  # completed trials at the last suggest
+    assert studies_lib.bucket(last, count) == home
+    assert studies_lib.bucket(last + count, count) != home
+    assert last <= config["completed_trials"], (
+        f"a study's last suggest holds {last} completed trials, over the "
+        f"configuration's completed_trials {config['completed_trials']}")
+    assert config["completed_trials"] < 512, (  # never the sparse side
+        f"completed_trials {config['completed_trials']} reaches the sparse switch at 512")
+    # Room for the window: 1.5x the rounds a client completed on the chip
+    # (PERF.md section 4), after the set-up's rounds on its first study.
+    served = studies_lib.rounds_in_bucket(start, count, traffic.get("max_rounds_per_study"))
+    assert served >= 9, (
+        f"a study makes {served} rounds before it is retired; a window needs 9")
+
+
 class _Study:
     def __init__(self, handle, client: int, index: int, x: np.ndarray, y: np.ndarray, rounds: int):
         self.handle = handle

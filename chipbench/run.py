@@ -8,7 +8,8 @@ over loopback gRPC with the cell's traffic: set-up (studies from the seed,
 one cold round per study, warm rounds until nothing compiles), the window,
 then — outside every timed region — the checks that decide ``correct``.
 Earlier stdout lines are free-form JSON, one object per phase; the last line
-is the contract's object. Exits non-zero, printing no result, when the
+is the contract's object, which ends with every number compared beside its
+limit (``compared``; the same rows are the last lines of stderr). Exits non-zero, printing no result, when the
 platform is not ``tpu`` or the device count is not the cell's. ``--rehearse``
 takes each file's ``rehearse`` sizes so that the control flow runs in a
 sandbox; off a TPU such a run still ends ``"correct": false`` and non-zero.
@@ -40,7 +41,7 @@ REQUIRED_PLATFORM = "tpu"
 TRACE_SECONDS = 1.0  # ~1.7 million device events per busy second, ~50 s to collect each
 TRACE_LEAD_SHARE = 0.4  # of the window: past its start, where every client is released at once
 TRACE_DIR = os.path.join(ROOT, "chiprun_out", "chipbench_trace")
-ANNOTATIONS = ("client.suggest", "client.complete")
+from chipbench.lib.stages import GAP_ANNOTATIONS as ANNOTATIONS  # noqa: E402  (names of idle gaps)
 
 
 def emit(obj: Dict[str, Any]) -> None:
@@ -322,7 +323,15 @@ def run_cell(args) -> int:
             "device_ops": [list(p) for p in evidence["trace"]["device_ops"]],
             "idle_gaps": [list(p) for p in evidence["trace"]["idle_gaps"]],
         }
-    emit(result)
+    # Each number compared beside its limit, the failing ones last: as the
+    # last lines of stderr and under the result line's last key, since the
+    # end of each is all that a record of a run that was not correct keeps.
+    rows = sorted(compared, key=lambda c: not c["ok"])
+    for c in rows:
+        print(f"{'ok' if c['ok'] else 'NOT OK'} {c['name']} {c['value']} limit {c['limit']}", file=sys.stderr)
+    sys.stderr.flush()
+    result["compared"] = {c["name"]: [c["value"], c["limit"]] for c in rows}
+    print(json.dumps(result, default=str), flush=True)  # keys as inserted: `compared` stays last
     return 0 if result["correct"] else 1
 
 

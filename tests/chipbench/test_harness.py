@@ -12,121 +12,67 @@ from __future__ import annotations
 
 import json
 import os
-import re
 import subprocess
 import sys
 
 import pytest
 
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-if ROOT not in sys.path:
-    sys.path.insert(0, ROOT)
-HERE = os.path.join(ROOT, "chipbench")
-NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
-UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
-RESULT_KEYS = {"correct", "attempted", "failed", "metrics", "device"}
+for _path in (ROOT, os.path.dirname(os.path.abspath(__file__))):
+    if _path not in sys.path:
+        sys.path.insert(0, _path)
 
-with open(os.path.join(ROOT, "BENCHMARK.json")) as _f:
-    BENCH = json.load(_f)
-CELLS = {w["name"]: w for w in BENCH["workloads"]}
-CONFIGS = {c["name"]: c for c in BENCH["configs"]}
+import contract_checks as contract  # noqa: E402  (beside this file)
+
+RESULT_KEYS = {"correct", "attempted", "failed", "metrics", "device", "compared"}
+BENCH = contract.load(ROOT, "BENCHMARK.json")
 END_TO_END = {m["name"]: m for m in BENCH["end_to_end"]}
 
 
-def _load(*parts):
-    with open(os.path.join(*parts)) as f:
-        return json.load(f)
-
-
-def _reports(metric, cell):
-    return cell in metric.get("workloads", [cell])
-
-
 def test_top_level_keys_and_limits():
-    assert set(BENCH) == {
-        "command", "paths", "run_seconds", "configs", "workloads", "end_to_end", "per_layer",
-    }
-    assert 1 <= BENCH["run_seconds"] <= 51 and isinstance(BENCH["run_seconds"], int)
-    assert BENCH["paths"] == ["chipbench", "tests/chipbench"]
-    assert BENCH["command"][1].startswith("chipbench/")
-    assert os.path.getsize(os.path.join(ROOT, "BENCHMARK.json")) <= 64 * 1024
-    four = sum(w["chips"] == 4 for w in BENCH["workloads"])
-    assert four <= max(1, len(BENCH["workloads"]) // 2)
+    contract.top_level_keys_and_limits(BENCH, ROOT)
 
 
-@pytest.mark.parametrize(
-    "entry",
-    BENCH["configs"] + BENCH["workloads"] + BENCH["end_to_end"] + BENCH["per_layer"],
-    ids=lambda e: e["name"],
-)
+@pytest.mark.parametrize("entry", contract.entries(BENCH), ids=lambda e: e["name"])
 def test_entry_names_units_and_lines(entry):
-    assert NAME.match(entry["name"])
-    for key in ("config", "traffic", "moves"):
-        if key in entry:
-            assert NAME.match(entry[key])
-    if "unit" in entry:
-        assert UNIT.match(entry["unit"]) and entry["better"] in ("lower", "higher")
-        assert entry["source"] in ("device_trace", "program_span", "program_counter", "host_clock")
-    for key in ("why", "layer", "source"):
-        if key in entry:
-            assert 1 <= len(entry[key]) <= 200 and "\n" not in entry[key] and "\t" not in entry[key]
-    for cell in entry.get("workloads", []):
-        assert cell in CELLS
-    if "bound" in entry:
-        assert 0.01 <= entry["bound"] <= 0.25
+    contract.entry_names_units_and_lines(BENCH, entry)
 
 
 @pytest.mark.parametrize("config", BENCH["configs"], ids=lambda c: c["name"])
 def test_config_file_states_the_deployment(config):
-    assert set(config) == {"name", "source", "file", "reduced", "why"}
-    assert config["file"].startswith("chipbench/configs/")
-    data = _load(ROOT, config["file"])
-    assert data["name"] == config["name"]
-    assert sorted(data["reduced"]) == sorted(config["reduced"])
-    assert data["guarantees"] and data["zero_counters"] and data["check_studies"] >= 1
-    assert os.path.exists(os.path.join(HERE, "references", data["reference"] + ".py"))
-    for name, limit in data["limits"].items():  # a ceiling, or a floor and/or a ceiling
-        assert isinstance(limit, (int, float)) or set(limit) <= {"min", "max"}, name
-    assert any(w["config"] == config["name"] for w in BENCH["workloads"])
+    contract.config_file_states_the_deployment(BENCH, ROOT, config)
 
 
 @pytest.mark.parametrize("cell", BENCH["workloads"], ids=lambda w: w["name"])
 def test_cell_files_resolve_by_name(cell):
-    assert set(cell) == {"name", "config", "traffic", "chips", "why"}
-    assert cell["chips"] in (1, 4) and cell["config"] in CONFIGS
-    traffic = _load(HERE, "traffic", cell["traffic"] + ".json")
-    assert os.path.exists(os.path.join(HERE, "generators", traffic["generator"] + ".py"))
-    assert set(traffic["batched_share_pct"]) <= {"min", "max"}
-    # Every cell reports setup_s, one more end-to-end metric and a per-layer one.
-    reported = [m["name"] for m in BENCH["end_to_end"] if _reports(m, cell["name"])]
-    assert "setup_s" in reported and len(reported) >= 2
-    assert any(_reports(m, cell["name"]) for m in BENCH["per_layer"])
-    # At most 64 studies: the designer cache keeps 64 entries.
-    assert traffic["clients"] * traffic["studies_per_client"] <= 64
+    contract.cell_files_resolve_by_name(BENCH, ROOT, cell)
 
 
 @pytest.mark.parametrize("metric", BENCH["per_layer"], ids=lambda m: m["name"])
 def test_layer_metric_has_a_reader_and_moves_a_reported_metric(metric):
-    assert set(metric) - {"workloads"} == {"name", "unit", "better", "source", "layer", "moves"}
-    from chipbench import run
-
-    assert callable(run.load_reader(metric["name"]).read)
-    moved = END_TO_END[metric["moves"]]
-    for cell in CELLS:
-        if _reports(metric, cell):
-            assert _reports(moved, cell), (metric["name"], cell)
+    contract.layer_metric_has_a_reader_and_moves_a_reported_metric(BENCH, ROOT, metric)
 
 
 def test_readers_return_nothing_when_there_is_nothing_to_read():
+    contract.readers_return_nothing_when_there_is_nothing_to_read(BENCH, ROOT)
+
+
+@pytest.mark.parametrize(
+    "stats,share",
+    [
+        ({"trials_reused": 350, "trials_fetched": 25}, 100.0 * 350 / 375),
+        ({"trials_reused": 0, "trials_fetched": 375}, 0.0),  # the whole study read again: 0, not nothing
+        ({"trials_reused": 0, "trials_fetched": 0}, None),
+        ({}, None),  # a program from before the delta read has no such counters
+    ],
+    ids=["delta_read", "full_read", "no_suggest", "no_counters"],
+)
+def test_trial_reuse_share_is_the_share_a_suggest_did_not_read_again(stats, share):
     from chipbench import run
 
-    empty = {
-        "histograms_window": {}, "latencies_ms": [], "trace": None, "seconds": 1.0,
-        "completed_in_window": 0, "stats_window": {"warm_trains": 0, "cold_trains": 0},
-    }
-    for metric in BENCH["per_layer"]:
-        if not metric["name"].startswith("compiles_in_window"):
-            assert run.load_reader(metric["name"]).read(empty) is None
+    for name in ("trial_reuse_share.lone", "trial_reuse_share.pool"):
+        value = run.load_reader(name).read({"stats_window": stats})
+        assert value == (share if share is None else pytest.approx(share))
 
 
 def test_a_rehearse_overlay_merges_into_groups_and_replaces_numbers():
@@ -157,23 +103,23 @@ def test_pad_arithmetic_is_the_programs(n):
     assert studies.pad_power_of_two(n) == padding.PaddingType.POWERS_OF_2.pad(n)
 
 
+def test_the_idle_gaps_names_are_the_programs_stages():
+    from chipbench import run
+    from chipbench.lib import stages
+    from vizier_tpu.observability import tracing
+
+    assert len(set(stages.STAGE_NAMES)) == len(stages.STAGE_NAMES)
+    assert set(stages.STAGE_NAMES) == tracing.STAGES
+    assert stages.GAP_ANNOTATIONS == (*stages.STAGE_NAMES, "batch_executor.queue_wait", "client.complete")
+    assert stages.REQUEST_STAGE in stages.STAGE_NAMES
+    assert run.ANNOTATIONS is stages.GAP_ANNOTATIONS  # what a traced run hands the reduction
+
+
 @pytest.mark.parametrize("cell", BENCH["workloads"], ids=lambda w: w["name"])
 def test_a_study_is_retired_before_it_leaves_its_bucket(cell):
-    from chipbench.lib import studies
-
-    config = _load(ROOT, CONFIGS[cell["config"]]["file"])
-    traffic = _load(HERE, "traffic", cell["traffic"] + ".json")
-    start, count = traffic["start_trials"], traffic["suggest_count"]
-    rounds = studies.rounds_in_bucket(start, count)
-    home = studies.bucket(start, count)
-    assert home[0] == config["trial_padding_bucket"]
-    last = start + (rounds - 1) * count  # completed trials at the last suggest
-    assert studies.bucket(last, count) == home
-    assert studies.bucket(last + count, count) != home
-    assert last <= config["completed_trials"] < 512  # never the sparse side
-    # Room for the window: 1.5x the rounds a client completed on the chip
-    # (PERF.md section 4), after the set-up's rounds on its first study.
-    assert rounds >= 9
+    # The rule is the cell's generator's (``closed_rounds.check_data`` holds
+    # what this test held); another arrival process states another.
+    contract.cell_data_keeps_its_generators_rules(BENCH, ROOT, cell)
 
 
 # -- child runs at rehearse size ------------------------------------------------
@@ -232,6 +178,13 @@ def test_off_a_tpu_the_command_prints_a_result_that_is_not_correct(cache_dir):
     compared = [o for o in objs if o.get("phase") == "correct"][0]["compared"]
     assert [c["name"] for c in compared if not c["ok"]] == ["platform"]
     assert all("limit" in c and "value" in c for c in compared)
+    # Each number beside its limit ends the result line and stderr, the
+    # failing row last: the end of each is what a failed run's record keeps.
+    assert list(result)[-1] == "compared" and set(result["compared"]) == {c["name"] for c in compared}
+    assert list(result["compared"])[-1] == "platform"
+    assert all(len(pair) == 2 for pair in result["compared"].values())
+    last = done.stderr.strip().splitlines()[-len(compared):]
+    assert last[-1].startswith("NOT OK platform ") and all(l.startswith("ok ") for l in last[:-1])
 
 
 def test_at_full_size_without_a_tpu_it_prints_no_result(cache_dir):
@@ -256,7 +209,7 @@ def test_with_the_chip_check_skipped_a_sound_traced_run_is_correct(cache_dir):
     )
     for name, metric in result["metrics"].items():
         assert metric["unit"] == per_layer[name]["unit"]
-        assert _reports(per_layer[name], "default20d.tenants16")
+        assert contract.reports(per_layer[name], "default20d.tenants16")
 
 
 @pytest.mark.parametrize(

@@ -14,12 +14,13 @@ if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
 from chipbench.lib import reduce  # noqa: E402
+from chipbench.lib import stages  # noqa: E402
 from chipbench.lib import trace_reduce as tr  # noqa: E402
 
 # ``default20d.lone25``, seed 106, 0.33 s of the window on a TPU v5 lite
 # (PR 23), thinned by dropping device operations under 2 us and all stats.
 RECORDING = os.path.join(ROOT, "chipbench", "testdata", "lone25_slice.xplane.pb.gz")
-ANNOTATIONS = ("client.suggest", "client.complete")
+ANNOTATIONS = stages.GAP_ANNOTATIONS  # what a run hands the reduction
 
 
 @pytest.mark.parametrize(
@@ -63,6 +64,43 @@ def test_clip_and_overlap():
 def test_a_gap_is_named_after_what_covers_most_of_it(gap, label):
     host = {"client.complete": [(0, 4)], "client.suggest": [(4.1, 8.5)]}
     assert tr.attribute(gap, host) == label
+
+
+def _host(recorded):
+    """Host spans as ``read_xplane`` keeps them: those of the names a run
+    asks for, every other recorded span dropped."""
+    host = {name: [] for name in ANNOTATIONS}
+    for name, span in recorded:
+        if name in host:
+            host[name].append(span)
+    return {name: tr.merge(spans) for name, spans in host.items()}
+
+
+def test_a_gap_takes_the_stage_that_covers_it_and_an_rpc_alone_names_nothing():
+    # One request: the RPC span covers all of it, the stages its parts. The
+    # device runs during device.wait only, and not for all of it.
+    recorded = [
+        ("client.suggest", (0.0, 8.0)),
+        ("service.read", (0.2, 0.5)),
+        ("policy.load_trials", (0.5, 2.0)),
+        ("designer.prepare", (2.0, 3.0)),
+        ("device.wait", (3.0, 5.0)),
+        ("designer.decode", (5.0, 6.0)),
+        ("client.complete", (8.0, 10.0)),
+    ]
+    ops = {"/device:TPU:0": [("m:%while.1 while", 3.2, 4.8)]}
+    assert "client.suggest" not in ANNOTATIONS
+    out = tr.reduce_intervals(ops, _host(recorded), 0.5, 10.0)
+    # The gap before the device starts, 0.5-3.2: load_trials covers 1.5 of
+    # its 2.7 s. The one after, 4.8-10.0: decode 1.0, complete 2.0 of 5.2 s,
+    # and 6.0-8.0 inside the RPC with no stage.
+    assert out["idle_gaps"] == [("client.complete", pytest.approx(5.2)), ("policy.load_trials", pytest.approx(2.7))]
+    # A gap inside the RPC that no stage covers reads "none", whatever the
+    # RPC's own span says: only leaves name a gap.
+    assert tr.attribute((6.2, 7.8), _host(recorded)) == "none"
+    assert tr.attribute((2.1, 2.9), _host(recorded)) == "designer.prepare"
+    assert tr.attribute((4.8, 5.0), _host(recorded)) == "device.wait"
+    assert set(out["idle_by_host_activity"]) <= set(ANNOTATIONS) | {"none"}
 
 
 def test_reduce_intervals_on_a_synthetic_trace():

@@ -111,7 +111,7 @@ def test_the_new_entries_are_spans_with_cells_and_known_layers():
     assert len(STAGE_METRICS) == 9
     for metric in STAGE_METRICS:
         assert metric["workloads"] and metric["moves"] and metric["layer"]
-    assert [m["name"] for m in BENCH["per_layer"][-9:]] == [m["name"] for m in STAGE_METRICS]
+    assert {m["name"].partition(".")[0] for m in STAGE_METRICS} == set(EXPECTED)
 
 
 def test_a_rehearsal_of_the_fused_cell_reports_the_stage_metrics(tmp_path):
