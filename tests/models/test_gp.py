@@ -189,6 +189,48 @@ class TestTraining:
         x, f = lbfgs_minimize(quad, jnp.asarray([100.0, 1.0]), maxiter=300)
         assert float(f) < 1e-6, float(f)
 
+    def test_lbfgs_does_not_jump_onto_a_flat_part_below_the_start(self):
+        """A steep start (|g| ~ 1e3) beside a soft-clipped bound: the first
+        step x - g lands where the loss is flat and lower than the start,
+        Armijo accepts it and the run ends there "converged" (an ARD train
+        on near noise-free labels did, PERF.md PR 29). Held to MAX_STEP a
+        step, it walks down to the minimum at 3."""
+        from vizier_tpu.optimizers.lbfgs import lbfgs_minimize
+
+        def loss(v):
+            bowl = 500.0 * (v[0] - 3.0) ** 2  # minimum 0 at 3; gradient -3000 at 0
+            shelf = 4000.0 * jax.nn.sigmoid(-(v[0] - 50.0))  # flat 0 beyond ~60, where the bowl is cut off
+            return jnp.minimum(bowl, 1000.0 + shelf)
+
+        x, f = lbfgs_minimize(loss, jnp.asarray([0.0]), maxiter=50)
+        assert abs(float(x[0]) - 3.0) < 1e-2 and float(f) < 1e-3, (x, f)
+
+    def test_the_gram_carries_its_nugget_whatever_the_noise_parameter(self):
+        """noise_stddev as the model builds and reports it is never under
+        NUGGET_TO_AMPLITUDE of the amplitude, the condition number of a
+        clustered noise-free Gram stays under n / nugget^2, and the noise
+        parameter keeps a gradient under the nugget (no dead zone)."""
+        model = gp_lib.VizierGaussianProcess(num_continuous=2, num_categorical=0)
+        coll = model.param_collection()
+        u = coll.random_init_unconstrained(jax.random.PRNGKey(1))
+        u = {**u, "noise_stddev": jnp.asarray(-30.0)}  # the parameter on its lower bound
+        bounded, p = coll.constrain(u), model.constrain(u)
+        assert float(bounded["noise_stddev"]) < 2e-3
+        ratio = float(p["noise_stddev"] / p["amplitude"])
+        assert ratio == pytest.approx(gp_lib.NUGGET_TO_AMPLITUDE, rel=2e-2) and ratio >= gp_lib.NUGGET_TO_AMPLITUDE
+        rng = np.random.default_rng(0)
+        x = np.clip(0.5 + rng.normal(size=(64, 2)) * np.geomspace(0.2, 1e-5, 64)[:, None], 0, 1)
+        data = gp_lib.GPData(
+            continuous=jnp.asarray(x, jnp.float32), categorical=jnp.zeros((64, 0), jnp.int32),
+            labels=jnp.asarray(-np.sum((x - 0.5) ** 2, -1), jnp.float32), row_mask=jnp.ones((64,), bool),
+            cont_dim_mask=jnp.ones((2,), bool), cat_dim_mask=jnp.zeros((0,), bool))
+        eig = np.linalg.eigvalsh(np.asarray(model._masked_gram(p, data), np.float64))
+        assert eig[-1] / eig[0] <= 64 / gp_lib.NUGGET_TO_AMPLITUDE**2 * 1.01
+        assert np.isfinite(np.asarray(model.precompute(u, data).chol)).all()
+        u_free = {**u, "noise_stddev": jnp.asarray(-1.0)}
+        grad = jax.grad(lambda v: model.neg_log_likelihood(v, data))(u_free)
+        assert abs(float(grad["noise_stddev"])) > 0.0
+
     def test_best_n_ensemble_shapes(self):
         model = gp_lib.VizierGaussianProcess(num_continuous=1, num_categorical=0)
         data = _make_data(8, 8, dc=1)

@@ -141,7 +141,15 @@ class TestPosteriorVsOracle:
         # The ARD loss = exact NLL + log-normal regularization; recover the
         # regularizer from the roundtripped constrained params.
         reg = float(coll.regularization(coll.constrain(unconstrained)))
-        assert loss - reg == pytest.approx(oracle.nll(), abs=5e-2)
+        # The likelihood is the Gram's as the model builds it: the noise
+        # with its nugget (gp.NUGGET_TO_AMPLITUDE).
+        built = model.constrain(unconstrained)
+        with_nugget = _Oracle(
+            oracle.x, oracle.z, oracle.y, oracle.amp, float(built["noise_stddev"]),
+            oracle.cont_ls, oracle.cat_ls,
+        )
+        assert float(built["noise_stddev"]) > float(params["noise_stddev"])
+        assert loss - reg == pytest.approx(with_nugget.nll(), abs=5e-2)
 
     def test_padding_rows_are_invisible(self, case):
         oracle, model, params, _, _, qx, qz, query = case

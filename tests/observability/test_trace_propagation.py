@@ -158,29 +158,35 @@ class TestFourHopTrace:
 
 
 class TestCoalescedFollowerLink:
+    # Driven at the Pythia servicer, where coalescing lives: two
+    # SuggestTrials of one study take turns and never meet there.
     def test_follower_span_links_to_leader_computation(self, tracer):
+        from vizier_tpu.service.protos import pythia_service_pb2
+
         servicer, pythia = _make_stack(
             policy_factory=_SlowDesignerPolicyFactory(delay_secs=0.4)
         )
         n = 2
-        ops = [None] * n
+        study = servicer.GetStudy(vizier_service_pb2.GetStudyRequest(name=STUDY))
+        preq = pythia_service_pb2.PythiaSuggestRequest(
+            count=1, algorithm=study.study_spec.algorithm, study_name=STUDY
+        )
+        preq.study_descriptor.config.CopyFrom(study.study_spec)
+        preq.study_descriptor.guid = STUDY
+        responses = [None] * n
         barrier = threading.Barrier(n)
 
         def worker(i):
             barrier.wait(timeout=10)
-            ops[i] = servicer.SuggestTrials(
-                vizier_service_pb2.SuggestTrialsRequest(
-                    parent=STUDY, suggestion_count=1, client_id=f"client-{i}"
-                )
-            )
+            responses[i] = pythia.Suggest(preq)
 
         threads = [threading.Thread(target=worker, args=(i,)) for i in range(n)]
         for t in threads:
             t.start()
         for t in threads:
             t.join(timeout=30)
-        for op in ops:
-            assert op is not None and op.done and not op.error
+        for resp in responses:
+            assert resp is not None and not resp.error
         assert pythia.serving_stats()["coalesced_requests"] == n - 1
 
         spans = tracer.finished_spans()

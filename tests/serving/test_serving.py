@@ -257,44 +257,61 @@ class _CountingPolicyFactory:
         return _P()
 
 
+def _suggest_request(client, name=STUDY, **kwargs):
+    return vizier_service_pb2.SuggestTrialsRequest(
+        parent=name, suggestion_count=1, client_id=client, **kwargs
+    )
+
+
+def _pythia_request(servicer, name=STUDY):
+    """The Pythia request the service would send for ``name`` right now."""
+    from vizier_tpu.service.protos import pythia_service_pb2
+
+    study = servicer.GetStudy(vizier_service_pb2.GetStudyRequest(name=name))
+    preq = pythia_service_pb2.PythiaSuggestRequest(
+        count=1, algorithm=study.study_spec.algorithm, study_name=name
+    )
+    preq.study_descriptor.config.CopyFrom(study.study_spec)
+    preq.study_descriptor.guid = name
+    preq.study_descriptor.max_trial_id = servicer.datastore.max_trial_id(name)
+    return preq
+
+
+def _run_together(n, call):
+    """``call(i)`` on n threads released at once; their results, by i."""
+    out = [None] * n
+    barrier = threading.Barrier(n)
+
+    def worker(i):
+        barrier.wait(timeout=10)
+        out[i] = call(i)
+
+    threads = [threading.Thread(target=worker, args=(i,)) for i in range(n)]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(timeout=30)
+    return out
+
+
 class TestSuggestCoalescing:
-    def test_n_concurrent_suggests_one_computation(self):
-        """Acceptance: N concurrent SuggestTrials -> exactly 1 designer
-        computation; every caller receives a valid suggestion."""
-        factory = _CountingPolicyFactory(delay_s=1.5)
+    """Coalescing lives at the Pythia servicer: identical computations that
+    reach it together (a second frontend, the speculative engine) share
+    one. ``SuggestTrials`` of one study no longer arrive there together:
+    they take turns (``TestStudyTurns``)."""
+
+    @pytest.mark.parametrize("n", [2, 6])
+    def test_n_identical_pythia_suggests_one_computation(self, n):
+        factory = _CountingPolicyFactory(delay_s=0.5)
         servicer, pythia = _make_service(policy_factory=factory)
         _create_study(servicer)
-
-        n = 6
-        ops = [None] * n
-        barrier = threading.Barrier(n)
-
-        def worker(i):
-            barrier.wait(timeout=10)
-            ops[i] = servicer.SuggestTrials(
-                vizier_service_pb2.SuggestTrialsRequest(
-                    parent=STUDY, suggestion_count=1, client_id=f"client-{i}"
-                )
-            )
-
-        threads = [threading.Thread(target=worker, args=(i,)) for i in range(n)]
-        for t in threads:
-            t.start()
-        for t in threads:
-            t.join(timeout=30)
-
+        preq = _pythia_request(servicer)
+        responses = _run_together(n, lambda i: pythia.Suggest(preq))
         assert factory.computations == 1
-        ids = set()
-        for op in ops:
-            assert op is not None and op.done and not op.error
-            assert len(op.response.trials) == 1
-            trial = op.response.trials[0]
-            ids.add(trial.id)
-            # Identical results: every caller got the shared computation's
-            # suggested point (as its own distinct trial).
-            values = {p.name: p.value.double_value for p in trial.parameters}
-            assert values == {"x0": 0.25, "x1": 0.75}
-        assert len(ids) == n  # distinct trials, one per caller
+        for resp in responses:
+            assert resp is not None and not resp.error
+            assert len(resp.suggestions) == 1
+        assert len({id(r) for r in responses}) == n  # a copy each
         snap = pythia.serving_stats()
         assert snap["coalesced_requests"] == n - 1
         assert snap["coalesced_computations"] == 1
@@ -307,26 +324,289 @@ class TestSuggestCoalescing:
         )
         _create_study(servicer)
         n = 3
-        ops = [None] * n
-        barrier = threading.Barrier(n)
-
-        def worker(i):
-            barrier.wait(timeout=10)
-            ops[i] = servicer.SuggestTrials(
-                vizier_service_pb2.SuggestTrialsRequest(
-                    parent=STUDY, suggestion_count=1, client_id=f"client-{i}"
-                )
-            )
-
-        threads = [threading.Thread(target=worker, args=(i,)) for i in range(n)]
-        for t in threads:
-            t.start()
-        for t in threads:
-            t.join(timeout=30)
-        for op in ops:
-            assert op.done and not op.error
+        preq = _pythia_request(servicer)
+        responses = _run_together(n, lambda i: pythia.Suggest(preq))
+        for resp in responses:
+            assert resp is not None and not resp.error
         assert factory.computations == n
         assert pythia.serving_stats()["coalesced_requests"] == 0
+
+
+class _RecordingPolicyFactory:
+    """Computation i suggests the point (i / 100, 0.5) and records the
+    ACTIVE trials it could read; ``gate`` (an Event) holds every
+    computation until set, ``fail_first`` makes the first one raise."""
+
+    def __init__(self, delay_s=0.0, gate=None, fail_first=False):
+        self.seen_active = []  # per computation: x0 of each ACTIVE trial
+        self.delay_s = delay_s
+        self.gate = gate
+        self.fail_first = fail_first
+        self.started = threading.Event()
+
+    @property
+    def computations(self):
+        return len(self.seen_active)
+
+    def __call__(self, problem, algorithm, supporter, study_name):
+        outer = self
+
+        class _P(policy_lib.Policy):
+            def suggest(self, request):
+                active = supporter.GetTrials(status_matches=vz.TrialStatus.ACTIVE)
+                outer.seen_active.append(
+                    sorted(t.parameters["x0"].value for t in active)
+                )
+                i = len(outer.seen_active)
+                outer.started.set()
+                if outer.gate is not None:
+                    assert outer.gate.wait(timeout=20)
+                time.sleep(outer.delay_s)
+                if outer.fail_first and i == 1:
+                    raise RuntimeError("the designer fell over")
+                point = vz.TrialSuggestion(parameters={"x0": i / 100, "x1": 0.5})
+                return policy_lib.SuggestDecision(suggestions=[point])
+
+        return _P()
+
+
+def _wait_until(condition, timeout=10.0):
+    deadline = time.monotonic() + timeout
+    while not condition():
+        assert time.monotonic() < deadline, "timed out"
+        time.sleep(0.005)
+
+
+def _in_line(turn) -> int:
+    """Requests standing behind a turn's holder."""
+    return max(0, turn._tickets - turn._serving - 1)
+
+
+class TestStudyTurns:
+    """A study's SuggestTrials run one at a time from the claim to the
+    write, in arrival order: N workers of one study get N points."""
+
+    @pytest.mark.parametrize("n", [2, 6])
+    def test_n_clients_of_one_study_get_n_points_in_arrival_order(self, n):
+        gate = threading.Event()
+        factory = _RecordingPolicyFactory(gate=gate)
+        servicer, pythia = _make_service(policy_factory=factory)
+        _create_study(servicer)
+        ops = [None] * n
+
+        def worker(i):
+            ops[i] = servicer.SuggestTrials(_suggest_request(f"client-{i}"))
+
+        threads = [threading.Thread(target=worker, args=(i,)) for i in range(n)]
+        turn = servicer._study_turns[STUDY]
+        for i, t in enumerate(threads):  # arrivals in order: i stands behind i - 1
+            t.start()
+            if i == 0:
+                assert factory.started.wait(timeout=10)
+            else:
+                _wait_until(lambda: _in_line(turn) == i)
+        gate.set()
+        for t in threads:
+            t.join(timeout=30)
+
+        assert factory.computations == n
+        for i, op in enumerate(ops):
+            assert op is not None and op.done and not op.error, op
+            (trial,) = op.response.trials
+            assert trial.id == i + 1 and trial.assigned_worker == f"client-{i}"
+            values = {p.name: p.value.double_value for p in trial.parameters}
+            assert values == {"x0": (i + 1) / 100, "x1": 0.5}
+            # Computation i read the i earlier picks as ACTIVE.
+            assert factory.seen_active[i] == [(k + 1) / 100 for k in range(i)]
+        snap = pythia.serving_stats()
+        assert snap["suggest_turns"] == n
+        assert snap["suggest_turns_contended"] == n - 1
+        assert snap["coalesced_requests"] == 0
+
+    def test_same_client_asking_again_gets_its_own_trial_back(self):
+        factory = _RecordingPolicyFactory()
+        servicer, _ = _make_service(policy_factory=factory)
+        _create_study(servicer)
+        first = servicer.SuggestTrials(_suggest_request("worker"))
+        again = servicer.SuggestTrials(_suggest_request("worker"))
+        assert not first.error and not again.error
+        assert [t.id for t in again.response.trials] == [t.id for t in first.response.trials] == [1]
+        assert factory.computations == 1
+
+    def test_a_completion_is_served_while_a_turn_is_held(self):
+        gate = threading.Event()
+        factory = _RecordingPolicyFactory(gate=gate)
+        servicer, _ = _make_service(policy_factory=factory)
+        _create_study(servicer)
+        created = servicer.CreateTrial(
+            vizier_service_pb2.CreateTrialRequest(
+                parent=STUDY, trial=pc.trial_to_proto(vz.Trial(parameters={"x0": 0.9, "x1": 0.9}))
+            )
+        )
+        holder = threading.Thread(
+            target=servicer.SuggestTrials, args=(_suggest_request("client-0"),)
+        )
+        holder.start()
+        try:
+            assert factory.started.wait(timeout=10)  # the turn is held, mid-computation
+            req = vizier_service_pb2.CompleteTrialRequest(name=created.name)
+            m = req.final_measurement.metrics.add()
+            m.name, m.value = "obj", 1.0
+            t0 = time.monotonic()
+            done = servicer.CompleteTrial(req)
+            assert time.monotonic() - t0 < 5.0
+            from vizier_tpu.service.protos import study_pb2
+
+            assert done.state == study_pb2.Trial.SUCCEEDED
+            assert holder.is_alive()
+        finally:
+            gate.set()
+            holder.join(timeout=30)
+
+    def test_a_waiter_whose_deadline_expires_gets_the_transient_error_and_the_turn_moves_on(self):
+        from vizier_tpu.reliability import errors as errors_lib
+
+        gate = threading.Event()
+        factory = _RecordingPolicyFactory(gate=gate)
+        servicer, pythia = _make_service(policy_factory=factory)
+        _create_study(servicer)
+        ops = {}
+
+        def ask(client, **kwargs):
+            ops[client] = servicer.SuggestTrials(_suggest_request(client, **kwargs))
+
+        turn = servicer._study_turns[STUDY]
+        holder = threading.Thread(target=ask, args=("holder",))
+        holder.start()
+        assert factory.started.wait(timeout=10)
+        hurried = threading.Thread(target=ask, args=("hurried",), kwargs={"deadline_secs": 0.2})
+        hurried.start()
+        _wait_until(lambda: _in_line(turn) == 1)
+        patient = threading.Thread(target=ask, args=("patient",))
+        patient.start()
+        _wait_until(lambda: _in_line(turn) == 2)
+        time.sleep(0.3)  # the hurried one's budget runs out in line
+        gate.set()
+        for t in (holder, hurried, patient):
+            t.join(timeout=30)
+
+        assert not ops["holder"].error and not ops["patient"].error
+        assert ops["hurried"].done and not ops["hurried"].response.trials
+        assert errors_lib.has_transient_marker(ops["hurried"].error)
+        assert "DEADLINE_EXCEEDED" in ops["hurried"].error
+        assert factory.computations == 2  # the expired request never reached Pythia
+        assert [t.id for t in ops["patient"].response.trials] == [2]
+        assert pythia.serving_stats()["deadline_exceeded"] == 1
+
+    def test_an_exception_inside_a_turn_releases_it(self):
+        from vizier_tpu.service.protos import study_pb2
+
+        factory = _RecordingPolicyFactory(fail_first=True)
+        servicer, _ = _make_service(policy_factory=factory)
+        _create_study(servicer)
+        # A designer that raises (the reliability fallback answers in its place) ...
+        rescued = servicer.SuggestTrials(_suggest_request("client-0"))
+        assert rescued.done and factory.computations == 1
+        # ... and an exception that leaves SuggestTrials itself, mid-turn.
+        servicer.SetStudyState(
+            vizier_service_pb2.SetStudyStateRequest(name=STUDY, state=study_pb2.Study.INACTIVE)
+        )
+        with pytest.raises(ValueError, match="not ACTIVE"):
+            servicer.SuggestTrials(_suggest_request("client-1"))
+        servicer.SetStudyState(
+            vizier_service_pb2.SetStudyStateRequest(name=STUDY, state=study_pb2.Study.ACTIVE)
+        )
+        ok = servicer.SuggestTrials(_suggest_request("client-1"))
+        assert ok.done and not ok.error and len(ok.response.trials) == 1
+        assert _in_line(servicer._study_turns[STUDY]) == 0
+
+    def test_first_requests_of_a_fresh_study_share_one_turn(self):
+        """Fifty workers behind a barrier hit a study no request has met:
+        every one of them must find the SAME turn, or two compute at once
+        and two workers get one point."""
+        from vizier_tpu.serving import study_turns
+
+        made = []
+        real = study_turns.StudyTurn.__init__
+
+        def slow_init(self, *args):  # widens the window between miss and set
+            made.append(self)
+            time.sleep(0.05)
+            real(self, *args)
+
+        n = 12
+        factory = _RecordingPolicyFactory()
+        servicer, _ = _make_service(policy_factory=factory)
+        _create_study(servicer)
+        barrier = threading.Barrier(n)
+        ops = [None] * n
+
+        def worker(i):
+            barrier.wait()
+            ops[i] = servicer.SuggestTrials(_suggest_request(f"client-{i}"))
+
+        threads = [threading.Thread(target=worker, args=(i,)) for i in range(n)]
+        study_turns.StudyTurn.__init__ = slow_init
+        try:
+            for t in threads:
+                t.start()
+            for t in threads:
+                t.join(timeout=60)
+        finally:
+            study_turns.StudyTurn.__init__ = real
+        turn = servicer._study_turns[STUDY]
+        assert turn._tickets == turn._serving == n  # all n went through the one turn
+        points = sorted(
+            tuple(p.value.double_value for p in op.response.trials[0].parameters) for op in ops
+        )
+        assert len(set(points)) == n, points
+        # Computation k read exactly the k earlier picks as ACTIVE.
+        assert sorted(len(seen) for seen in factory.seen_active) == list(range(n))
+
+    def test_an_observer_that_raises_does_not_strand_the_turn(self):
+        from vizier_tpu.serving import study_turns
+
+        calls = []
+
+        def waited(seconds, contended):
+            calls.append(contended)
+            if len(calls) == 1:
+                raise RuntimeError("observer")
+
+        turn = study_turns.StudyTurns(waited, lambda seconds: None)["s"]
+        with pytest.raises(RuntimeError, match="observer"):
+            with turn:
+                pass
+        with turn:  # would wait for ever behind the stranded ticket
+            assert _in_line(turn) == 0
+        assert calls == [False, False] and turn._tickets == turn._serving == 2
+
+    def test_two_studies_do_not_wait_for_each_other(self):
+        gate = threading.Event()
+        slow = _RecordingPolicyFactory(gate=gate)
+        other = "owners/o/studies/other"
+
+        def factory(problem, algorithm, supporter, study_name):
+            if study_name == STUDY:
+                return slow(problem, algorithm, supporter, study_name)
+            return _RecordingPolicyFactory()(problem, algorithm, supporter, study_name)
+
+        servicer, pythia = _make_service(policy_factory=factory)
+        _create_study(servicer)
+        _create_study(servicer, name=other)
+        holder = threading.Thread(
+            target=servicer.SuggestTrials, args=(_suggest_request("client-0"),)
+        )
+        holder.start()
+        try:
+            assert slow.started.wait(timeout=10)
+            op = servicer.SuggestTrials(_suggest_request("client-0", name=other))
+            assert op.done and not op.error and len(op.response.trials) == 1
+            assert holder.is_alive()
+        finally:
+            gate.set()
+            holder.join(timeout=30)
+        assert pythia.serving_stats()["suggest_turns_contended"] == 0
 
 
 @pytest.fixture(scope="module")

@@ -41,12 +41,15 @@ from vizier_tpu.analysis import common
 
 PASS_NAME = "lock_order"
 
-_LOCK_CTORS = {"Lock", "RLock", "Condition"}
+# ``StudyTurns`` (serving.study_turns): study name -> a FIFO mutex held as a
+# ``with`` block, like ``defaultdict(threading.Lock)``.
+_LOCK_CTORS = {"Lock", "RLock", "Condition", "StudyTurns"}
 
 # Locks whose critical sections must stay free of blocking work. Matched
 # by site id; the list mirrors the serving stack's contention points.
 DEFAULT_CRITICAL_LOCKS = (
     "VizierServicer._study_locks",
+    "VizierServicer._study_turns",
     "DesignerStateCache._lock",
     "CachedDesignerEntry.lock",
     "RequestCoalescer._lock",
@@ -95,7 +98,7 @@ _NONBLOCKING_GRPC = frozenset(
 @dataclasses.dataclass(frozen=True)
 class LockSite:
     lock_id: str  # "Class.attr" or "module.NAME"
-    kind: str  # "Lock" | "RLock" | "Condition"
+    kind: str  # "Lock" | "RLock" | "Condition" | "StudyTurns"
     path: str
     line: int
     factory: bool = False  # constructed via a factory (defaultdict etc.)

@@ -43,6 +43,30 @@ _JITTER = 1e-5
 # zeroes the stddev and the UCB/PE terms with it. Whether a cheaper
 # precision suffices per matmul is ROADMAP S2's measurement to make.
 POSTERIOR_PRECISION = jax.lax.Precision.HIGHEST
+# The nugget every Gram carries, as a share of the amplitude: the model is
+# built with noise^2 + (NUGGET_TO_AMPLITUDE * amplitude)^2 where the noise
+# parameter alone would stand, and reports that as its ``noise_stddev``. It
+# bounds the Gram's condition number by n / NUGGET_TO_AMPLITUDE^2 (400 n:
+# 1e5 at 250 trials), which is about what float32 carries through a
+# Cholesky, its inverse and the variance's difference of near-equal terms.
+# Beyond it (a noise-free objective fits the noise's lower bound: 1e6-1e7
+# on 250 clustered trials) a v5e read the variance at a point the data
+# already held -- exact: under the noise -- as large as the largest
+# anywhere, so PE handed out a point another worker still held and the
+# promising region's threshold landed on another trial; the mean drifted by
+# a tenth of a label stddev; and an ARD train met Grams that float32 does
+# not factor and walked off to a saturated bound (PERF.md, PR 29). A noisy
+# objective (``default20d`` fits the noise at 6-8 % of the amplitude) moves
+# its noise parameter down by the nugget and keeps its Gram.
+NUGGET_TO_AMPLITUDE = 0.05
+
+
+def _conditioned(p: Params) -> Params:
+    """``p`` with the nugget (``NUGGET_TO_AMPLITUDE``) in its noise."""
+    p = dict(p)
+    nugget = NUGGET_TO_AMPLITUDE * p["amplitude"]
+    p["noise_stddev"] = jnp.sqrt(p["noise_stddev"] * p["noise_stddev"] + nugget * nugget)
+    return p
 
 
 @flax.struct.dataclass
@@ -165,6 +189,12 @@ class VizierGaussianProcess:
             )
         return params_lib.ParameterCollection(tuple(specs))
 
+    def constrain(self, unconstrained: Params) -> Params:
+        """Constrained hyperparameters as the Gram is built with them: the
+        collection's bounds, then the nugget in the noise
+        (``NUGGET_TO_AMPLITUDE``)."""
+        return _conditioned(self.param_collection().constrain(unconstrained))
+
     # -- kernel & mean -----------------------------------------------------
 
     def _warp_features(self, p: Params, f: kernels.MixedFeatures) -> kernels.MixedFeatures:
@@ -205,7 +235,8 @@ class VizierGaussianProcess:
     def neg_log_likelihood(self, unconstrained: Params, data: GPData) -> Array:
         """-log p(y | X, θ) + log-normal regularization (the ARD loss)."""
         coll = self.param_collection()
-        p = coll.constrain(unconstrained)
+        bounded = coll.constrain(unconstrained)
+        p = _conditioned(bounded)
         gram = self._masked_gram(p, data)
         chol = jnp.linalg.cholesky(gram)
         y = data.labels
@@ -217,15 +248,15 @@ class VizierGaussianProcess:
             jnp.where(data.row_mask, jnp.log(jnp.diagonal(chol)), 0.0)
         )
         nll = data_fit + log_det + 0.5 * n_valid * _LOG_2PI
-        loss = nll + coll.regularization(p)
+        # (The priors are on the parameters, not on the noise with its nugget.)
+        loss = nll + coll.regularization(bounded)
         # Guard non-finite (Cholesky blow-ups under extreme params).
         return jnp.where(jnp.isfinite(loss), loss, jnp.asarray(1e10, loss.dtype))
 
     # -- predictive --------------------------------------------------------
 
     def precompute(self, unconstrained: Params, data: GPData) -> "GPState":
-        p = self.param_collection().constrain(unconstrained)
-        return self.precompute_constrained(p, data)
+        return self.precompute_constrained(self.constrain(unconstrained), data)
 
     def precompute_constrained(self, p: Params, data: GPData) -> "GPState":
         """Precompute from already-constrained params (e.g. after a noise

@@ -55,7 +55,7 @@ from vizier_tpu.observability import config as config_lib
 STAGES = frozenset(
     {
         "service.read",  # study fetch, open-trial claim, the Pythia request
-        "policy.load_trials",  # both GetTrials: datastore -> proto -> pyvizier
+        "policy.load_trials",  # the delta read (stateless DesignerPolicy: both GetTrials) -> pyvizier
         "designer.update",  # new trials into the designer
         "designer.prepare",  # host encode / padding / RNG before the device
         "flush.stack",  # host re-stack + upload of a fused flush's members

@@ -35,6 +35,19 @@ LossFn = Callable[[Params], Array]
 DEFAULT_RANDOM_RESTARTS = 4
 
 
+# The longest step of one iteration, per coordinate of the unconstrained
+# vector. The hyperparameters are soft-clipped (``models.params.SoftClip``: a
+# sigmoid, flat beyond |x| ~ 10), and an ARD loss on near noise-free labels
+# has gradients of 1e3-1e4 where the noise is set too small: a first step
+# ``x - t * g`` then lands every parameter on a saturated bound, where the loss
+# can still be far below the start's, so Armijo accepts it, and where the
+# gradient vanishes, so the run ends "converged" on a junk fit (amplitude 100,
+# length scales 0.005; or amplitude 0.01, noise 1 — PERF.md, PR 29). Held to
+# one unit a step, a run crosses the whole useful range in a few iterations
+# and cannot jump onto the flat part.
+MAX_STEP = 1.0
+
+
 class OptimizeResult(NamedTuple):
     params: Params  # best (or top-k stacked) unconstrained params
     losses: Array  # [num_restarts] final losses
@@ -169,7 +182,9 @@ def lbfgs_minimize(
         # may have been available, so the next iteration resets to a full
         # step — otherwise a capped step cascade can stall ill-conditioned
         # runs far from the optimum.
-        t0 = state.t_init
+        # No trial point lies further than MAX_STEP from the current one in
+        # any coordinate.
+        t0 = jnp.minimum(state.t_init, MAX_STEP / jnp.maximum(jnp.max(jnp.abs(d)), 1e-30))
         t, f_new, num_halvings = jax.lax.while_loop(
             ls_cond, ls_body, (t0, loss_fn(state.x + t0 * d), jnp.asarray(0))
         )

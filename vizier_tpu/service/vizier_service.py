@@ -7,6 +7,10 @@ Parity with ``/root/reference/vizier/_src/service/vizier_service.py:64``
 The multi-worker behavioral contract is preserved exactly:
 
 - per-(owner/study/operation) locks; datastore does its own locking;
+- a study's ``SuggestTrials`` take turns, one request at a time from the
+  claim to the write, in arrival order (``serving.study_turns``): each
+  computation sees every earlier pick ACTIVE, so N workers of one study get
+  N points, as under upstream's study lock;
 - ``SuggestTrials`` first returns the client's existing ACTIVE trials, then
   drains the REQUESTED pool, then dispatches to Pythia — so a crashed
   worker that re-requests gets its old trials back;
@@ -43,6 +47,7 @@ from vizier_tpu.service import ram_datastore
 from vizier_tpu.service import resources
 from vizier_tpu.service import sql_datastore
 from vizier_tpu.service.protos import study_pb2, vizier_service_pb2
+from vizier_tpu.serving import study_turns
 
 
 class VizierServicer:
@@ -80,6 +85,13 @@ class VizierServicer:
         self._study_locks: Dict[str, threading.Lock] = collections.defaultdict(
             threading.Lock
         )
+        # One SuggestTrials of a study at a time, in arrival order, held
+        # from the claim to the write; the short study locks above are what
+        # completions, creations and reads take, so none of them waits for
+        # a turn, and different studies never wait for each other.
+        self._study_turns = study_turns.StudyTurns(
+            self._turn_waited, self._turn_held
+        )
         self._policy_factory = None  # set via set_policy_factory / pythia servicer
         self._pythia = None  # object with Suggest/EarlyStop proto methods
         # Ops created by THIS process; a persisted not-done op absent from
@@ -96,6 +108,19 @@ class VizierServicer:
         """The connected Pythia's ServingStats, or None (remote stub)."""
         runtime = getattr(self._pythia, "serving_runtime", None)
         return runtime.stats if runtime is not None else None
+
+    # A study turn's wait and hold go into the in-process Pythia's counters
+    # and histograms (there is nothing to record into with a remote one).
+
+    def _turn_waited(self, seconds: float, contended: bool) -> None:
+        runtime = getattr(self._pythia, "serving_runtime", None)
+        if runtime is not None:
+            runtime.observe_turn_wait(seconds, contended)
+
+    def _turn_held(self, seconds: float) -> None:
+        runtime = getattr(self._pythia, "serving_runtime", None)
+        if runtime is not None:
+            runtime.observe_turn_held(seconds)
 
     def serving_stats(self) -> dict:
         """Delegates to the in-process Pythia servicer's counters."""
@@ -298,32 +323,67 @@ class VizierServicer:
         # reads. Short-circuit with the typed error on a synthetic done
         # op — no op number is consumed, nothing is persisted.
         if self._reliability.deadlines_on and request.deadline_secs < 0:
-            stats = self._serving_stats_sink()
-            if stats is not None:
-                stats.increment("deadline_exceeded")
-            tracing_lib.add_current_event(
-                "deadline.exceeded", at="service_ingress"
-            )
             recorder_lib.get_recorder().record(
                 study_name, "deadline_expired_at_ingress",
                 budget_secs=float(request.deadline_secs),
             )
-            op = vizier_service_pb2.Operation(
-                name=(
-                    f"{study_name}/clients/{client_id}/operations/expired"
-                ),
-                done=True,
+            return self._expired_operation(
+                study_name, client_id, "service_ingress",
+                f"request budget expired {-request.deadline_secs:.3f}s before dispatch",
             )
-            op.error = errors_lib.format_op_error(
-                errors_lib.DeadlineExceededError(
-                    errors_lib.mark_transient(
-                        "DEADLINE_EXCEEDED: request budget expired "
-                        f"{-request.deadline_secs:.3f}s before dispatch; "
-                        "designer computation skipped."
-                    )
+        # The client's deadline budget (request.deadline_secs, remaining
+        # seconds) becomes a Deadline here and is decremented across every
+        # hop below, the wait for the study's turn first; transient failures
+        # are marked TRANSIENT: in op.error so client retry logic can tell
+        # them from permanent errors.
+        deadline = (
+            deadline_lib.Deadline.from_budget(request.deadline_secs)
+            if self._reliability.deadlines_on
+            else deadline_lib.Deadline.none()
+        )
+        # The study's turn: held from the op-dedup and the claim to the
+        # write, so this request's computation reads every earlier pick as
+        # ACTIVE. Given up on every exit; a waiter whose budget ran out
+        # while it stood in line leaves at once and the turn moves on.
+        with self._study_turns[study_name]:
+            if deadline.expired:
+                return self._expired_operation(
+                    study_name, client_id, "study_turn",
+                    f"request budget expired {-deadline.remaining():.3f}s "
+                    "before the study's suggest turn came",
+                )
+            return self._suggest_in_turn(request, study_name, client_id, deadline)
+
+    def _expired_operation(
+        self, study_name: str, client_id: str, at: str, what: str
+    ) -> vizier_service_pb2.Operation:
+        """A synthetic done op carrying the typed deadline error: no op
+        number is consumed, nothing is persisted, Pythia is never asked."""
+        stats = self._serving_stats_sink()
+        if stats is not None:
+            stats.increment("deadline_exceeded")
+        tracing_lib.add_current_event("deadline.exceeded", at=at)
+        op = vizier_service_pb2.Operation(
+            name=f"{study_name}/clients/{client_id}/operations/expired",
+            done=True,
+        )
+        op.error = errors_lib.format_op_error(
+            errors_lib.DeadlineExceededError(
+                errors_lib.mark_transient(
+                    f"DEADLINE_EXCEEDED: {what}; designer computation skipped."
                 )
             )
-            return op
+        )
+        return op
+
+    def _suggest_in_turn(
+        self,
+        request: vizier_service_pb2.SuggestTrialsRequest,
+        study_name: str,
+        client_id: str,
+        deadline: deadline_lib.Deadline,
+    ) -> vizier_service_pb2.Operation:
+        """One request from the claim to the write, inside its study's turn."""
         # The host half of the service hop is two stage spans around the
         # Pythia dispatch: service.read (what is fetched and claimed before
         # it) and service.write (what is persisted after it).
@@ -362,24 +422,14 @@ class VizierServicer:
                 self.datastore.create_suggestion_operation(op)
                 self._inflight_ops.add(op.name)
 
-            # The Pythia dispatch runs OUTSIDE the study lock: the lock
+            # The Pythia dispatch runs OUTSIDE the study lock (the lock
             # protects datastore read-modify-write windows, not the
-            # designer computation. Concurrent clients therefore reach
-            # Pythia with the same trial frontier and coalesce onto ONE
-            # computation (vizier_tpu.serving); a same-client retry
-            # meanwhile sees the not-done op above and polls GetOperation,
-            # the reference's long-running-operation contract.
-            #
-            # The client's deadline budget (request.deadline_secs, remaining
-            # seconds) becomes a Deadline here and is decremented across
-            # every hop below; transient failures are marked TRANSIENT: in
-            # op.error so client retry logic can tell them from permanent
-            # errors.
-            deadline = (
-                deadline_lib.Deadline.from_budget(request.deadline_secs)
-                if self._reliability.deadlines_on
-                else deadline_lib.Deadline.none()
-            )
+            # designer computation) and INSIDE the study's turn: another
+            # client's request for this study waits its turn and then
+            # reads this one's pick as ACTIVE. A same-client retry waits
+            # too and is handed its ACTIVE trials back. (The Pythia-level
+            # coalescer still joins identical computations that reach it
+            # from elsewhere: a second frontend, the speculative engine.)
             trials: List[study_pb2.Trial] = []
             try:
                 trials, preq = self._claim_or_request(
@@ -486,8 +536,8 @@ class VizierServicer:
                 return out, None
             max_id = self.datastore.max_trial_id(study_name)
 
-        # Ask Pythia for the remainder — lock released, so concurrent
-        # clients' identical requests can coalesce at the compute level.
+        # Ask Pythia for the remainder — study lock released (completions
+        # go on), the study's turn still held.
         if self._pythia is None:
             raise RuntimeError("No Pythia endpoint connected to the Vizier service.")
         from vizier_tpu.service.protos import pythia_service_pb2
@@ -538,10 +588,9 @@ class VizierServicer:
         count = len(out) + wanted
         sr = resources.StudyResource.from_name(study_name)
         with self._study_locks[study_name]:
-            # Re-drain first: a coalesced peer that shared this computation
-            # may have materialized extras as REQUESTED while we waited —
-            # claiming those avoids creating duplicate trials for the same
-            # suggested points.
+            # Re-drain first: trials may have entered the REQUESTED pool
+            # while the computation ran (a client's CreateTrial) — claiming
+            # those comes before creating new ones.
             refill, _ = self._claim_open_trials(
                 study_name, client_id, count - len(out), reuse_active=False
             )
@@ -551,8 +600,7 @@ class VizierServicer:
             # Materialize suggestions as trials: the first `remaining`
             # become ACTIVE for this client; extras (policy over-produced)
             # stay REQUESTED. When the re-drain supplied trials, only the
-            # shortfall is materialized — the shared computation's points
-            # already exist as the peer's trials.
+            # shortfall is materialized.
             remaining = count - len(out)
             to_create = (
                 list(presp.suggestions)[:remaining]

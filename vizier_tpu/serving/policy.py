@@ -123,6 +123,7 @@ class CachedDesignerStatePolicy(policy_lib.Policy):
         stats = self._runtime.stats
         stats.increment("trials_fetched", fetched)
         stats.increment("trials_reused", num_completed - len(new_completed))
+        stats.increment("pending_trials_conditioned", len(active))
         before = self._train_counts(designer)
         surrogate_before = self._surrogate_counts(designer)
         with tracer.span(

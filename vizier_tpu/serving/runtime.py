@@ -93,6 +93,16 @@ class ServingRuntime:
             "vizier_suggest_latency_seconds",
             help="SuggestTrials wall time per hop (service, pythia).",
         )
+        # A study's suggest turns (serving.study_turns; taken by the Vizier
+        # servicer): how long a request stood in line, how long it held.
+        self._turn_wait = self.metrics.histogram(
+            "vizier_study_turn_wait_seconds",
+            help="SuggestTrials arrival until its study's suggest turn.",
+        )
+        self._turn_held = self.metrics.histogram(
+            "vizier_study_turn_seconds",
+            help="Time a SuggestTrials held its study's turn (claim to write).",
+        )
         # Multi-tenant overload protection (vizier_tpu.serving.admission):
         # bounded in-flight admission + deadline-aware shedding + the
         # healthy→shedding→degraded state machine at the Pythia dispatch
@@ -276,6 +286,21 @@ class ServingRuntime:
             if tenant is not None:
                 labels["tenant"] = tenant
             self._suggest_latency.observe(seconds, trace_id=trace_id, **labels)
+
+    def observe_turn_wait(self, seconds: float, contended: bool) -> None:
+        """A study's suggest turn was granted (``study_turns.StudyTurn``):
+        arrival -> turn, and whether it stood behind another request. The
+        counters stay on with metrics off, like every serving counter."""
+        self.stats.increment("suggest_turns")
+        if contended:
+            self.stats.increment("suggest_turns_contended")
+        if self.observability.metrics_on:
+            self._turn_wait.observe(seconds)
+
+    def observe_turn_held(self, seconds: float) -> None:
+        """A study's suggest turn was given up after ``seconds``."""
+        if self.observability.metrics_on:
+            self._turn_held.observe(seconds)
 
     def slo_report(self) -> Dict[str, Any]:
         """Evaluates the armed SLOs now and returns the JSON-ready report

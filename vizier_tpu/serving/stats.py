@@ -42,6 +42,10 @@ class ServingStats:
         # fetched) is the share of a study a suggest did not re-read.
         "trials_fetched",  # trial protos converted to pyvizier for an update
         "trials_reused",  # completed trials the cached designer already held
+        "pending_trials_conditioned",  # ACTIVE trials handed to designer.update
+        # A study's suggest turns (vizier_tpu.serving.study_turns).
+        "suggest_turns",  # SuggestTrials that got their study's turn
+        "suggest_turns_contended",  # ... after waiting behind another request
         # Reliability (vizier_tpu.reliability): retry/fallback/breaker/deadline.
         "retries",  # client-side RPC / suggest retries
         "designer_failures",  # designer computations that raised

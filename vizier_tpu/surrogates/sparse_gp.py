@@ -240,7 +240,9 @@ class SparseGaussianProcess:
         multi-restart L-BFGS program.
         """
         coll = self.param_collection()
-        p = coll.constrain(unconstrained)
+        bounded = coll.constrain(unconstrained)
+        # (The noise as the base model builds it: with its nugget.)
+        p = gp_lib._conditioned(bounded)
         chol, chol_b, a, c, sigma2 = self._factorize(p, sdata)
         del chol
         data = sdata.data
@@ -255,7 +257,7 @@ class SparseGaussianProcess:
         # exactly tr(Qnn)/σ² (padded columns are zero).
         trace = n_valid * amp2 / sigma2 - jnp.sum(a * a)
         nll = 0.5 * (n_valid * _LOG_2PI + log_det + quad + trace)
-        loss = nll + coll.regularization(p)
+        loss = nll + coll.regularization(bounded)
         # Guard non-finite (Cholesky blow-ups under extreme params) — the
         # same fail-soft the exact GP's loss applies.
         return jnp.where(jnp.isfinite(loss), loss, jnp.asarray(1e10, loss.dtype))
@@ -264,9 +266,7 @@ class SparseGaussianProcess:
 
     def precompute(self, unconstrained: Params, sdata: SparseGPData) -> "SparseGPState":
         """Factorize once; posterior queries are then matmul-only O(m²)."""
-        return self.precompute_constrained(
-            self.param_collection().constrain(unconstrained), sdata
-        )
+        return self.precompute_constrained(self.base.constrain(unconstrained), sdata)
 
     def precompute_constrained(self, p: Params, sdata: SparseGPData) -> "SparseGPState":
         """Factorization from already-constrained params.
