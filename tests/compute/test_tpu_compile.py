@@ -271,3 +271,19 @@ def test_posterior_ucb_forward_compiles(one_chip):
         return acquisitions.UCB(1.8)(mean, stddev, jnp.max(data.labels))
 
     _fits(jax.jit(forward).lower(params, data, query).compile())
+
+
+def test_sequential_crossings_compile(one_chip):
+    """The sequential path's three small programs between its big ones
+    (``_sweep_inputs``, ``_stack_fits``, ``_append_first_pick``) at pad 512,
+    one metric: the ``top_k`` of the prior features is the only operation of
+    theirs a chip's compiler could refuse."""
+    designer = _designer(1)
+    data, states = _gp_state_shapes(designer, 512, one_chip)
+    picked = kernels.MixedFeatures(
+        jax.ShapeDtypeStruct((1, DIM), jnp.float32, sharding=one_chip),
+        jax.ShapeDtypeStruct((1, 0), jnp.int32, sharding=one_chip),
+    )
+    _fits(gp_ucb_pe._sweep_inputs.lower((data,)).compile())
+    _fits(gp_ucb_pe._stack_fits.lower((states,)).compile())
+    _fits(gp_ucb_pe._append_first_pick.lower(data, picked).compile())

@@ -333,7 +333,7 @@ class TestPlumbing:
                 _complete(p, np.linspace(0, 1, 3), lambda x: {"obj": x})
             )
         )
-        all_data = d._all_points_data(2)
+        all_data = jax.device_put(d._all_points_data(2))  # host NumPy -> device
         n_before = int(jnp.sum(all_data.row_mask))
         from vizier_tpu.models import kernels as kernels_lib
 

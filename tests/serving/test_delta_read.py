@@ -408,3 +408,56 @@ def test_a_warm_suggest_converts_only_what_it_lacks(stack, backend, monkeypatch)
     done, active = s.received()[-1]
     assert [t.id for t in done] == [t.id for t in batch]
     assert [t.id for t in active] == [t.id for t in others]
+
+
+class TestEncodedRowCounters:
+    """``rows_encoded`` / ``rows_reused`` through ``serving_stats()``: what a
+    suggest of the real DEFAULT designer encoded against what it took from
+    the designer's store of encoded rows."""
+
+    @staticmethod
+    def _real_designer(problem, **_):
+        from vizier_tpu.designers import gp_ucb_pe
+        from vizier_tpu.optimizers import lbfgs
+
+        return gp_ucb_pe.VizierGPUCBPEBandit(
+            problem,
+            max_acquisition_evaluations=100,
+            ard_optimizer=lbfgs.LbfgsOptimizer(maxiter=2),
+        )
+
+    def _round(self, s, count):
+        before = s.servicer.serving_stats()
+        suggestions = s.client(f"w{len(s.expected)}").get_suggestions(count)
+        s.expected.append(None)  # one more worker name used
+        after = s.servicer.serving_stats()
+        assert after["fallbacks"] == before["fallbacks"] == 0
+        return suggestions, {
+            k: after[k] - before[k] for k in ("rows_encoded", "rows_reused")
+        }
+
+    def test_a_round_of_25_new_trials_reuses_the_rows_it_held(self, stack):
+        s = stack()
+        s._designer = self._real_designer
+        s.add_completed(360)
+        suggestions, first = self._round(s, 25)
+        assert first == {"rows_encoded": 360, "rows_reused": 0}  # a cold entry
+        for trial in suggestions:
+            s.complete(trial, worker="w0")
+        _, second = self._round(s, 25)
+        assert second == {"rows_encoded": 25, "rows_reused": 360}
+        share = second["rows_reused"] / sum(second.values())
+        assert 0.93 < share < 0.94
+        # An invalidated entry replays the study into a new designer: every
+        # row is encoded again and none is taken from a store.
+        s.runtime.designer_cache.invalidate(STUDY)
+        _, third = self._round(s, 1)
+        assert third == {"rows_encoded": 385 + 25, "rows_reused": 0}
+
+    def test_pending_rows_are_encoded_every_turn(self, stack):
+        s = stack()
+        s._designer = self._real_designer
+        s.add_completed(6)
+        self._round(s, 2)  # two trials stay ACTIVE under their worker
+        _, turn = self._round(s, 1)
+        assert turn == {"rows_encoded": 2, "rows_reused": 6}

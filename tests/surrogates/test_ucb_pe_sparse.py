@@ -179,7 +179,7 @@ class TestNystromAugmentation:
 
     def test_far_pick_augments_inducing_set(self):
         member, d = self._trained_member()
-        all_data = d._all_points_data(2)
+        all_data = jax.device_put(d._all_points_data(2))  # host NumPy -> device
         sdata = sparse_gp.with_pending_capacity(member.sdata, all_data, 2)
         before = int(jnp.sum(sdata.inducing_mask))
         # The all-ones corner is far from the (0.3-centered) training data:
@@ -197,7 +197,7 @@ class TestNystromAugmentation:
 
     def test_near_pick_does_not_augment(self):
         member, d = self._trained_member()
-        all_data = d._all_points_data(2)
+        all_data = jax.device_put(d._all_points_data(2))  # host NumPy -> device
         sdata = sparse_gp.with_pending_capacity(member.sdata, all_data, 2)
         before = int(jnp.sum(sdata.inducing_mask))
         # An existing inducing row has zero Nyström residual by definition.
@@ -214,7 +214,7 @@ class TestNystromAugmentation:
         """Appending a pending pick must reduce the conditioned posterior's
         stddev there — the whole point of UCB-PE's all-points posterior."""
         member, d = self._trained_member()
-        all_data = d._all_points_data(2)
+        all_data = jax.device_put(d._all_points_data(2))  # host NumPy -> device
         sdata = sparse_gp.with_pending_capacity(member.sdata, all_data, 2)
         aug_model = d._sparse_all_model(2)
         x = kernels.MixedFeatures(

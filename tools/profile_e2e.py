@@ -62,7 +62,7 @@ def main() -> None:
 
     orig_train = designer._train_states_me
 
-    def timed_train():
+    def timed_train(*args, **kwargs):
         t0 = time.perf_counter()
         # Sub-time the host-side encode inside by instrumenting the converter.
         conv = designer._converter
@@ -77,9 +77,9 @@ def main() -> None:
             )
             return out
 
-        def feat(trials, extra_rows=0):
+        def feat(*rows, **kw):
             s = time.perf_counter()
-            out = orig_feat(trials, extra_rows)
+            out = orig_feat(*rows, **kw)
             stage["padded_features"] = stage.get("padded_features", 0) + (
                 time.perf_counter() - s
             )
@@ -88,7 +88,7 @@ def main() -> None:
         object.__setattr__(conv.metrics, "encode", enc)
         designer._padded_features = feat
         try:
-            out = orig_train()
+            out = orig_train(*args, **kwargs)
             jax.block_until_ready(out[0].params if hasattr(out[0], "params") else out[0])
         finally:
             object.__setattr__(conv.metrics, "encode", orig_enc)

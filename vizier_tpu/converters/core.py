@@ -265,8 +265,12 @@ class SearchSpaceEncoder:
         )
         out: List[trial_.ParameterDict] = []
         decoded_cont: Dict[str, np.ndarray] = {}
+        # Columns made contiguous: NumPy's exp takes its vector loop on a
+        # contiguous operand only, so a batch decodes to the bits its rows
+        # decode to one at a time (a one-row column is always contiguous).
+        columns = np.ascontiguousarray(continuous.T)
         for j, config in enumerate(self._continuous):
-            decoded_cont[config.name] = self._codecs[config.name].decode(continuous[:, j])
+            decoded_cont[config.name] = self._codecs[config.name].decode(columns[j])
         for i in range(n):
             params = trial_.ParameterDict()
             for config in self._continuous:
@@ -359,6 +363,85 @@ class MetricsEncoder:
         if self._flip and info.goal == base_study_config.ObjectiveMetricGoal.MINIMIZE:
             return -np.asarray(values)
         return np.asarray(values)
+
+
+class EncodedTrials:
+    """The encoded rows of a list of completed trials that only grows.
+
+    A designer's completed trials are appended to and never rewritten, so
+    their feature rows and raw metric rows are encoded once, when they
+    arrive, and kept as host NumPy: ``sync`` encodes the trials past the
+    rows it holds. It trusts no caller: a list that is shorter than the
+    store, or whose trial at the store's last row is another object, is a
+    list that was rebound or rewritten, and is encoded again from its start.
+    Rows are independent of one another in both encoders, so a store built
+    by appends holds the bits one ``encode`` of the whole list returns.
+
+    ``rows_encoded`` / ``rows_reused`` count, at each ``tally`` (one a
+    suggest), the rows encoded since the last one against the rows that
+    were held already.
+    """
+
+    def __init__(self, encoder: SearchSpaceEncoder, metrics: MetricsEncoder):
+        self._encoder = encoder
+        self._metrics = metrics
+        self.rows_encoded = 0
+        self.rows_reused = 0
+        self.reset()
+
+    def reset(self) -> None:
+        """Drops every row: the next ``sync`` encodes its list from scratch."""
+        self._continuous = np.zeros((0, self._encoder.num_continuous), np.float32)
+        self._categorical = np.zeros((0, self._encoder.num_categorical), np.int32)
+        self._labels = np.zeros((0, self._metrics.num_metrics), np.float64)
+        self._size = 0
+        self._last: Optional[trial_.Trial] = None
+        self._untallied = 0
+
+    def sync(self, trials: Sequence[trial_.Trial]) -> None:
+        """Brings the store up to ``trials``, encoding only what it lacks."""
+        held = self._size
+        if held > len(trials) or (held and trials[held - 1] is not self._last):
+            self.reset()
+            held = 0
+        new = trials[held:]
+        if not new:
+            return
+        continuous, categorical = self._encoder.encode(new)
+        size = held + len(new)
+        if size > self._continuous.shape[0]:
+            capacity = max(size, 2 * self._continuous.shape[0])
+            self._continuous = _grown(self._continuous, held, capacity)
+            self._categorical = _grown(self._categorical, held, capacity)
+            self._labels = _grown(self._labels, held, capacity)
+        self._continuous[held:size] = continuous  # float64 -> float32
+        self._categorical[held:size] = categorical
+        self._labels[held:size] = self._metrics.encode(new)
+        self._size = size
+        self._last = trials[-1]
+        self._untallied += len(new)
+
+    def features(self) -> Tuple[np.ndarray, np.ndarray]:
+        """(continuous [N, Dc] float32, categorical [N, Ds] int32) views."""
+        return self._continuous[: self._size], self._categorical[: self._size]
+
+    def labels(self) -> np.ndarray:
+        """[N, M] float64 view: all-MAXIMIZE raw metrics, NaN infeasible."""
+        return self._labels[: self._size]
+
+    def tally(self, also_encoded: int = 0) -> None:
+        """Counts one suggest's read: the rows encoded for it (those since
+        the last tally, plus ``also_encoded`` rows the caller encoded beside
+        the store) and the rows it took as they were held."""
+        self.rows_encoded += self._untallied + also_encoded
+        self.rows_reused += self._size - self._untallied
+        self._untallied = 0
+
+
+def _grown(rows: np.ndarray, held: int, capacity: int) -> np.ndarray:
+    out = np.empty((capacity,) + rows.shape[1:], rows.dtype)
+    out[:held] = rows[:held]
+    return out
 
 
 @dataclasses.dataclass(frozen=True)

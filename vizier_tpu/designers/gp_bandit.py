@@ -124,6 +124,12 @@ def _prior_features_from_data(data: gp_lib.GPData) -> kernels.MixedFeatures:
     return kernels.MixedFeatures(data.continuous[idx], data.categorical[idx])
 
 
+# The sequential path's call: ONE small program a fit, compiled in set-up,
+# where the eager form launched one per operation (where, top_k, sum, two
+# gathers and their index arithmetic).
+_prior_features_jit = jax.jit(_prior_features_from_data)
+
+
 # -- cross-study batched programs (vizier_tpu.parallel.batch_executor) ------
 #
 # The padding schedule makes concurrent studies shape-identical by
@@ -366,9 +372,17 @@ class VizierGPBandit(core_lib.Designer, core_lib.Predictor):
             self.problem.search_space, seed=self.rng_seed
         )
         self._trials: List[trial_.Trial] = []
+        # The encoded rows of ``_trials``, kept between suggests: ``update``
+        # appends the rows of the trials it is handed, and every reader goes
+        # through ``_completed_rows``, whose ``sync`` catches a ``_trials``
+        # that was rebound or rewritten and encodes it again from scratch.
+        self._store = converters.EncodedTrials(enc, self._converter.metrics)
         self._warper_fitted = False
         self._rng = jax.random.PRNGKey(self.rng_seed)
-        self._last_predictive: Optional[gp_lib.EnsemblePredictive] = None
+        # What ``_last_predictive`` holds, and a fit that becomes one only
+        # when somebody reads it (see the property).
+        self._predictive = None
+        self._unread_fit = None
         # Production multi-chip path (SURVEY §2.10): when more than one
         # device is visible, suggest() shards ARD restarts and acquisition
         # pools over a mesh automatically — a user calling suggest() on a
@@ -420,6 +434,44 @@ class VizierGPBandit(core_lib.Designer, core_lib.Predictor):
     ) -> None:
         del all_active
         self._trials.extend(completed.trials)
+        self._store.sync(self._trials)
+
+    @property
+    def _last_predictive(self):
+        """The last fit's predictive. A fit that needs device work to become
+        one (GP-UCB-PE slices metric 0 out of its per-metric state, a
+        program per leaf) waits in ``_unread_fit`` until somebody reads
+        this: ``predict``/``sample`` and an operator do, a suggest does not.
+        Callers hold the designer as they do for a suggest (the serving
+        cache entry's lock)."""
+        if self._unread_fit is not None:
+            fit, self._unread_fit = self._unread_fit, None
+            self._predictive = self._predictive_of(fit)
+        return self._predictive
+
+    @_last_predictive.setter
+    def _last_predictive(self, predictive) -> None:
+        self._predictive = predictive
+        self._unread_fit = None
+
+    def _predictive_of(self, fit):
+        """The predictive of an ``_unread_fit`` (designers that defer one)."""
+        raise NotImplementedError
+
+    def _completed_rows(self) -> tuple:
+        """(continuous [N, Dc] float32, categorical [N, Ds] int32, raw
+        labels [N, M] float64) of ``_trials``, from the store."""
+        self._store.sync(self._trials)
+        return (*self._store.features(), self._store.labels())
+
+    @property
+    def encoded_row_counts(self) -> dict:
+        """Rows the suggests encoded against rows they took from the store
+        as they were held (serving stats)."""
+        return {
+            "encoded": self._store.rows_encoded,
+            "reused": self._store.rows_reused,
+        }
 
     # -- mesh-aware compute (the ONE production train/sweep implementation) --
 
@@ -710,23 +762,27 @@ class VizierGPBandit(core_lib.Designer, core_lib.Predictor):
         return out
 
     def _padded_features(
-        self, trials: Sequence[trial_.Trial], extra_rows: int = 0
+        self,
+        continuous: np.ndarray,
+        categorical: np.ndarray,
+        extra_rows: int = 0,
     ) -> tuple:
-        """(ModelInput, n_pad): the ONE encode+pad implementation.
+        """(ModelInput, n_pad) of encoded rows: the ONE pad implementation.
 
+        The rows come from the store (``_completed_rows``) or, for trials
+        that are not this study's completed ones, from the encoder.
         ``extra_rows`` reserves additional padded capacity (e.g. for batch
         fantasy conditioning in GP-UCB-PE).
         """
         conv = self._converter
-        n_pad = conv.padding.pad_trials(len(trials) + extra_rows)
-        cont, cat = conv.encoder.encode(trials)
+        n_pad = conv.padding.pad_trials(continuous.shape[0] + extra_rows)
         features = types.ContinuousAndCategorical(
             continuous=types.PaddedArray.from_array(
-                cont.astype(np.float32),
+                continuous.astype(np.float32, copy=False),
                 (n_pad, conv.padding.pad_features(conv.encoder.num_continuous)),
             ),
             categorical=types.PaddedArray.from_array(
-                cat.astype(np.int32),
+                categorical.astype(np.int32, copy=False),
                 (n_pad, conv.padding.pad_features(conv.encoder.num_categorical)),
                 fill_value=0,
             ),
@@ -742,11 +798,13 @@ class VizierGPBandit(core_lib.Designer, core_lib.Predictor):
 
     def _warped_model_data(self, extra_rows: int = 0) -> types.ModelData:
         """Encode + warp labels + pad. Labels leave here all-MAXIMIZE ~N(0,1)."""
-        conv = self._converter
-        raw_labels = conv.metrics.encode(self._trials)  # [N, M], NaN infeasible
+        cont, cat, raw_labels = self._completed_rows()  # labels NaN infeasible
+        self._store.tally()
+        # The warp is a whole-study computation (half-rank and the
+        # infeasible shift depend on every label): only its input is kept.
         warped = self._warper(raw_labels[:, self.metric_index])
         self._warper_fitted = raw_labels.shape[0] > 0
-        features, n_pad = self._padded_features(self._trials, extra_rows)
+        features, n_pad = self._padded_features(cont, cat, extra_rows)
         return types.ModelData(
             features=features, labels=self._padded_labels(warped, n_pad)
         )
@@ -880,13 +938,13 @@ class VizierGPBandit(core_lib.Designer, core_lib.Predictor):
         cont, cat, scores = jax.device_get(
             (result.features.continuous, result.features.categorical, result.scores)
         )
-        cont, cat, scores = cont[:count], cat[:count], scores[:count]
+        enc = self._converter.encoder
+        # ONE decode of the batch's rows, as of its fetch.
+        parameters = self._converter.to_parameters(
+            cont[:count, : enc.num_continuous], cat[:count, : enc.num_categorical]
+        )
         suggestions = []
-        for row_cont, row_cat, score in zip(cont, cat, scores):
-            params = self._converter.to_parameters(
-                row_cont[None, : self._converter.encoder.num_continuous],
-                row_cat[None, : self._converter.encoder.num_categorical],
-            )[0]
+        for params, score in zip(parameters, scores[:count]):
             s = trial_.TrialSuggestion(parameters=params)
             s.metadata.ns("gp_bandit")["acquisition"] = float(score)
             s.metadata.ns("gp_bandit")["acquisition_kind"] = kind
@@ -901,7 +959,7 @@ class VizierGPBandit(core_lib.Designer, core_lib.Predictor):
         raw = conv.metrics.encode(trials)
         warped = self._warper(raw[:, self.metric_index])
         self._warper_fitted = raw.shape[0] > 0
-        features, n_pad = self._padded_features(trials)
+        features, n_pad = self._padded_features(*conv.encoder.encode(trials))
         return gp_lib.GPData.from_model_data(
             types.ModelData(features, self._padded_labels(warped, n_pad))
         )
@@ -950,15 +1008,14 @@ class VizierGPBandit(core_lib.Designer, core_lib.Predictor):
 
     def _suggest_multiobjective(self, count: int) -> List[trial_.TrialSuggestion]:
         """Random-hypervolume scalarized UCB over per-metric GPs."""
-        conv = self._converter
-        trials = self._trials
-        raw = conv.metrics.encode(trials)  # [N, M] all-MAXIMIZE
+        cont, cat, raw = self._completed_rows()  # raw: [N, M] all-MAXIMIZE
+        self._store.tally()
         objective_idx = [
             j
             for j, m in enumerate(self.problem.metric_information)
             if not m.is_safety_metric
         ]
-        features, n_pad = self._padded_features(trials)
+        features, n_pad = self._padded_features(cont, cat)
         datas = []
         refs = []
         for j in objective_idx:
@@ -1037,7 +1094,7 @@ class VizierGPBandit(core_lib.Designer, core_lib.Predictor):
         Slots past the valid rows would be all-zero padding rows, so
         :func:`_prior_features_from_data` redirects them to the best row.
         """
-        return _prior_features_from_data(data)
+        return _prior_features_jit(data)
 
     # -- Predictor ---------------------------------------------------------
 

@@ -285,6 +285,57 @@ def _append_row_sparse(
     )
 
 
+# The sequential path's three crossings between its compiled programs. Each
+# is ONE small program, compiled in set-up with the shapes it serves, where
+# the eager form launched one program per operation and per leaf.
+# ``_stack_fits`` and ``_append_first_pick`` only move and select values.
+# ``_sweep_inputs`` computes (the reference point is a min, a max, a multiply
+# and a subtract per metric; the prior features a ``top_k`` and a sum), so
+# that its bits are the eager form's is measured, not given: suggestions and
+# metadata equal the eager tree's on the CPU (tests/designers) and on a v5e
+# (PERF.md section 6, PR 30: served and designer-level sequences, one and two
+# metrics, exact, sparse and multitask).
+
+
+@jax.jit
+def _stack_fits(states_list):
+    """Per-metric trained states -> (states [M, E, ...], each metric's best
+    member's constrained params: what seeds its next train)."""
+    states_me = jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *states_list)
+    best = [
+        jax.tree_util.tree_map(lambda a: a[0], states.params)
+        for states in states_list
+    ]
+    return states_me, best
+
+
+@jax.jit
+def _sweep_inputs(datas: Tuple[gp_lib.GPData, ...]):
+    """What the sweeps read of the completed data besides the fit: labels
+    [M, N1], their row mask, the reference point (nadir - 0.1 * range,
+    Ishibuchi2011) and the prior features. The formulas the fused programs
+    fold into their trace (``_sweep_batched``)."""
+    labels_mn = jnp.stack([d.labels for d in datas])
+    labels_mask = datas[0].row_mask
+    ref_point = acquisitions.get_reference_point(labels_mn, labels_mask)
+    prior = gp_bandit._prior_features_from_data(datas[0])
+    return labels_mn, labels_mask, ref_point, prior
+
+
+@jax.jit
+def _append_first_pick(all_data, features: kernels.MixedFeatures, states_me=None):
+    """The all-points data with a sweep's first pick written into its first
+    free row: what the next sweep conditions on. ``states_me`` is the sparse
+    path's trained state (its member 0 decides the Nystrom augment)."""
+    x = kernels.MixedFeatures(features.continuous[:1], features.categorical[:1])
+    if isinstance(all_data, sparse_gp.SparseGPData):
+        member0 = jax.tree_util.tree_map(lambda a: a[0, 0], states_me)
+        return _append_row_sparse(all_data, x, member0)
+    if isinstance(all_data, mtgp.MultiTaskData):
+        return _append_row_mt(all_data, x)
+    return _append_row(all_data, x)
+
+
 def _hv_scalarized(
     values: Array,  # [M, Q] per-metric acquisition values
     weights: Array,  # [K, M] positive scalarization directions
@@ -904,6 +955,9 @@ class VizierGPUCBPEBandit(gp_bandit.VizierGPBandit):
         # Trained per-metric states, reused until new data arrives (predict/
         # sample after a suggest must not pay a second ARD optimization).
         self._cached_states = None
+        # (datas, _sweep_inputs(datas)) of the last fit's datas: made once
+        # a fit, found again by the identity of the list.
+        self._sweep_inputs_of: Optional[tuple] = None
         # Joint set-PE optimizers are built lazily per batch size.
         self._set_opt_cache: dict = {}
         # Per-pick sweep optimizers under the per_batch budget policy, keyed
@@ -960,6 +1014,7 @@ class VizierGPUCBPEBandit(gp_bandit.VizierGPBandit):
         if completed.trials:
             self._cached_states = None  # new labels invalidate the GP fit
         self._trials.extend(completed.trials)
+        self._store.sync(self._trials)
         self._active_trials = list(all_active.trials)
 
     def _has_new_completed_trials(self) -> bool:
@@ -1043,18 +1098,24 @@ class VizierGPUCBPEBandit(gp_bandit.VizierGPBandit):
             base=self._model, num_inducing=base.num_inducing + count
         )
 
+    @staticmethod
+    def _warp_column(raw: np.ndarray) -> Tuple[np.ndarray, Any]:
+        """One metric's raw labels warped by a warper fitted on them: a
+        whole-study computation (half-rank and the infeasible shift depend
+        on every label), so the store keeps its input and not its result."""
+        warper = output_warpers.create_default_warper()
+        return (warper(raw) if raw.shape[0] else raw), warper
+
     def _encode_datas(self) -> List[gp_lib.GPData]:
-        """The host half of a train: the completed trials encoded, warped
-        and padded, one GPData per objective metric."""
-        conv = self._converter
-        raw = conv.metrics.encode(self._trials)  # [N, M_all], all-MAXIMIZE
-        features, n_pad = self._padded_features(self._trials)
+        """The host half of a train: the completed trials' rows from the
+        store, warped and padded, one host GPData per objective metric."""
+        cont, cat, raw = self._completed_rows()  # raw: [N, M_all], all-MAXIMIZE
+        features, n_pad = self._padded_features(cont, cat)
         datas = []
         self._metric_warpers = []
         self._warpers_fitted = raw.shape[0] > 0
         for j in self._objective_indices():
-            warper = output_warpers.create_default_warper()
-            warped = warper(raw[:, j]) if raw.shape[0] else raw[:, j]
+            warped, warper = self._warp_column(raw[:, j])
             self._metric_warpers.append(warper)
             data = gp_lib.GPData.from_model_data(
                 types.ModelData(features, self._padded_labels(warped, n_pad))
@@ -1099,15 +1160,8 @@ class VizierGPUCBPEBandit(gp_bandit.VizierGPBandit):
                 self._warm_params_me[0],
             )
             self._record_train()
-            if self._warm_update_allowed():
-                coll = self._model.param_collection()
-                self._warm_params_me = [
-                    coll.unconstrain(
-                        jax.tree_util.tree_map(lambda a: a[0], states.params)
-                    )
-                ]
-                self._warm_is_trained = True
-            states_me = jax.tree_util.tree_map(lambda a: a[None], states)
+            states_me, best = _stack_fits((states,))
+            self._seed_next_trains(best)
             self._cached_states = (states_me, datas)
             return self._cached_states
         if self._use_multitask(len(datas)):
@@ -1153,20 +1207,18 @@ class VizierGPUCBPEBandit(gp_bandit.VizierGPBandit):
             for j, data in enumerate(datas)
         ]
         self._record_train()
-        if self._warm_update_allowed():
-            coll = self._model.param_collection()
-            self._warm_params_me = [
-                coll.unconstrain(
-                    jax.tree_util.tree_map(lambda a: a[0], states.params)
-                )
-                for states in states_list
-            ]
-            self._warm_is_trained = True
-        states_me = jax.tree_util.tree_map(
-            lambda *xs: jnp.stack(xs), *states_list
-        )
+        states_me, best = _stack_fits(tuple(states_list))
+        self._seed_next_trains(best)
         self._cached_states = (states_me, datas)
         return self._cached_states
+
+    def _seed_next_trains(self, best: List[gp_lib.Params]) -> None:
+        """Each metric's best member seeds its next train (constrained
+        params mapped back through the bijectors), once the floor is met."""
+        if self._warm_update_allowed():
+            coll = self._model.param_collection()
+            self._warm_params_me = [coll.unconstrain(p) for p in best]
+            self._warm_is_trained = True
 
     # -- serving warm-start surface (vizier_tpu.serving) --------------------
 
@@ -1249,23 +1301,42 @@ class VizierGPUCBPEBandit(gp_bandit.VizierGPBandit):
 
     def _all_points_model_data(self, count: int) -> types.ModelData:
         """Host (numpy) ModelData over completed+active rows with capacity
-        for the picks."""
-        all_trials = list(self._trials) + list(self._active_trials)
-        features, n_pad = self._padded_features(all_trials, extra_rows=count)
-        spare = n_pad - len(all_trials)
+        for the picks: the store's rows and, encoded now (they are replaced
+        on every update), the ACTIVE trials'. Every suggest path reads its
+        all-points rows here once, so this is where the store's read is
+        counted."""
+        cont, cat, _ = self._completed_rows()
+        active = self._active_trials
+        self._store.tally(also_encoded=len(active))
+        if active:
+            active_cont, active_cat = self._converter.encoder.encode(active)
+            cont = np.concatenate([cont, active_cont.astype(np.float32)])
+            cat = np.concatenate([cat, active_cat])
+        num_rows = cont.shape[0]
+        features, n_pad = self._padded_features(cont, cat, extra_rows=count)
+        spare = n_pad - num_rows
         if spare < count:  # capacity guard: _append_row must never no-op
             raise RuntimeError(
                 f"Padded capacity {n_pad} leaves {spare} spare rows for a "
                 f"batch of {count}; padding schedule must reserve the batch."
             )
         zero_labels = types.PaddedArray.from_array(
-            np.zeros((len(all_trials), 1), np.float32), (n_pad, 1), fill_value=np.nan
+            np.zeros((num_rows, 1), np.float32), (n_pad, 1), fill_value=np.nan
         )
         return types.ModelData(features, zero_labels)
 
     def _all_points_data(self, count: int) -> gp_lib.GPData:
-        """GPData over completed+active rows with capacity for the picks."""
+        """Host GPData over completed+active rows with capacity for the
+        picks."""
         return gp_lib.GPData.from_model_data(self._all_points_model_data(count))
+
+    def _sweep_inputs(self, datas: List[gp_lib.GPData]) -> tuple:
+        """``_sweep_inputs`` of a fit's datas, made once a fit: a suggest
+        on a cached fit finds them again and launches nothing for them."""
+        held = self._sweep_inputs_of
+        if held is None or held[0] is not datas:
+            held = self._sweep_inputs_of = (datas, _sweep_inputs(tuple(datas)))
+        return held[1]
 
     def suggest(self, count: Optional[int] = None) -> List[trial_.TrialSuggestion]:
         count = count or 1
@@ -1290,13 +1361,11 @@ class VizierGPUCBPEBandit(gp_bandit.VizierGPBandit):
             else:
                 datas = self._cached_states[1]
             all_data = self._all_points_data(count)
-            labels_mn = jnp.stack([d.labels for d in datas])  # [M, N1]
-            labels_mask = datas[0].row_mask
-            # Reference point: nadir − 0.1·range (Ishibuchi2011, shared helper).
-            ref_point = acquisitions.get_reference_point(labels_mn, labels_mask)
-            first_has_new = jnp.asarray(self._has_new_completed_trials())
-            has_completed = jnp.asarray(bool(self._trials))
-            prior_feats = self._prior_features(datas[0])
+            labels_mn, labels_mask, ref_point, prior_feats = self._sweep_inputs(
+                datas
+            )
+            first_has_new = np.asarray(self._has_new_completed_trials())
+            has_completed = np.asarray(bool(self._trials))
         with profiler.timeit("train_gp"):
             # Device-attributed ARD timing (compile vs. steady-state): see
             # gp_bandit.suggest for the rationale; no-op + no device sync
@@ -1365,28 +1434,15 @@ class VizierGPUCBPEBandit(gp_bandit.VizierGPBandit):
                     self.config, self.use_trust_region, self._mesh,
                     self.prior_acquisition,
                 )
-                x = kernels.MixedFeatures(
-                    first.features.continuous[:1],
-                    first.features.categorical[:1],
+                all_data = _append_first_pick(
+                    all_data, first.features, states_me if is_sparse else None
                 )
-                if is_sparse:
-                    all_data = _append_row_sparse(
-                        all_data,
-                        x,
-                        jax.tree_util.tree_map(
-                            lambda a: a[0, 0], states_me
-                        ),
-                    )
-                else:
-                    all_data = (_append_row_mt if is_mt else _append_row)(
-                        all_data, x
-                    )
                 # _pick_vec_opt(count) is the ONE budget-dispatch point: under
                 # first_pick_full it returns the (count-1)-way split sweep.
                 rest, aux2 = _suggest_batch(
                     model, self._pick_vec_opt(count), states_me,
                     all_data, labels_mn, labels_mask, ref_point, prior_feats,
-                    self._next_rng(), jnp.asarray(False), has_completed,
+                    self._next_rng(), np.asarray(False), has_completed,
                     count - 1, self.config, self.use_trust_region,
                     self._mesh, self.prior_acquisition,
                 )
@@ -1418,29 +1474,30 @@ class VizierGPUCBPEBandit(gp_bandit.VizierGPBandit):
         with profiler.timeit("best_candidates_to_trials"), tracer.span(
             "designer.decode"
         ):
-            # The fit's writeback belongs to the decode, as in the flush
-            # programs' finalize: a device slice per leaf, which the sweeps
-            # above do not wait for.
             self._remember_fit(states_me)
-            out: List[trial_.TrialSuggestion] = []
-            for result, aux, rows in results:
-                out.extend(self._decode_ucb_pe(result, aux, rows))
-            return out
+            return self._decode_ucb_pe(results)
 
     def _remember_fit(self, states_me) -> None:
-        """Keeps metric 0's trained posterior for predict()/sample()."""
+        """Keeps the trained per-metric state; metric 0's predictive is made
+        of it when somebody reads ``_last_predictive`` (a device slice per
+        leaf, which no suggest needs)."""
+        self._predictive = None
+        self._unread_fit = states_me
+
+    def _predictive_of(self, states_me):
+        """Metric 0's trained posterior, as predict()/sample() read it."""
         if isinstance(states_me, mtgp.MultiTaskGPState):
-            self._last_predictive = _MetricZeroMTPredictive(states_me)
-        elif isinstance(states_me, sparse_gp.SparseGPState):
-            member_states = jax.tree_util.tree_map(lambda a: a[0], states_me)
-            self._last_predictive = sparse_gp.SparseEnsemblePredictive(
-                member_states
-            )
+            return _MetricZeroMTPredictive(states_me)
+        member_states = jax.tree_util.tree_map(lambda a: a[0], states_me)
+        if isinstance(states_me, sparse_gp.SparseGPState):
             self._last_sparse_state = member_states
-        else:
-            self._last_predictive = gp_lib.EnsemblePredictive(
-                jax.tree_util.tree_map(lambda a: a[0], states_me)
-            )
+            return sparse_gp.SparseEnsemblePredictive(member_states)
+        return gp_lib.EnsemblePredictive(member_states)
+
+    def sparse_inducing_state(self) -> Optional[sparse_gp.SparseGPState]:
+        if isinstance(self._unread_fit, sparse_gp.SparseGPState):
+            _ = self._last_predictive  # slices the unread fit, this state with it
+        return self._last_sparse_state
 
     def _suggest_with_set_acquisition(
         self, count, states_me, all_data, labels_mn, labels_mask, ref_point,
@@ -1453,18 +1510,13 @@ class VizierGPUCBPEBandit(gp_bandit.VizierGPBandit):
                 first, aux1 = _suggest_batch(
                     self._model, self._vec_opt, states_me, all_data,
                     labels_mn, labels_mask, ref_point,
-                    self._prior_features(datas[0]), self._next_rng(),
+                    self._sweep_inputs(datas)[3], self._next_rng(),
                     first_has_new, has_completed, 1, self.config,
                     self.use_trust_region, self._mesh, self.prior_acquisition,
                 )
                 jax.block_until_ready(first.scores)
-            suggestions.extend(self._decode_ucb_pe(first, aux1, 1))
-            all_data = _append_row(
-                all_data,
-                kernels.MixedFeatures(
-                    first.features.continuous[:1], first.features.categorical[:1]
-                ),
-            )
+            suggestions.extend(self._decode_ucb_pe([(first, aux1, 1)]))
+            all_data = _append_first_pick(all_data, first.features)
         q = count - len(suggestions)
         set_opt = self._set_opt_cache.get(q)
         if set_opt is None:
@@ -1494,41 +1546,53 @@ class VizierGPUCBPEBandit(gp_bandit.VizierGPBandit):
             )
             jax.block_until_ready(result.scores)
         with profiler.timeit("best_candidates_to_trials"):
-            suggestions.extend(self._decode_ucb_pe(result, aux, q))
+            suggestions.extend(self._decode_ucb_pe([(result, aux, q)]))
         return suggestions
 
     def _decode_ucb_pe(
-        self, result: vectorized_lib.VectorizedOptimizerResult, aux: dict, count: int
+        self, segments: Sequence[Tuple[Any, dict, int]]
     ) -> List[trial_.TrialSuggestion]:
+        """The suggestions of a suggest's sweeps, ``(result, aux, rows)``
+        each in pick order: ONE device->host fetch for everything (each
+        separate np.asarray on a device array is a blocking round trip) and
+        ONE decode of the whole batch's rows."""
         conv = self._converter
-        # ONE device->host fetch for everything this decode needs: each
-        # separate np.asarray on a device array is a blocking round trip.
         fetched = jax.device_get(
-            (
-                result.features.continuous,
-                result.features.categorical,
-                result.scores,
-                aux["mean"],
-                aux["stddev"],
-                aux["stddev_from_all"],
-                aux["use_ucb"],
-                aux["trust_radius"],
-            )
+            [
+                (
+                    result.features.continuous,
+                    result.features.categorical,
+                    result.scores,
+                    aux["mean"],
+                    aux["stddev"],
+                    aux["stddev_from_all"],
+                    aux["use_ucb"],
+                    aux["trust_radius"],
+                )
+                for result, aux, _ in segments
+            ]
         )
-        cont, cat, scores = fetched[0][:count], fetched[1][:count], fetched[2][:count]
-        mean, stddev, stddev_all, use_ucb = fetched[3:7]
-        trust_radius = float(fetched[7])
+        # Each sweep's first ``rows`` picks, one after another; a sweep's one
+        # trust radius is every one of its picks'.
+        columns: List[List[np.ndarray]] = [[] for _ in range(8)]
+        for (*per_pick, radius), (_, _, rows) in zip(fetched, segments):
+            for column, values in zip(columns, per_pick):
+                column.append(np.asarray(values)[:rows])
+            columns[7].append(np.full(rows, radius))
+        cont, cat, scores, mean, stddev, stddev_all, use_ucb, trust_radius = (
+            np.concatenate(column) for column in columns
+        )
+        parameters = conv.to_parameters(
+            cont[:, : conv.encoder.num_continuous],
+            cat[:, : conv.encoder.num_categorical],
+        )
         suggestions = []
-        for i in range(count):
-            params = conv.to_parameters(
-                cont[i : i + 1, : conv.encoder.num_continuous],
-                cat[i : i + 1, : conv.encoder.num_categorical],
-            )[0]
+        for i, params in enumerate(parameters):
             s = trial_.TrialSuggestion(parameters=params)
             ns = s.metadata.ns("gp_ucb_pe")
             ns["acquisition"] = float(scores[i])
             ns["use_ucb"] = str(bool(use_ucb[i]))
-            ns["trust_radius"] = trust_radius
+            ns["trust_radius"] = float(trust_radius[i])
             pred = ns.ns("prediction_in_warped_y_space")
             pred["mean"] = np.array2string(mean[i], separator=",")
             pred["stddev"] = np.array2string(stddev[i], separator=",")
@@ -1634,19 +1698,16 @@ def _ucb_pe_prepare(
 ) -> dict:
     """Host-side half of a batched UCB-PE suggest (single-objective path).
 
-    Encodes + warps this study's data and draws RNG keys in exactly the
-    sequential order: one train key, then one acquisition key per
+    Pads + warps this study's rows from the store and draws RNG keys in
+    exactly the sequential order: one train key, then one acquisition key per
     ``_suggest_batch`` call the budget policy would make. Host-only (numpy
     ModelData): GPData conversion, label stacking, reference point, and
     prior features all happen inside the batched device programs —
     prepare's only device work is the RNG splits.
     """
-    conv = designer._converter
-    raw = conv.metrics.encode(designer._trials)
-    features, n_pad = designer._padded_features(designer._trials)
-    j = designer._objective_indices()[0]
-    warper = output_warpers.create_default_warper()
-    warped = warper(raw[:, j]) if raw.shape[0] else raw[:, j]
+    cont, cat, raw = designer._completed_rows()
+    features, n_pad = designer._padded_features(cont, cat)
+    warped, warper = designer._warp_column(raw[:, designer._objective_indices()[0]])
     designer._metric_warpers = [warper]
     designer._warpers_fitted = raw.shape[0] > 0
     md = types.ModelData(features, designer._padded_labels(warped, n_pad))
@@ -1800,10 +1861,7 @@ class UCBPEProgram(compute_ir.DesignerProgram):
         states_me = jax.tree_util.tree_map(lambda a: a[None], states)  # [1, E]
         designer._cached_states = (states_me, [output["data"]])
         designer._last_predictive = gp_lib.EnsemblePredictive(states)
-        out: List[trial_.TrialSuggestion] = []
-        for result, aux, rows in output["segments"]:
-            out.extend(designer._decode_ucb_pe(result, aux, rows))
-        return out
+        return designer._decode_ucb_pe(output["segments"])
 
     def prewarm_factory(self, problem, **kwargs):
         return VizierGPUCBPEBandit(problem, **kwargs)
@@ -1904,10 +1962,7 @@ class UCBPESparseProgram(compute_ir.DesignerProgram):
         designer._last_predictive = sparse_gp.SparseEnsemblePredictive(states)
         designer._last_sparse_state = states
         designer._surrogate_counts["sparse_suggests"] += 1
-        out: List[trial_.TrialSuggestion] = []
-        for result, aux, rows in output["segments"]:
-            out.extend(designer._decode_ucb_pe(result, aux, rows))
-        return out
+        return designer._decode_ucb_pe(output["segments"])
 
     def prewarm_factory(self, problem, **kwargs):
         return VizierGPUCBPEBandit(problem, **kwargs)

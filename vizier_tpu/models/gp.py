@@ -82,18 +82,26 @@ class GPData:
 
     @classmethod
     def from_model_data(cls, data: types.ModelData, metric_index: int = 0) -> "GPData":
+        """Host ``ModelData`` (every leaf NumPy) converts in NumPy, to NumPy
+        leaves: masks and selections only, so the bits are those the traced
+        conversion gives, and the host launches no device program for them.
+        The compiled programs take either."""
+        on_host = all(
+            isinstance(leaf, np.ndarray) for leaf in jax.tree_util.tree_leaves(data)
+        )
+        xp = np if on_host else jnp
         cont = data.features.continuous
         cat = data.features.categorical
         labels = data.labels.padded_array[:, metric_index]
         row_mask = (
             cont.valid_mask(0)
             & data.labels.valid_mask(0)
-            & ~jnp.isnan(labels)
+            & ~xp.isnan(labels)
         )
         return cls(
-            continuous=jnp.asarray(cont.padded_array, jnp.float32),
-            categorical=jnp.asarray(cat.padded_array, jnp.int32),
-            labels=jnp.where(row_mask, jnp.nan_to_num(labels), 0.0).astype(jnp.float32),
+            continuous=xp.asarray(cont.padded_array, xp.float32),
+            categorical=xp.asarray(cat.padded_array, xp.int32),
+            labels=xp.where(row_mask, xp.nan_to_num(labels), 0.0).astype(xp.float32),
             row_mask=row_mask,
             cont_dim_mask=cont.valid_mask(1),
             cat_dim_mask=cat.valid_mask(1),

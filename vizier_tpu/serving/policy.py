@@ -126,6 +126,7 @@ class CachedDesignerStatePolicy(policy_lib.Policy):
         stats.increment("pending_trials_conditioned", len(active))
         before = self._train_counts(designer)
         surrogate_before = self._surrogate_counts(designer)
+        rows_before = self._row_counts(designer)
         with tracer.span(
             "designer.update",
             designer=type(designer).__name__,
@@ -164,6 +165,7 @@ class CachedDesignerStatePolicy(policy_lib.Policy):
         self._account_surrogate(
             surrogate_before, self._surrogate_counts(designer)
         )
+        self._account_rows(rows_before, self._row_counts(designer))
         # Mirror the trained unconstrained ARD params into the entry: the
         # stats/inspection surface for "what would seed the next train",
         # and the hand-off if the designer is ever rebuilt around them.
@@ -194,6 +196,18 @@ class CachedDesignerStatePolicy(policy_lib.Policy):
     def _surrogate_counts(designer: Any) -> Optional[dict]:
         counts = getattr(designer, "surrogate_counts", None)
         return dict(counts) if counts is not None else None
+
+    @staticmethod
+    def _row_counts(designer: Any) -> Optional[dict]:
+        counts = getattr(designer, "encoded_row_counts", None)
+        return dict(counts) if counts is not None else None
+
+    def _account_rows(self, before: Optional[dict], after: Optional[dict]) -> None:
+        if before is None or after is None:
+            return
+        stats = self._runtime.stats
+        stats.increment("rows_encoded", after["encoded"] - before["encoded"])
+        stats.increment("rows_reused", after["reused"] - before["reused"])
 
     def _account_surrogate(
         self, before: Optional[dict], after: Optional[dict]

@@ -43,6 +43,11 @@ class ServingStats:
         "trials_fetched",  # trial protos converted to pyvizier for an update
         "trials_reused",  # completed trials the cached designer already held
         "pending_trials_conditioned",  # ACTIVE trials handed to designer.update
+        # The designer's encoded-row store (converters.EncodedTrials): reused /
+        # (reused + encoded) is the share of a study a suggest did not
+        # re-encode; 0 when the store is rebuilt on every suggest.
+        "rows_encoded",  # trial rows a suggest encoded (new completed + ACTIVE)
+        "rows_reused",  # completed rows it took from the store as held
         # A study's suggest turns (vizier_tpu.serving.study_turns).
         "suggest_turns",  # SuggestTrials that got their study's turn
         "suggest_turns_contended",  # ... after waiting behind another request
