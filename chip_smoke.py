@@ -232,7 +232,7 @@ def objective(x: np.ndarray) -> np.ndarray:
 
 
 def completed_trials(rng: np.random.Generator, n: int):
-    """Seeded trials on the shifted quadratic ``bench.py`` uses."""
+    """Seeded trials on a shifted quadratic."""
     from vizier_tpu import pyvizier as vz
 
     x = rng.uniform(size=(n, DIM))

@@ -62,10 +62,10 @@ class TestRealTree:
         # traffic-engine knobs, the 11 VIZIER_ADMISSION*
         # overload-protection knobs, the 4 VIZIER_COMPUTE_TIER*
         # disaggregated-compute knobs, and the VIZIER_NETCHAOS fault
-        # schedule) + 2 bench switches + the 2 reserved grpc constants.
+        # schedule) + the 2 reserved grpc constants.
         # Growing the tree means growing this registry.
-        assert len(registry.SWITCHES) == 86
-        assert len(registry.env_switch_names()) == 84
+        assert len(registry.SWITCHES) == 84
+        assert len(registry.env_switch_names()) == 82
 
     def test_known_switches_declared(self):
         for name in (
@@ -73,7 +73,6 @@ class TestRealTree:
             "VIZIER_BATCHING",
             "VIZIER_RELIABILITY",
             "VIZIER_OBSERVABILITY",
-            "VIZIER_BENCH_SCALE",
             "VIZIER_SPARSE",
             "VIZIER_DISTRIBUTED_ROUTE_CACHE_SIZE",
         ):

@@ -63,10 +63,10 @@ class TestRealTree:
             r.fn.qualname for r in real_suite_result.jax_result.roots
         }
         # The GP-bandit train/acquisition programs and the cross-study
-        # batched entry points must all be discovered as jit roots.
+        # flush program must all be discovered as jit roots.
         assert any("_train_gp" in q for q in roots)
         assert any("_maximize_acquisition" in q for q in roots)
-        assert any("train_batched" in q for q in roots)
+        assert any("_gp_bandit_flush_program" in q for q in roots)
         assert len(roots) >= 15
 
     def test_statics_parsed_from_partial_decorators(self, real_suite_result):

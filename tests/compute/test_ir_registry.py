@@ -121,33 +121,24 @@ class TestResolution:
         d = gp_bandit_lib.VizierGPBandit(_problem(), rng_seed=0, **_FAST)
         assert compute_registry.resolve(d, 1) is None
 
-    def test_duck_typed_designer_gets_adapter(self):
-        class Duck:
+    def test_unregistered_designer_is_served_by_suggest(self):
+        # No registered program and no compute_program hook: the designer
+        # resolves to None and the executor serves it through its
+        # suggest().
+        from vizier_tpu.parallel.batch_executor import BatchExecutor
+
+        class Unregistered:
             def suggest(self, count=1):
                 return ["s"] * (count or 1)
 
-            def batch_bucket_key(self, count=1):
-                return compute_ir.BucketKey(
-                    kind="duck", pad_trials=8, cont_width=1, cat_width=0,
-                    metric_count=1, count=count or 1,
-                )
-
-            def batch_prepare(self, count=1):
-                return dict(designer=self, count=count)
-
-            def batch_execute(self, items, pad_to=None):
-                return [dict(v=1) for _ in items]
-
-            def batch_finalize(self, item, output):
-                return ["done"] * item["count"]
-
-        duck = Duck()
-        program, key = compute_registry.resolve(duck, 2)
-        assert isinstance(program, compute_registry.DuckTypedProgram)
-        assert key.kind == "duck"
-        item = program.prepare(duck, 2)
-        out = program.device_program([item])
-        assert program.finalize(duck, item, out[0]) == ["done", "done"]
+        designer = Unregistered()
+        assert compute_registry.resolve(designer, 2) is None
+        executor = BatchExecutor(max_batch_size=4, max_wait_ms=5000)
+        try:
+            assert executor.suggest(designer, 2) == ["s", "s"]
+            assert executor.pending_counts() == {}
+        finally:
+            executor.close()
 
     def test_plain_designer_resolves_none(self):
         class Plain:

@@ -17,6 +17,8 @@ from vizier_tpu.optimizers import lbfgs as lbfgs_lib
 from vizier_tpu.parallel.batch_executor import BatchExecutor
 from vizier_tpu.surrogates import SurrogateConfig
 
+from tests import program_driver
+
 _FAST = dict(
     ard_optimizer=lbfgs_lib.AdamOptimizer(maxiter=15),
     ard_restarts=3,
@@ -97,14 +99,10 @@ class TestPortedProgramBitIdentity:
         sequential = [factory(s).suggest(count) for s in batch_seeds]
 
         batched = [factory(s) for s in batch_seeds]
-        resolved = [compute_registry.resolve(d, count) for d in batched]
-        assert all(r is not None and r[1].kind == kind for r in resolved)
-        program = resolved[0][0]
-        items = [program.prepare(d, count) for d in batched]
-        outs = program.device_program(items, pad_to=max(4, len(items)))
-        results = [
-            program.finalize(d, i, o) for d, i, o in zip(batched, items, outs)
-        ]
+        assert program_driver.bucket_key(batched[0], count).kind == kind
+        results = program_driver.flush(
+            batched, count, pad_to=max(4, len(batched))
+        )
         for seq, res in zip(sequential, results):
             _assert_bit_identical(_params(seq), _params(res))
 
@@ -153,20 +151,15 @@ class TestExecutorSingletonIsSequential:
         self._run_kind("gp_ucb_pe_sparse")
 
 
-class TestLegacyDuckSurfaceMatchesPrograms:
-    """The thin designer-level ``batch_*`` methods delegate to the same
-    registered programs (subclass/test/chaos compatibility)."""
+class TestLoneSlotThroughThePaddedProgram:
+    """A lone prepare survivor still rides the fused program, padded with
+    copies of itself: bit-identical to its sequential run."""
 
-    def test_designer_methods_route_to_registry(self):
+    def test_padded_singleton_flush_matches_sequential(self):
         d = _FACTORIES["gp_bandit"](7)
-        key = d.batch_bucket_key(1)
-        program, resolved_key = compute_registry.resolve(
-            _FACTORIES["gp_bandit"](7), 1
-        )
-        assert key == resolved_key
-        item = d.batch_prepare(1)
-        assert item["sparse"] is False
-        outs = type(d).batch_execute([item], pad_to=2)
-        result = d.batch_finalize(item, outs[0])
+        program, key = compute_registry.resolve(d, 1)
+        assert program is compute_registry.get("gp_bandit")
+        assert key == program.bucket_key(_FACTORIES["gp_bandit"](7), 1)
+        (result,) = program_driver.flush([d], 1, pad_to=2)
         reference = _FACTORIES["gp_bandit"](7).suggest(1)
         _assert_bit_identical(_params(reference), _params(result))

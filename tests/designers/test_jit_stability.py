@@ -18,6 +18,8 @@ from vizier_tpu.designers import gp_bandit as gp_bandit_lib
 from vizier_tpu.designers import gp_ucb_pe as gp_ucb_pe_lib
 from vizier_tpu.optimizers import lbfgs as lbfgs_lib
 
+from tests import program_driver
+
 _FAST = dict(
     ard_optimizer=lbfgs_lib.AdamOptimizer(maxiter=10),
     ard_restarts=2,
@@ -206,14 +208,13 @@ class TestSparseJitStability:
 
         def flush(seeds, n):
             designers = [fresh(s, n) for s in seeds]
-            # Same calling convention as the executor: the bucket key
+            # Same calling convention as the executor: resolving the bucket
             # refreshes each designer's surrogate mode before prepare.
-            keys = [d.batch_bucket_key(1) for d in designers]
-            assert len(set(keys)) == 1 and keys[0].kind == "gp_bandit_sparse"
-            items = [d.batch_prepare(1) for d in designers]
-            outs = designers[0].batch_execute(items, pad_to=len(items))
-            for d, i, o in zip(designers, items, outs):
-                d.batch_finalize(i, o)
+            assert (
+                program_driver.bucket_key(designers[0], 1).kind
+                == "gp_bandit_sparse"
+            )
+            program_driver.flush(designers, 1, pad_to=len(designers))
 
         program = sparse_bandit._sparse_flush_program
         flush((40, 41), n=4)
@@ -307,12 +308,11 @@ class TestSparseUCBPEJitStability:
 
         def flush(seeds, n):
             designers = [fresh(s, n) for s in seeds]
-            keys = [d.batch_bucket_key(1) for d in designers]
-            assert len(set(keys)) == 1 and keys[0].kind == "gp_ucb_pe_sparse"
-            items = [d.batch_prepare(1) for d in designers]
-            outs = designers[0].batch_execute(items, pad_to=len(items))
-            for d, i, o in zip(designers, items, outs):
-                d.batch_finalize(i, o)
+            assert (
+                program_driver.bucket_key(designers[0], 1).kind
+                == "gp_ucb_pe_sparse"
+            )
+            program_driver.flush(designers, 1, pad_to=len(designers))
 
         program = gp_ucb_pe_lib._sparse_ucb_pe_flush_program
         flush((40, 41), n=3)
@@ -330,8 +330,6 @@ class TestIRRoutedProgramJitStability:
     bucket, +1 exactly at a bucket boundary."""
 
     def test_ir_routed_flushes_share_the_bucket_program(self):
-        from vizier_tpu.compute import registry as compute_registry
-
         def fresh(seed, n):
             d = gp_bandit_lib.VizierGPBandit(_problem(), rng_seed=seed, **_FAST)
             d.update(core_lib.CompletedTrials(_trials(1, n, seed=seed)))
@@ -342,14 +340,8 @@ class TestIRRoutedProgramJitStability:
         # static of the same shared flush body).
         def flush(seeds, n):
             designers = [fresh(s, n) for s in seeds]
-            resolved = [compute_registry.resolve(d, 2) for d in designers]
-            assert all(r is not None for r in resolved)
-            program = resolved[0][0]
-            assert program.kind == "gp_bandit"
-            items = [program.prepare(d, 2) for d in designers]
-            outs = program.device_program(items, pad_to=len(items))
-            for d, i, o in zip(designers, items, outs):
-                program.finalize(d, i, o)
+            assert program_driver.bucket_key(designers[0], 2).kind == "gp_bandit"
+            program_driver.flush(designers, 2, pad_to=len(designers))
 
         body = gp_bandit_lib._gp_bandit_flush_program
         flush((60, 61), n=4)
@@ -371,10 +363,7 @@ class TestBatchedProgramJitStability:
 
         def flush(seeds, n):
             designers = [fresh(s, n) for s in seeds]
-            items = [d.batch_prepare(1) for d in designers]
-            outs = designers[0].batch_execute(items, pad_to=len(items))
-            for d, i, o in zip(designers, items, outs):
-                d.batch_finalize(i, o)
+            program_driver.flush(designers, 1, pad_to=len(designers))
 
         program = gp_bandit_lib._gp_bandit_flush_program
         flush((0, 1), n=4)

@@ -183,14 +183,19 @@ class TestGracefulShutdown:
         """The PR 15 shutdown contract: SIGTERM → drain → flush standby →
         compact WAL → observability dump, all before exit."""
 
-        def pick():
+        def hold():
+            # A port that stays ours until the child listens on it: bound
+            # with SO_REUSEPORT (gRPC's own default on a listener), so the
+            # child binds beside this socket while the kernel hands the
+            # port to nobody else — a picked-then-closed port could go to
+            # another xdist worker's socket before the child's bind.
             s = socket.socket()
+            s.setsockopt(socket.SOL_SOCKET, socket.SO_REUSEPORT, 1)
             s.bind(("localhost", 0))
-            port = s.getsockname()[1]
-            s.close()
-            return port
+            return s
 
-        ports = [pick(), pick()]
+        held = [hold(), hold()]
+        ports = [s.getsockname()[1] for s in held]
         peers = ",".join(
             f"replica-{i}=localhost:{ports[i]}" for i in range(2)
         )
@@ -222,12 +227,14 @@ class TestGracefulShutdown:
                     env={**os.environ, "JAX_PLATFORMS": "cpu"},
                 )
             )
+        endpoints = []
         try:
-            endpoints = []
             for proc in procs:
                 line = proc.stdout.readline().strip()
                 assert line.startswith("READY "), line
                 endpoints.append(line.split(" ", 1)[1])
+            for s in held:
+                s.close()
             # One mutation on replica-0 so there is WAL + standby state
             # for the shutdown to make durable.
             study = study_pb2.Study(name="owners/sub/studies/gs")
@@ -262,6 +269,8 @@ class TestGracefulShutdown:
                 assert os.path.exists(metrics_path)
                 json.load(open(metrics_path))
         finally:
+            for s in held:
+                s.close()
             for proc in procs:
                 if proc.poll() is None:
                     proc.kill()

@@ -8,6 +8,7 @@ import pytest
 
 from vizier_tpu import pyvizier as vz
 from vizier_tpu.algorithms import core as core_lib
+from vizier_tpu.compute import ir as compute_ir
 from vizier_tpu.designers.gp_bandit import VizierGPBandit
 from vizier_tpu.designers.gp_ucb_pe import VizierGPUCBPEBandit
 from vizier_tpu.optimizers import lbfgs as lbfgs_lib
@@ -18,6 +19,8 @@ from vizier_tpu.parallel.batch_executor import (
 )
 from vizier_tpu.serving.stats import ServingStats
 from vizier_tpu.testing import chaos as chaos_lib
+
+from tests import program_driver
 
 _FAST = dict(
     ard_optimizer=lbfgs_lib.AdamOptimizer(maxiter=15),
@@ -87,8 +90,44 @@ def _stub_suggestion(value):
     return vz.TrialSuggestion(parameters={"x": float(value)})
 
 
+class StubProgram(compute_ir.DesignerProgram):
+    """The four hooks with trivial arithmetic (subclasses plant faults)."""
+
+    kind = "stub"
+    device_phase = "stub.flush"
+
+    def bucket_key(self, designer, count):
+        if not designer.batchable:
+            return None
+        return BucketKey(
+            kind=self.kind,
+            pad_trials=8,
+            cont_width=1,
+            cat_width=0,
+            metric_count=1,
+            count=count or 1,
+            statics=(designer.group,),
+        )
+
+    def prepare(self, designer, count):
+        return dict(designer=designer, count=count or 1, value=designer.value)
+
+    def device_program(self, items, pad_to=None, placement=None):
+        return [dict(value=item["value"]) for item in items]
+
+    def finalize(self, designer, item, output):
+        designer.batched = True
+        return [_stub_suggestion(output["value"])] * item["count"]
+
+    def prewarm_factory(self, problem, **kwargs):
+        raise NotImplementedError
+
+
 class StubDesigner:
-    """Implements the batch protocol with trivial arithmetic."""
+    """An unregistered designer: ``suggest`` and, behind the
+    ``compute_program`` hook, its class's stub program."""
+
+    program = StubProgram()
 
     def __init__(self, value, group="g", batchable=True):
         self.value = value
@@ -101,43 +140,36 @@ class StubDesigner:
         self.sequential_calls += 1
         return [_stub_suggestion(self.value)] * (count or 1)
 
-    def batch_bucket_key(self, count=1):
-        if not self.batchable:
-            return None
-        return BucketKey(
-            kind="stub",
-            pad_trials=8,
-            cont_width=1,
-            cat_width=0,
-            metric_count=1,
-            count=count or 1,
-            statics=(self.group,),
-        )
-
-    def batch_prepare(self, count=1):
-        return dict(designer=self, count=count or 1, value=self.value)
-
-    def batch_execute(self, items, pad_to=None):
-        return [dict(value=item["value"]) for item in items]
-
-    def batch_finalize(self, item, output):
-        self.batched = True
-        return [_stub_suggestion(output["value"])] * item["count"]
+    def compute_program(self, count=1):
+        key = self.program.bucket_key(self, count)
+        return None if key is None else (self.program, key)
 
 
-class FailPrepareStub(StubDesigner):
-    def batch_prepare(self, count=1):
+class FailPrepareProgram(StubProgram):
+    def prepare(self, designer, count):
         raise RuntimeError("prepare exploded")
 
 
-class FailExecuteStub(StubDesigner):
-    def batch_execute(self, items, pad_to=None):
+class FailPrepareStub(StubDesigner):
+    program = FailPrepareProgram()
+
+
+class FailExecuteProgram(StubProgram):
+    def device_program(self, items, pad_to=None, placement=None):
         raise RuntimeError("device program exploded")
 
 
-class NanStub(StubDesigner):
-    def batch_finalize(self, item, output):
+class FailExecuteStub(StubDesigner):
+    program = FailExecuteProgram()
+
+
+class NanProgram(StubProgram):
+    def finalize(self, designer, item, output):
         return [_stub_suggestion(float("nan"))]
+
+
+class NanStub(StubDesigner):
+    program = NanProgram()
 
 
 def _run_concurrent(executor, designers, count=1):
@@ -163,34 +195,35 @@ def _run_concurrent(executor, designers, count=1):
 class TestBucketKeys:
     def test_seeding_stage_unbatchable(self):
         d = _gp_bandit(0)  # no trials yet: quasi-random seeding path
-        assert d.batch_bucket_key(1) is None
+        assert program_driver.bucket_key(d, 1) is None
 
     def test_multiobjective_unbatchable(self):
         d = VizierGPBandit(_problem(num_metrics=2), rng_seed=0, **_FAST)
         _feed(d, 0, num_metrics=2)
-        assert d.batch_bucket_key(1) is None
+        assert program_driver.bucket_key(d, 1) is None
 
     def test_priors_unbatchable(self):
         d = _feed(_gp_bandit(0), 0)
         d.set_priors([])
-        assert d.batch_bucket_key(1) is not None  # empty priors list is falsy
+        # An empty priors list is falsy.
+        assert program_driver.bucket_key(d, 1) is not None
         d.set_priors([[t for t in d._trials]])
-        assert d.batch_bucket_key(1) is None
+        assert program_driver.bucket_key(d, 1) is None
 
     def test_same_config_same_bucket(self):
         a, b = _feed(_gp_bandit(1), 1), _feed(_gp_bandit(2), 2)
-        assert a.batch_bucket_key(1) == b.batch_bucket_key(1)
+        assert program_driver.bucket_key(a, 1) == program_driver.bucket_key(b, 1)
 
     def test_different_shape_different_bucket(self):
         a = _feed(_gp_bandit(1), 1, n=5)  # pad bucket 8
         b = _feed(_gp_bandit(2), 2, n=9)  # pad bucket 16
-        assert a.batch_bucket_key(1) != b.batch_bucket_key(1)
+        assert program_driver.bucket_key(a, 1) != program_driver.bucket_key(b, 1)
 
     def test_ucb_pe_cached_fit_unbatchable(self):
         d = _feed(_gp_ucb_pe(3), 3, n=4)
-        assert d.batch_bucket_key(1) is not None
+        assert program_driver.bucket_key(d, 1) is not None
         d.suggest(1)  # populates the cached fit
-        assert d.batch_bucket_key(1) is None
+        assert program_driver.bucket_key(d, 1) is None
 
 
 class TestExecutorMechanics:
@@ -319,19 +352,11 @@ class TestBatchedVsSequentialParity:
 
         # Padded partial batch (2 real slots padded to 4) ...
         padded = [_feed(_gp_bandit(s), s) for s in seeds]
-        items = [d.batch_prepare(1) for d in padded]
-        outs = padded[0].batch_execute(items, pad_to=4)
-        padded_out = [
-            d.batch_finalize(i, o) for d, i, o in zip(padded, items, outs)
-        ]
+        padded_out = program_driver.flush(padded, 1, pad_to=4)
         # ... and the unpadded batch must both match the sequential run:
         # masked filler slots never leak into real slots' posteriors.
         plain = [_feed(_gp_bandit(s), s) for s in seeds]
-        items2 = [d.batch_prepare(1) for d in plain]
-        outs2 = plain[0].batch_execute(items2, pad_to=None)
-        plain_out = [
-            d.batch_finalize(i, o) for d, i, o in zip(plain, items2, outs2)
-        ]
+        plain_out = program_driver.flush(plain, 1, pad_to=None)
         for i in range(len(seeds)):
             _assert_params_equal(_params(sequential[i]), _params(padded_out[i]))
             _assert_params_equal(_params(padded_out[i]), _params(plain_out[i]))
@@ -342,13 +367,7 @@ class TestBatchedVsSequentialParity:
         seeds = (21, 22)
         sequential = [_feed(_gp_ucb_pe(s), s, n=4).suggest(1) for s in seeds]
         batched = [_feed(_gp_ucb_pe(s), s, n=4) for s in seeds]
-        keys = [d.batch_bucket_key(1) for d in batched]
-        assert keys[0] == keys[1]
-        items = [d.batch_prepare(1) for d in batched]
-        outs = batched[0].batch_execute(items, pad_to=4)
-        batched_out = [
-            d.batch_finalize(i, o) for d, i, o in zip(batched, items, outs)
-        ]
+        batched_out = program_driver.flush(batched, 1, pad_to=4)
         for i in range(len(seeds)):
             _assert_params_equal(_params(sequential[i]), _params(batched_out[i]))
         # predict() after a batched suggest reuses the cached fit.
@@ -361,11 +380,7 @@ class TestBatchedVsSequentialParity:
         seeds = (31, 32)
         sequential = [_feed(_gp_ucb_pe(s), s, n=4).suggest(2) for s in seeds]
         batched = [_feed(_gp_ucb_pe(s), s, n=4) for s in seeds]
-        items = [d.batch_prepare(2) for d in batched]
-        outs = batched[0].batch_execute(items, pad_to=None)
-        batched_out = [
-            d.batch_finalize(i, o) for d, i, o in zip(batched, items, outs)
-        ]
+        batched_out = program_driver.flush(batched, 2, pad_to=None)
         for i in range(len(seeds)):
             assert len(batched_out[i]) == 2
             _assert_params_equal(_params(sequential[i]), _params(batched_out[i]))
@@ -396,8 +411,8 @@ class TestChaosIsolation:
         ex = BatchExecutor(max_batch_size=3, max_wait_ms=10_000, stats=stats)
         try:
             results, errors = _run_concurrent(ex, [chaotic] + healthy)
-            # The chaos slot fails at batch_prepare and is dropped from the
-            # batch; its error reaches only its own study's waiter.
+            # The chaos slot fails at its prepare strike and is dropped from
+            # the batch; its error reaches only its own study's waiter.
             assert isinstance(errors[0], chaos_lib.failing.FailedSuggestError)
             assert errors[1] is None and errors[2] is None
             for i, seq in enumerate(sequential):
@@ -410,14 +425,14 @@ class TestChaosIsolation:
             ex.close()
 
     def test_chaos_execute_poisons_batch_but_sequential_fallback_recovers(self):
-        # One strike in batch_execute kills the shared device program; every
+        # One strike in device_program kills the shared device program; every
         # slot recovers through its own sequential run (chaos designer's
         # plain suggest also strikes -> ITS slot errors, batchmate succeeds).
         monkey = chaos_lib.ChaosMonkey(seed=0, failure_prob=1.0)
         chaotic = chaos_lib.ChaosDesigner(_feed(_gp_bandit(61), 61), monkey)
         healthy = _feed(_gp_bandit(62), 62)
         # Force the chaos slot to pass prepare: only strike execute/suggest.
-        chaotic.batch_prepare = chaotic._inner.batch_prepare
+        program_driver.pass_prepare(chaotic)
         stats = ServingStats()
         ex = BatchExecutor(max_batch_size=2, max_wait_ms=10_000, stats=stats)
         try:
@@ -433,8 +448,8 @@ class TestChaosIsolation:
                     errors[i] = e
 
             # The chaos designer must arrive FIRST so the flush dispatches
-            # through ITS batch_execute (the executor uses the first live
-            # slot's program entry point).
+            # through ITS ChaosProgram (the executor runs the first live
+            # slot's device_program).
             t0 = threading.Thread(target=run, args=(0, chaotic))
             t0.start()
             for _ in range(400):

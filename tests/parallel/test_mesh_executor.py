@@ -30,8 +30,10 @@ from vizier_tpu.parallel.mesh import DevicePlacement, MeshConfig, build_placemen
 from vizier_tpu.serving.stats import ServingStats
 from vizier_tpu.testing import chaos as chaos_lib
 
+from tests import program_driver
 from tests.parallel.test_batch_executor import (  # noqa: F401  (shared idioms)
     StubDesigner,
+    StubProgram,
     _run_concurrent,
 )
 
@@ -192,10 +194,13 @@ class TestMeshScheduling:
         try:
             seen_threads = set()
 
-            class Recorder(StubDesigner):
-                def batch_execute(self, items, pad_to=None):
+            class RecordingProgram(StubProgram):
+                def device_program(self, items, pad_to=None, placement=None):
                     seen_threads.add(threading.current_thread().name)
-                    return super().batch_execute(items, pad_to=pad_to)
+                    return super().device_program(items, pad_to=pad_to)
+
+            class Recorder(StubDesigner):
+                program = RecordingProgram()
 
             results, errors = _run_concurrent(
                 ex, [Recorder(i) for i in range(4)]
@@ -305,7 +310,7 @@ class TestMeshChaosIsolation:
         # Bucket B, on ANOTHER placement, is untouched and stays batched.
         monkey = chaos_lib.ChaosMonkey(seed=0, failure_prob=1.0)
         chaotic = chaos_lib.ChaosDesigner(_designer(51), monkey)
-        chaotic.batch_prepare = chaotic._inner.batch_prepare  # reach execute
+        program_driver.pass_prepare(chaotic)  # reach device_program
         mate = _designer(52)
         other_bucket = [
             _designer(s, max_acquisition_evaluations=208) for s in (53, 54)

@@ -15,6 +15,8 @@ from vizier_tpu.serving.stats import ServingStats
 from vizier_tpu.surrogates import SurrogateConfig
 from vizier_tpu.testing import chaos as chaos_lib
 
+from tests import program_driver
+
 _FAST = dict(
     ard_optimizer=lbfgs_lib.AdamOptimizer(maxiter=15),
     ard_restarts=3,
@@ -95,16 +97,16 @@ def _run_concurrent(executor, designers, count=1):
 
 class TestBucketSeparation:
     def test_sparse_and_exact_studies_land_in_different_buckets(self):
-        sparse_key = _feed(_sparse_designer(1), 1).batch_bucket_key(1)
-        exact_key = _feed(_exact_designer(2), 2).batch_bucket_key(1)
+        sparse_key = program_driver.bucket_key(_feed(_sparse_designer(1), 1), 1)
+        exact_key = program_driver.bucket_key(_feed(_exact_designer(2), 2), 1)
         assert sparse_key is not None and exact_key is not None
         assert sparse_key.kind == "gp_bandit_sparse"
         assert exact_key.kind == "gp_bandit"
         assert sparse_key != exact_key
 
     def test_same_sparse_config_same_bucket(self):
-        a = _feed(_sparse_designer(3), 3).batch_bucket_key(1)
-        b = _feed(_sparse_designer(4), 4).batch_bucket_key(1)
+        a = program_driver.bucket_key(_feed(_sparse_designer(3), 3), 1)
+        b = program_driver.bucket_key(_feed(_sparse_designer(4), 4), 1)
         assert a == b
 
     def test_different_inducing_bucket_different_key(self):
@@ -114,8 +116,8 @@ class TestBucketSeparation:
             sparse_threshold_trials=4, hysteresis_trials=0, num_inducing=12
         )
         d_big = VizierGPBandit(_problem(), rng_seed=5, surrogate=big_m, **_FAST)
-        a = _feed(_sparse_designer(5), 5).batch_bucket_key(1)
-        b = _feed(d_big, 6).batch_bucket_key(1)
+        a = program_driver.bucket_key(_feed(_sparse_designer(5), 5), 1)
+        b = program_driver.bucket_key(_feed(d_big, 6), 1)
         assert a != b
 
     def test_below_threshold_uses_exact_bucket(self):
@@ -123,7 +125,7 @@ class TestBucketSeparation:
             sparse_threshold_trials=100, hysteresis_trials=0, num_inducing=6
         )
         d = VizierGPBandit(_problem(), rng_seed=7, surrogate=cfg, **_FAST)
-        key = _feed(d, 7).batch_bucket_key(1)
+        key = program_driver.bucket_key(_feed(d, 7), 1)
         assert key.kind == "gp_bandit"
 
 
@@ -133,14 +135,8 @@ class TestSparseBatchedParity:
         sequential = [_feed(_sparse_designer(s), s).suggest(1) for s in seeds]
 
         batched = [_feed(_sparse_designer(s), s) for s in seeds]
-        keys = [d.batch_bucket_key(1) for d in batched]
-        assert keys[0] == keys[1]
-        items = [d.batch_prepare(1) for d in batched]
-        assert all(item["sparse"] for item in items)
-        outs = batched[0].batch_execute(items, pad_to=4)
-        batched_out = [
-            d.batch_finalize(i, o) for d, i, o in zip(batched, items, outs)
-        ]
+        assert program_driver.bucket_key(batched[0], 1).kind == "gp_bandit_sparse"
+        batched_out = program_driver.flush(batched, 1, pad_to=4)
         for i in range(len(seeds)):
             _assert_params_equal(_params(sequential[i]), _params(batched_out[i]))
         # Batched sparse suggests update the designer's sparse bookkeeping.

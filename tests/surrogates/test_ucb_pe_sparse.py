@@ -25,6 +25,8 @@ from vizier_tpu.surrogates import sparse_bandit
 from vizier_tpu.surrogates import sparse_gp
 from vizier_tpu.testing import chaos as chaos_lib
 
+from tests import program_driver
+
 _FAST = dict(
     ard_optimizer=lbfgs_lib.AdamOptimizer(maxiter=15),
     ard_restarts=3,
@@ -306,10 +308,10 @@ class TestSparseFitSurface:
         assert ns.get("acquisition") is not None
 
     def test_exact_and_sparse_never_share_a_bucket(self):
-        sparse_key = _sparse_designer(80).batch_bucket_key(1)
-        exact_key = _feed(
-            VizierGPUCBPEBandit(_problem(), rng_seed=81, **_FAST), 81
-        ).batch_bucket_key(1)
+        sparse_key = program_driver.bucket_key(_sparse_designer(80), 1)
+        exact_key = program_driver.bucket_key(
+            _feed(VizierGPUCBPEBandit(_problem(), rng_seed=81, **_FAST), 81), 1
+        )
         assert sparse_key.kind == "gp_ucb_pe_sparse"
         assert exact_key.kind == "gp_ucb_pe"
         assert sparse_key != exact_key

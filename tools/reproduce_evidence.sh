@@ -13,10 +13,6 @@ python tools/check_analysis.py
 echo "== 1. full test suite (CPU; ~6 min with six xdist workers) =="
 python -m pytest tests/ -q -p xdist -n 6 --dist loadfile
 
-echo "== 2. full-scale CPU run of bench.py's control flow (~30 min; not a timing) =="
-JAX_PLATFORMS=cpu VIZIER_BENCH_SCALE=1.0 VIZIER_BENCH_WATCHDOG_S=14400 \
-  python bench.py
-
 echo "== 3. service throughput head-to-head + sharded-tier A/B (~8 min) =="
 #    -> SERVICE_THROUGHPUT.json (builds /tmp/refvizier on first run);
 #    --replicas adds the "distributed" section: 4 routed replicas vs one

@@ -79,7 +79,7 @@ from vizier_tpu.loadgen import report as report_lib  # noqa: E402
 
 
 def _stamps() -> dict:
-    """Provenance stamps (same families bench.py records)."""
+    """Provenance stamps: which serving planes and surrogates ran."""
     import jax
 
     from vizier_tpu.compute import registry as compute_registry

@@ -319,11 +319,6 @@ SWITCHES: Tuple[EnvSwitch, ...] = (
     # -- designers ---------------------------------------------------------
     _switch("VIZIER_DISABLE_MESH", "flag", "GPBanditDesigner", _SWITCH_DOC,
             "Opt out of the multi-device auto-mesh (set = disabled).", "0"),
-    # -- bench.py (repo-root benchmark harness) ----------------------------
-    _switch("VIZIER_BENCH_SCALE", "float", "bench.py", _PERF_DOC,
-            "Global workload scale factor for bench.py.", "1.0"),
-    _switch("VIZIER_BENCH_WATCHDOG_S", "float", "bench.py", _PERF_DOC,
-            "bench.py watchdog timeout in seconds."),
     # -- reserved constants (NOT environment variables) --------------------
     _switch("VIZIER_METHODS", "constant", "service.grpc_stubs",
             "docs/guides/running_the_service.md",

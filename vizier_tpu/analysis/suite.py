@@ -3,7 +3,7 @@
 Configuration lives in ``pyproject.toml``::
 
     [tool.vizier_analysis]
-    paths = ["vizier_tpu", "bench.py", "tools"]
+    paths = ["vizier_tpu", "tools"]
     baseline = "vizier_tpu/analysis/baseline.toml"
     passes = ["lock_order", "jax_discipline", "env_registry"]
     critical_locks = [...]   # optional override
@@ -34,7 +34,7 @@ ALL_PASSES = (
     "debug_locks",
 )
 
-DEFAULT_PATHS = ("vizier_tpu", "bench.py", "tools")
+DEFAULT_PATHS = ("vizier_tpu", "tools")
 DEFAULT_BASELINE = "vizier_tpu/analysis/baseline.toml"
 
 

@@ -13,13 +13,10 @@ Every batchable designer computation in the tree has the same anatomy:
   transitions (warm ARD seed, cached posterior, counters) and decodes
   suggestions.
 
-Before this module those four stages were duck-typed methods copied onto
-every designer (``batch_bucket_key`` / ``batch_prepare`` /
-``batch_execute`` / ``batch_finalize``), and each cross-cutting feature —
-the batch executor, the compile-prewarm walker, chaos slot isolation,
-``device.wait`` stage-span tracing, the speculative lane — had to
-be wired per copy. :class:`DesignerProgram` names the contract once;
-programs register in :mod:`vizier_tpu.compute.registry` and every feature
+Those four stages are the hooks of :class:`DesignerProgram`; programs
+register in :mod:`vizier_tpu.compute.registry`, and every cross-cutting
+feature — the batch executor, the compile-prewarm walker, chaos slot
+isolation, ``device.wait`` stage-span tracing, the speculative lane —
 consumes the registry generically. A designer that implements one program
 gets batching, prewarm, fail isolation, tracing, and speculation for free
 (docs/guides/performance.md "Batched compute IR" is the author guide).

@@ -3,36 +3,31 @@
 One process-wide table, two indexes:
 
 - **by kind** — ``get("gp_ucb_pe")`` → the :class:`~vizier_tpu.compute.ir.
-  DesignerProgram` whose device body executes that bucket family. The
-  batch executor looks a flush's program up here instead of calling a
-  per-designer method; tools (obs_report, bench stamps) enumerate
-  :func:`kinds` instead of maintaining hardcoded lists.
+  DesignerProgram` whose device body executes that bucket family; tools
+  (obs_report, run stamps) enumerate :func:`kinds` instead of maintaining
+  hardcoded lists.
 - **by designer type** — :func:`resolve` walks ``type(designer).__mro__``
   to the most-derived class with registered programs and returns the
   first program whose ``bucket_key`` accepts the designer's current state
   (e.g. the exact GP-bandit program declines a study the surrogate
   auto-switch has flipped sparse, and the sparse program picks it up).
 
-Wrappers and custom designers compose without registering:
+A wrapper composes without registering: a designer exposing
+``compute_program(count) -> (program, key) | None`` overrides resolution
+entirely — the chaos harness uses this to wrap the resolved program in
+fault-injecting hooks. A designer with neither a hook nor a registered
+program resolves to ``None`` and is served by its sequential ``suggest``.
 
-- a designer exposing ``compute_program(count) -> (program, key) | None``
-  overrides resolution entirely — the chaos harness uses this to wrap the
-  resolved program in fault-injecting hooks (slot isolation rides the IR,
-  not per-designer method copies);
-- a designer with only the legacy duck-typed ``batch_*`` methods resolves
-  to a :class:`DuckTypedProgram` adapter, so out-of-tree designers keep
-  batching without a registry entry (they forgo prewarm/conformance).
-
-Registration happens at designer-module import: importing
-``vizier_tpu.compute.programs`` (or any designer module) populates the
-table. The analysis suite's ``compute_ir`` pass statically audits every
+Registration happens at designer-module import; :func:`kinds` and
+:func:`programs` import the in-tree designer modules themselves. The
+analysis suite's ``compute_ir`` pass statically audits every
 ``register(...)`` site for prewarm coverage and chaos-test coverage.
 """
 
 from __future__ import annotations
 
 import threading
-from typing import Any, Dict, List, Optional, Tuple, Type
+from typing import Any, Dict, List, Optional, Tuple
 
 from vizier_tpu.compute import ir
 
@@ -80,46 +75,6 @@ def programs_for_algorithm(algorithm: str) -> Tuple[ir.DesignerProgram, ...]:
     return tuple(p for p in programs() if p.matches_algorithm(algorithm))
 
 
-class DuckTypedProgram(ir.DesignerProgram):
-    """Adapter over the legacy duck-typed ``batch_*`` designer methods.
-
-    Unregistered designers (test stubs, out-of-tree extensions) keep
-    batching through the executor; the adapter is per-resolution so the
-    bound designer's own hooks run — including any fault-injection those
-    hooks carry.
-    """
-
-    surrogate_family = "exact"
-
-    def __init__(self, kind: str, designer: Any):
-        self.kind = kind
-        self.device_phase = f"{kind}.suggest_batched"
-        # The device body dispatches through the RESOLVED designer (not the
-        # inner designer an item may record): a wrapper's batch_execute —
-        # e.g. a chaos strike — must stay on the dispatch path, exactly as
-        # the pre-IR executor's ``live[0].designer.batch_execute`` did.
-        self._designer = designer
-
-    def bucket_key(self, designer, count):
-        key_fn = getattr(designer, "batch_bucket_key", None)
-        return key_fn(count) if key_fn is not None else None
-
-    def prepare(self, designer, count):
-        return designer.batch_prepare(count)
-
-    def device_program(self, items, pad_to=None):
-        return self._designer.batch_execute(items, pad_to=pad_to)
-
-    def finalize(self, designer, item, output):
-        return designer.batch_finalize(item, output)
-
-    def prewarm_factory(self, problem, **kwargs):
-        raise NotImplementedError(
-            "Duck-typed designers are not prewarmable; register a "
-            "DesignerProgram to join the prewarm walk."
-        )
-
-
 def _ensure_builtin_programs() -> None:
     """Imports the in-tree designer modules so their programs are present.
 
@@ -139,31 +94,22 @@ def resolve(
 
     Order: the designer's own ``compute_program`` hook (wrappers), then
     the most-derived registered designer type's programs in registration
-    order (first non-None ``bucket_key`` wins), then the duck-typed
-    ``batch_*`` fallback. None means unbatchable — the caller runs the
-    plain sequential ``suggest``.
+    order (first non-None ``bucket_key`` wins). None means unbatchable —
+    the caller runs the plain sequential ``suggest``.
     """
     count = count or 1
     hook = getattr(designer, "compute_program", None)
     if hook is not None:
         return hook(count)
     with _LOCK:
-        type_programs = None
+        type_programs: List[ir.DesignerProgram] = []
         for cls in type(designer).__mro__:
             found = _BY_TYPE.get(cls)
             if found:
                 type_programs = list(found)
                 break
-    if type_programs is not None:
-        for program in type_programs:
-            key = program.bucket_key(designer, count)
-            if key is not None:
-                return program, key
-        return None
-    key_fn = getattr(designer, "batch_bucket_key", None)
-    if key_fn is None:
-        return None
-    key = key_fn(count)
-    if key is None:
-        return None
-    return DuckTypedProgram(key.kind, designer), key
+    for program in type_programs:
+        key = program.bucket_key(designer, count)
+        if key is not None:
+            return program, key
+    return None

@@ -18,6 +18,7 @@ every model-side op is mask-safe.
 
 from __future__ import annotations
 
+import abc
 import dataclasses
 import functools
 from typing import Any, List, Optional, Sequence
@@ -140,35 +141,9 @@ _prior_features_jit = jax.jit(_prior_features_from_data)
 # sequential path runs, so slot i of a batch matches study i run alone.
 
 
-@functools.partial(
-    jax.jit, static_argnames=("model", "optimizer", "num_restarts", "ensemble_size")
-)
-def train_batched(
-    model: gp_lib.VizierGaussianProcess,
-    optimizer: lbfgs_lib.LbfgsOptimizer,
-    data: gp_lib.GPData,  # leading study axis [B, ...]
-    rng: Array,  # [B] per-study keys
-    num_restarts: int,
-    ensemble_size: int,
-    warm_start: Optional[gp_lib.Params] = None,  # leading axis [B]
-) -> gp_lib.GPState:
-    """Multi-study ARD: one device program vmapping :func:`_train_gp`."""
-    if warm_start is None:
-        return jax.vmap(
-            lambda d, k: _train_gp(
-                model, optimizer, d, k, num_restarts, ensemble_size
-            )
-        )(data, rng)
-    return jax.vmap(
-        lambda d, k, w: _train_gp(
-            model, optimizer, d, k, num_restarts, ensemble_size, w
-        )
-    )(data, rng, warm_start)
-
-
 def _sweep_one(vec_opt, acquisition, s, d, k, count, use_trust_region):
-    """Per-study scoring + eagle sweep (trace-shared by the batched entry
-    points below; identical math to the sequential suggest)."""
+    """Per-study scoring + eagle sweep, vmapped by the flush program below
+    (identical math to the sequential suggest)."""
     best_label = jnp.max(jnp.where(d.row_mask, d.labels, -jnp.inf))
     trust = acquisitions.TrustRegion.from_data(d) if use_trust_region else None
     scoring = acquisitions.ScoringFunction(
@@ -180,37 +155,6 @@ def _sweep_one(vec_opt, acquisition, s, d, k, count, use_trust_region):
     return _maximize_acquisition(
         vec_opt, scoring, k, count, _prior_features_from_data(d)
     )
-
-
-@functools.partial(
-    jax.jit, static_argnames=("vec_opt", "acquisition", "count", "use_trust_region")
-)
-def suggest_batched(
-    vec_opt: vectorized_lib.VectorizedOptimizer,
-    acquisition,  # hashable Acquisition instance (UCB/EI/...), jit-static
-    states: gp_lib.GPState,  # leading study axis [B, E, ...]
-    data: gp_lib.GPData,  # leading study axis [B, ...]
-    rng: Array,  # [B] per-study keys
-    count: int,
-    use_trust_region: bool = True,
-) -> vectorized_lib.VectorizedOptimizerResult:
-    """Multi-study acquisition sweep: one device program, one eagle pool
-    per study slot, vmapping the sequential scoring + sweep."""
-    return jax.vmap(
-        lambda s, d, k: _sweep_one(
-            vec_opt, acquisition, s, d, k, count, use_trust_region
-        )
-    )(states, data, rng)
-
-
-@jax.jit
-def _to_gp_data_batched(md: types.ModelData) -> gp_lib.GPData:
-    """Stacked host ModelData → batched device GPData, inside ONE program.
-
-    The eager per-study ``GPData.from_model_data`` costs ~6 dispatches per
-    study; done here the whole batch pays one transfer + one fused program.
-    """
-    return jax.vmap(lambda m: gp_lib.GPData.from_model_data(m))(md)
 
 
 def _warm_next_batched(model: gp_lib.VizierGaussianProcess, states) -> gp_lib.Params:
@@ -673,13 +617,7 @@ class VizierGPBandit(core_lib.Designer, core_lib.Predictor):
                 result, count, kind=f"{self.acquisition}+sparse"
             )
 
-    # -- cross-study batch protocol (vizier_tpu.compute IR) -----------------
-    #
-    # The real implementations live in the registered DesignerProgram
-    # classes at the bottom of this module (GPBanditProgram /
-    # GPBanditSparseProgram); these thin methods keep the legacy duck-typed
-    # surface working for callers that talk to the designer directly
-    # (tests, chaos wrappers, subclass overrides).
+    # -- read by the registered programs at the bottom of this module ------
 
     def _batch_restarts(self) -> int:
         """The jit-static restart budget the next train would use (mirrors
@@ -687,56 +625,6 @@ class VizierGPBandit(core_lib.Designer, core_lib.Predictor):
         return max(
             self._warm_restart_budget() or self.ard_restarts, self.ensemble_size
         )
-
-    def _active_batch_program(self):
-        """The compute-IR program the current surrogate mode routes to."""
-        from vizier_tpu.compute import registry as compute_registry
-
-        kind = (
-            "gp_bandit_sparse"
-            if self._surrogate_mode == surrogate_config_lib.MODE_SPARSE
-            else "gp_bandit"
-        )
-        return compute_registry.get(kind)
-
-    def batch_bucket_key(self, count: Optional[int] = None):
-        """Shape-bucket identity for cross-study batching, or None.
-
-        None marks the paths the batched programs do not cover (seeding,
-        multi-objective, transfer priors, joint qEI, mesh-sharded): those
-        run the ordinary sequential suggest.
-        """
-        from vizier_tpu.compute import registry as compute_registry
-
-        resolved = compute_registry.resolve(self, count)
-        return resolved[1] if resolved is not None else None
-
-    def batch_prepare(self, count: Optional[int] = None) -> dict:
-        """Host-side half of a batched suggest (see the program classes)."""
-        return self._active_batch_program().prepare(self, count or 1)
-
-    @classmethod
-    def batch_execute(
-        cls,
-        items: Sequence[dict],
-        pad_to: Optional[int] = None,
-        placement: Optional[Any] = None,
-    ):
-        """Device half: dispatched to the bucket's registered program
-        (slot 0's item says which — the bucket key guarantees agreement)."""
-        from vizier_tpu.compute import registry as compute_registry
-
-        kind = "gp_bandit_sparse" if items[0].get("sparse") else "gp_bandit"
-        return compute_registry.get(kind).device_program(
-            items, pad_to=pad_to, placement=placement
-        )
-
-    def batch_finalize(self, item: dict, output: dict) -> List[trial_.TrialSuggestion]:
-        """Host-side demux (see the program classes)."""
-        from vizier_tpu.compute import registry as compute_registry
-
-        kind = "gp_bandit_sparse" if output.get("sparse") else "gp_bandit"
-        return compute_registry.get(kind).finalize(self, item, output)
 
     def _maximize(
         self,
@@ -1254,10 +1142,10 @@ def _maximize_q_batch(
 # The batched designer-compute contract for the GP-bandit family: one
 # program per compiled-flush family (exact | sparse), registered so the
 # batch executor, prewarm walker, chaos wrappers, device-phase tracing and
-# the speculative lane consume them generically. The hook bodies ARE the
-# pre-IR ``batch_*`` designer methods, moved verbatim — slot i of a batch
-# stays bit-identical to study i run alone, and the thin designer methods
-# above delegate here for legacy callers.
+# the speculative lane consume them generically. The two families share one
+# body (``_GPBanditFlush``); ``prepare``/``finalize`` run the state
+# transitions of the sequential ``suggest``, so slot i of a batch stays
+# bit-identical to study i run alone.
 
 
 def _gp_bandit_unbatchable(designer: "VizierGPBandit", count: int) -> bool:
@@ -1273,30 +1161,7 @@ def _gp_bandit_unbatchable(designer: "VizierGPBandit", count: int) -> bool:
     )
 
 
-def _gp_bandit_prepare(designer: "VizierGPBandit", count: int, sparse: bool) -> dict:
-    """Host-side half of a batched suggest: encode + warp + RNG draws.
-
-    Consumes the designer's RNG stream in exactly the order the sequential
-    ``suggest`` would (train key, then acquisition key), so batched and
-    sequential runs of the same study are key-for-key identical. Host-only:
-    the ModelData leaves stay numpy; the GPData conversion happens inside
-    the batched program, so prepare issues zero device dispatches.
-    """
-    return dict(
-        designer=designer,
-        count=count,
-        md=designer._warped_model_data(),
-        rng_train=designer._next_rng(),
-        rng_acq=designer._next_rng(),
-        warm=designer._warm_params,
-        restarts=designer._batch_restarts(),
-        # The bucket key (computed just before prepare) already refreshed
-        # the auto-switch; equal keys guarantee a whole bucket agrees.
-        sparse=sparse,
-    )
-
-
-def _gp_bandit_demux(items, pad_to, states, warm_next, result, sparse: bool):
+def _gp_bandit_demux(items, states, warm_next, result):
     """ONE device->host fetch for the whole batch; per-slot demux is then
     free numpy views (per-slot device slices would be ~20 dispatches per
     slot and dominated the executor's wall time)."""
@@ -1313,30 +1178,48 @@ def _gp_bandit_demux(items, pad_to, states, warm_next, result, sparse: bool):
                 states=batch_executor.slice_pytree(states, i),
                 warm_next=batch_executor.slice_pytree(warm_next, i),
                 result=batch_executor.slice_pytree(result, i),
-                sparse=sparse,
             )
             for i in range(len(items))
         ]
 
 
-class GPBanditProgram(compute_ir.DesignerProgram):
-    """Exact-GP single-objective flush: encode→multi-restart ARD→UCB/EI
-    sweep, one fused vmapped dispatch per bucket."""
+class _GPBanditFlush(compute_ir.DesignerProgram):
+    """The flush both GP-bandit families run: encode→multi-restart ARD→
+    UCB/EI sweep, one fused vmapped dispatch per bucket.
 
-    kind = "gp_bandit"
-    device_phase = "gp_bandit.suggest_batched"
-    surrogate_family = "exact"
-    shardable_batch_axis = "study"
-    algorithms = ("GAUSSIAN_PROCESS_BANDIT",)
+    A subclass states its registry literals and the three things that
+    differ: the surrogate mode it owns, its model and flush program, and
+    what ``finalize`` keeps of the fit."""
+
+    #: The ``surrogate_config_lib.MODE_*`` whose studies this program owns.
+    surrogate_mode = ""
+    #: Appended to the acquisition name in the suggestions' metadata.
+    decode_suffix = ""
+
+    @abc.abstractmethod
+    def _model(self, designer: "VizierGPBandit"):
+        """The model the flush trains: first bucket static, first flush
+        argument."""
+
+    @abc.abstractmethod
+    def _flush(self, *args):
+        """The jitted flush program of this family, looked up in its
+        module when called (``tests/compute/test_tpu_compile.py`` swaps
+        it there)."""
+
+    @abc.abstractmethod
+    def _keep_fit(self, designer: "VizierGPBandit", states) -> None:
+        """The sequential suggest's bookkeeping of a trained fit."""
 
     def bucket_key(self, designer, count):
         if _gp_bandit_unbatchable(designer, count):
             return None
-        if (
-            designer._refresh_surrogate_mode()
-            == surrogate_config_lib.MODE_SPARSE
-        ):
-            return None  # the sparse program owns this study
+        if designer._refresh_surrogate_mode() != self.surrogate_mode:
+            return None  # the other family's program owns this study
+        # The model rides in the statics (the sparse one with its padded
+        # inducing-slot count, the m-bucket), so equal keys ⇒ one compiled
+        # flush program per bucket — per (n-bucket, m-bucket) pair when
+        # sparse.
         return compute_ir.BucketKey(
             kind=self.kind,
             pad_trials=designer._converter.padding.pad_trials(
@@ -1347,7 +1230,7 @@ class GPBanditProgram(compute_ir.DesignerProgram):
             metric_count=1,
             count=count,
             statics=(
-                designer._model,
+                self._model(designer),
                 designer._ard,
                 designer._vec_opt,
                 designer._batch_restarts(),
@@ -1358,7 +1241,24 @@ class GPBanditProgram(compute_ir.DesignerProgram):
         )
 
     def prepare(self, designer, count):
-        return _gp_bandit_prepare(designer, count, sparse=False)
+        """Host-side half of a batched suggest: encode + warp + RNG draws.
+
+        Consumes the designer's RNG stream in exactly the order the
+        sequential ``suggest`` would (train key, then acquisition key), so
+        batched and sequential runs of the same study are key-for-key
+        identical. Host-only: the ModelData leaves stay numpy; the GPData
+        conversion happens inside the batched program, so prepare issues
+        zero device dispatches.
+        """
+        return dict(
+            designer=designer,
+            count=count,
+            md=designer._warped_model_data(),
+            rng_train=designer._next_rng(),
+            rng_acq=designer._next_rng(),
+            warm=designer._warm_params,
+            restarts=designer._batch_restarts(),
+        )
 
     def device_program(self, items, pad_to=None, placement=None):
         """ONE vmapped train + ONE vmapped sweep for the whole bucket
@@ -1375,37 +1275,60 @@ class GPBanditProgram(compute_ir.DesignerProgram):
         with jax_timing.device_phase(
             self.device_phase, **tracing_lib.FUSED_FLUSH
         ) as phase:
-            states, warm_next, result = _gp_bandit_flush_program(
-                d0._model, d0._ard, d0._vec_opt, d0._make_acquisition(),
+            states, warm_next, result = self._flush(
+                self._model(d0), d0._ard, d0._vec_opt, d0._make_acquisition(),
                 stacked["md"], stacked["rng_train"], stacked["rng_acq"],
                 stacked["warm"],
                 items[0]["restarts"], d0.ensemble_size,
                 items[0]["count"], d0.use_trust_region,
             )
             phase.block(result)
-        return _gp_bandit_demux(
-            items, pad_to, states, warm_next, result, sparse=False
-        )
+        return _gp_bandit_demux(items, states, warm_next, result)
 
     def finalize(self, designer, item, output):
         """Host-side demux: per-study warm-param writeback + decode — the
         same state transitions the sequential suggest performs."""
-        states = output["states"]
         designer._record_train()
         if designer._warm_update_allowed():
             # The unconstrain already ran (vmapped) inside the flush program.
             designer._warm_params = output["warm_next"]
             designer._warm_is_trained = True
-        designer._last_predictive = gp_lib.EnsemblePredictive(states)
+        self._keep_fit(designer, output["states"])
         return designer._decode_result(
-            output["result"], item["count"], kind=designer.acquisition
+            output["result"],
+            item["count"],
+            kind=f"{designer.acquisition}{self.decode_suffix}",
         )
 
     def prewarm_factory(self, problem, **kwargs):
+        # The walker's synthetic studies engage the sparse program exactly
+        # when the factory's surrogate config flips them sparse (threshold
+        # vs the walked trial bucket) — the same auto-switch live studies
+        # use.
         return VizierGPBandit(problem, **kwargs)
 
 
-class GPBanditSparseProgram(compute_ir.DesignerProgram):
+class GPBanditProgram(_GPBanditFlush):
+    """Exact-GP single-objective flush."""
+
+    kind = "gp_bandit"
+    device_phase = "gp_bandit.suggest_batched"
+    surrogate_family = "exact"
+    shardable_batch_axis = "study"
+    algorithms = ("GAUSSIAN_PROCESS_BANDIT",)
+    surrogate_mode = surrogate_config_lib.MODE_EXACT
+
+    def _model(self, designer):
+        return designer._model
+
+    def _flush(self, *args):
+        return _gp_bandit_flush_program(*args)
+
+    def _keep_fit(self, designer, states):
+        designer._last_predictive = gp_lib.EnsemblePredictive(states)
+
+
+class GPBanditSparseProgram(_GPBanditFlush):
     """Sparse (SGPR) flush twin: same stages over the collapsed-bound
     posterior, one compiled program per (n-bucket, m-bucket) pair, its own
     device phase so the ``device.wait`` spans' ``phase`` separates sparse
@@ -1416,85 +1339,19 @@ class GPBanditSparseProgram(compute_ir.DesignerProgram):
     surrogate_family = "sparse"
     shardable_batch_axis = "study"
     algorithms = ("GAUSSIAN_PROCESS_BANDIT",)
+    surrogate_mode = surrogate_config_lib.MODE_SPARSE
+    decode_suffix = "+sparse"
 
-    def bucket_key(self, designer, count):
-        if _gp_bandit_unbatchable(designer, count):
-            return None
-        if (
-            designer._refresh_surrogate_mode()
-            != surrogate_config_lib.MODE_SPARSE
-        ):
-            return None
-        # Sparse studies batch among themselves: the sparse model (with
-        # its padded inducing-slot count — the m-bucket) rides in the
-        # statics, so equal keys ⇒ one compiled _sparse_flush_program per
-        # (n-bucket, m-bucket) pair.
-        return compute_ir.BucketKey(
-            kind=self.kind,
-            pad_trials=designer._converter.padding.pad_trials(
-                len(designer._trials)
-            ),
-            cont_width=designer._cont_width,
-            cat_width=designer._cat_width,
-            metric_count=1,
-            count=count,
-            statics=(
-                designer._sparse_model(),
-                designer._ard,
-                designer._vec_opt,
-                designer._batch_restarts(),
-                designer.ensemble_size,
-                designer._make_acquisition(),
-                designer.use_trust_region,
-            ),
-        )
+    def _model(self, designer):
+        return designer._sparse_model()
 
-    def prepare(self, designer, count):
-        return _gp_bandit_prepare(designer, count, sparse=True)
+    def _flush(self, *args):
+        return sparse_bandit._sparse_flush_program(*args)
 
-    def device_program(self, items, pad_to=None, placement=None):
-        from vizier_tpu.parallel import batch_executor
-
-        d0: "VizierGPBandit" = items[0]["designer"]
-        stacked = batch_executor.stack_members(
-            items, ("md", "rng_train", "rng_acq", "warm"), pad_to, placement
-        )
-        with jax_timing.device_phase(
-            self.device_phase, **tracing_lib.FUSED_FLUSH
-        ) as phase:
-            states, warm_next, result = sparse_bandit._sparse_flush_program(
-                d0._sparse_model(), d0._ard, d0._vec_opt,
-                d0._make_acquisition(),
-                stacked["md"], stacked["rng_train"], stacked["rng_acq"],
-                stacked["warm"],
-                items[0]["restarts"], d0.ensemble_size,
-                items[0]["count"], d0.use_trust_region,
-            )
-            phase.block(result)
-        return _gp_bandit_demux(
-            items, pad_to, states, warm_next, result, sparse=True
-        )
-
-    def finalize(self, designer, item, output):
-        states = output["states"]
-        designer._record_train()
-        if designer._warm_update_allowed():
-            designer._warm_params = output["warm_next"]
-            designer._warm_is_trained = True
+    def _keep_fit(self, designer, states):
         designer._last_predictive = sparse_gp.SparseEnsemblePredictive(states)
         designer._last_sparse_state = states
         designer._surrogate_counts["sparse_suggests"] += 1
-        return designer._decode_result(
-            output["result"],
-            item["count"],
-            kind=f"{designer.acquisition}+sparse",
-        )
-
-    def prewarm_factory(self, problem, **kwargs):
-        # The walker's synthetic studies engage this program exactly when
-        # the factory's surrogate config flips them sparse (threshold vs
-        # the walked trial bucket) — the same auto-switch live studies use.
-        return VizierGPBandit(problem, **kwargs)
 
 
 compute_registry.register(VizierGPBandit, GPBanditProgram())

@@ -34,6 +34,7 @@ or retraces within a padding bucket), and ensemble members × metrics are
 
 from __future__ import annotations
 
+import abc
 import dataclasses
 import functools
 from typing import Any, Callable, List, Optional, Sequence, Tuple
@@ -668,49 +669,16 @@ def _suggest_set_pe(
     )
 
 
-@functools.partial(
-    jax.jit,
-    static_argnames=("model", "vec_opt", "count", "config", "use_trust_region"),
-)
-def suggest_batched(
-    model: gp_lib.VizierGaussianProcess,
-    vec_opt: vectorized_lib.VectorizedOptimizer,
-    states_me,  # leading study axis [B, M, E]
-    all_data,  # GPData with leading study axis [B, ...]
-    data,  # completed-trials GPData with leading study axis [B, ...]
-    rng: Array,  # [B] per-study keys
-    first_has_new: Array,  # [B] bool
-    has_completed: Array,  # [B] bool
-    count: int,
-    config: UCBPEConfig,
-    use_trust_region: bool = True,
-) -> Tuple[vectorized_lib.VectorizedOptimizerResult, dict]:
-    """Multi-study UCB-PE batch: ONE device program vmapping the sequential
-    :func:`_suggest_batch` (greedy per-pick UCB/PE with pending-point
-    conditioning) over a leading study axis.
-
-    Used by the cross-study batch executor
-    (``vizier_tpu.parallel.batch_executor``): every slot runs the exact
-    per-study program, so slot i matches study i executed alone. The labels
-    / reference-point / prior-feature plumbing the sequential path computes
-    eagerly is folded into the traced program (same formulas, zero host
-    dispatches per study). The mesh-sharded and prior-acquisition variants
-    are not batchable (their bucket key is None).
-    """
-
-    return _sweep_batched(
-        model, vec_opt, states_me, all_data, data, rng,
-        first_has_new, has_completed, count, config, use_trust_region,
-    )
-
-
 def _sweep_batched(
     model, vec_opt, states_me, all_data, data, rng,
     first_has_new, has_completed, count, config, use_trust_region,
 ):
-    """Trace-shared body of :func:`suggest_batched` (also used by the fused
-    flush program): vmap of the per-study greedy batch loop, with the label
-    stack / reference point / prior features folded into the trace."""
+    """The sweep of both flush programs: ONE traced vmap of the sequential
+    :func:`_suggest_batch` (greedy per-pick UCB/PE with pending-point
+    conditioning) over a leading study axis, so slot i matches study i
+    executed alone. The label stack / reference point / prior features the
+    sequential path computes before its sweep are folded into the trace
+    (same formulas, zero host dispatches per study)."""
 
     def one(s, ad, d, r, f, h):
         labels_mn = d.labels[None]  # [M=1, N1]
@@ -922,8 +890,8 @@ class VizierGPUCBPEBandit(gp_bandit.VizierGPBandit):
     #   ≈ 2 sweeps per suggest() regardless of batch size.
     # - "per_batch": one full budget split across ALL picks (floored at
     #   _MIN_PICK_EVALUATIONS) — cheapest, measurably worse exploitation
-    #   precision on 20-D (the per-pick sweep dominates e2e latency, ~88%
-    #   at 1000x20-D).
+    #   precision on 20-D (regret: budget_ab_r5.json; its time against the
+    #   default's is not measured on the chip).
     # - "per_pick": every pick runs the full budget — the reference's
     #   effective behavior (its ``_suggest_one`` spends max_evaluations=75k
     #   per pick, ``gp_ucb_pe.py:693-697,1440-1446``, with a TODO
@@ -1235,13 +1203,7 @@ class VizierGPUCBPEBandit(gp_bandit.VizierGPBandit):
         self._warm_params_me = list(params)
         self._warm_is_trained = True
 
-    # -- cross-study batch protocol (vizier_tpu.compute IR) -----------------
-    #
-    # The real implementations live in the registered DesignerProgram
-    # classes at the bottom of this module (UCBPEProgram /
-    # UCBPESparseProgram); the thin methods inherited from VizierGPBandit
-    # keep the duck-typed surface working, routed here via
-    # ``_active_batch_program``.
+    # -- read by the registered programs at the bottom of this module ------
 
     def _batch_ensemble(self) -> int:
         return max(self.ensemble_size, 1)
@@ -1253,37 +1215,6 @@ class VizierGPUCBPEBandit(gp_bandit.VizierGPBandit):
             self._warm_restart_budget() or self.ard_restarts,
             self._batch_ensemble(),
         )
-
-    def _active_batch_program(self):
-        from vizier_tpu.compute import registry as compute_registry
-
-        kind = (
-            "gp_ucb_pe_sparse"
-            if self._surrogate_mode == surrogate_config_lib.MODE_SPARSE
-            else "gp_ucb_pe"
-        )
-        return compute_registry.get(kind)
-
-    @classmethod
-    def batch_execute(
-        cls,
-        items: Sequence[dict],
-        pad_to: Optional[int] = None,
-        placement: Optional[Any] = None,
-    ):
-        """Device half: dispatched to the bucket's registered program."""
-        from vizier_tpu.compute import registry as compute_registry
-
-        kind = "gp_ucb_pe_sparse" if items[0].get("sparse") else "gp_ucb_pe"
-        return compute_registry.get(kind).device_program(
-            items, pad_to=pad_to, placement=placement
-        )
-
-    def batch_finalize(self, item: dict, output: dict) -> List[trial_.TrialSuggestion]:
-        from vizier_tpu.compute import registry as compute_registry
-
-        kind = "gp_ucb_pe_sparse" if output.get("sparse") else "gp_ucb_pe"
-        return compute_registry.get(kind).finalize(self, item, output)
 
     def _use_multitask(self, num_metrics: int) -> bool:
         return (
@@ -1666,10 +1597,11 @@ def default_factory(
 # -- compute-IR programs (vizier_tpu.compute) --------------------------------
 #
 # The batched designer-compute contract for the service DEFAULT: one
-# program per compiled-flush family (exact | sparse UCB-PE). Hook bodies
-# are the pre-IR ``batch_*`` methods moved verbatim (exact) and the sparse
-# twin that exists because the seam does — SGPR train + pending-pick
-# conditioning through the inducing-point posterior.
+# program per compiled-flush family (exact | sparse UCB-PE) over one shared
+# body (``_UCBPEFlush``). The sparse family swaps only the train and the
+# per-pick re-conditioning — SGPR train + pending-pick conditioning through
+# the inducing-point posterior — so 1000+-trial studies on the service
+# DEFAULT scale like the sparse GP-bandit path.
 
 
 def _ucb_pe_unbatchable(designer: "VizierGPUCBPEBandit", count: int) -> bool:
@@ -1693,54 +1625,13 @@ def _ucb_pe_unbatchable(designer: "VizierGPUCBPEBandit", count: int) -> bool:
     )
 
 
-def _ucb_pe_prepare(
-    designer: "VizierGPUCBPEBandit", count: int, sparse: bool
-) -> dict:
-    """Host-side half of a batched UCB-PE suggest (single-objective path).
-
-    Pads + warps this study's rows from the store and draws RNG keys in
-    exactly the sequential order: one train key, then one acquisition key per
-    ``_suggest_batch`` call the budget policy would make. Host-only (numpy
-    ModelData): GPData conversion, label stacking, reference point, and
-    prior features all happen inside the batched device programs —
-    prepare's only device work is the RNG splits.
-    """
-    cont, cat, raw = designer._completed_rows()
-    features, n_pad = designer._padded_features(cont, cat)
-    warped, warper = designer._warp_column(raw[:, designer._objective_indices()[0]])
-    designer._metric_warpers = [warper]
-    designer._warpers_fitted = raw.shape[0] > 0
-    md = types.ModelData(features, designer._padded_labels(warped, n_pad))
-    rng_train = designer._next_rng()
-    two_phase = (
-        designer.acquisition_budget_policy == "first_pick_full" and count > 1
-    )
-    return dict(
-        designer=designer,
-        count=count,
-        md=md,
-        all_md=designer._all_points_model_data(count),
-        first_has_new=np.asarray(designer._has_new_completed_trials()),
-        has_completed=np.asarray(bool(designer._trials)),
-        warm=designer._warm_params_me[0],
-        restarts=designer._batch_restarts(),
-        rng_train=rng_train,
-        rng_acq=designer._next_rng(),
-        rng_acq_rest=designer._next_rng() if two_phase else None,
-        sparse=sparse,
-    )
+def _ucb_pe_two_phase(designer: "VizierGPUCBPEBandit", count: int) -> bool:
+    """Whether the budget policy makes two sweeps of this suggest (the
+    first pick alone, then the rest), exactly like the sequential flow."""
+    return designer.acquisition_budget_policy == "first_pick_full" and count > 1
 
 
-def _ucb_pe_stacked_names(two_phase: bool) -> Tuple[str, ...]:
-    """The prepared entries a UCB-PE flush stacks along the study axis."""
-    names = (
-        "md", "all_md", "rng_train", "rng_acq", "warm", "first_has_new",
-        "has_completed",
-    )
-    return names + ("rng_acq_rest",) if two_phase else names
-
-
-def _ucb_pe_demux(items, states, warm_next, data, segments, rows, sparse: bool):
+def _ucb_pe_demux(items, states, warm_next, data, segments, rows):
     """ONE device->host fetch for everything the demux needs; per-slot
     slices below are then free numpy views. The per-flush half of the fused
     path's ``designer.decode`` stage (``finalize`` is the per-slot half)."""
@@ -1765,31 +1656,43 @@ def _ucb_pe_demux(items, states, warm_next, data, segments, rows, sparse: bool):
                     )
                     for (result, aux), n in zip(segments, rows)
                 ],
-                sparse=sparse,
             )
             for i in range(len(items))
         ]
 
 
-class UCBPEProgram(compute_ir.DesignerProgram):
-    """Exact UCB-PE flush: vmapped ARD train + vmapped greedy batch loop(s)
-    (two sweep programs under ``first_pick_full`` with count > 1, exactly
-    like the sequential flow)."""
+class _UCBPEFlush(compute_ir.DesignerProgram):
+    """The flush both UCB-PE families run: vmapped ARD train + vmapped
+    greedy batch loop(s) (two sweep programs under ``first_pick_full`` with
+    count > 1, exactly like the sequential flow).
 
-    kind = "gp_ucb_pe"
-    device_phase = "gp_ucb_pe.suggest_batched"
-    surrogate_family = "exact"
-    shardable_batch_axis = "study"
-    algorithms = ("DEFAULT", "GP_UCB_PE", "ALGORITHM_UNSPECIFIED")
+    A subclass states its registry literals and the three things that
+    differ: the surrogate mode it owns, its models and flush program, and
+    what ``finalize`` keeps of the fit."""
+
+    #: The ``surrogate_config_lib.MODE_*`` whose studies this program owns.
+    surrogate_mode = ""
+
+    @abc.abstractmethod
+    def _models(self, designer: "VizierGPUCBPEBandit", count: int) -> tuple:
+        """The model(s) the flush trains and re-conditions: the bucket
+        statics after the all-points pad, and the first flush arguments."""
+
+    @abc.abstractmethod
+    def _flush(self, *args):
+        """The jitted flush program of this family, looked up in its
+        module when called (``tests/compute/test_tpu_compile.py`` swaps
+        it there)."""
+
+    @abc.abstractmethod
+    def _keep_fit(self, designer: "VizierGPUCBPEBandit", states) -> None:
+        """The sequential suggest's bookkeeping of a trained fit."""
 
     def bucket_key(self, designer, count):
         if _ucb_pe_unbatchable(designer, count):
             return None
-        if (
-            designer._refresh_ucb_pe_surrogate_mode()
-            == surrogate_config_lib.MODE_SPARSE
-        ):
-            return None  # the sparse UCB-PE program owns this study
+        if designer._refresh_ucb_pe_surrogate_mode() != self.surrogate_mode:
+            return None  # the other family's program owns this study
         pad = designer._converter.padding
         n_all = len(designer._trials) + len(designer._active_trials)
         return compute_ir.BucketKey(
@@ -1803,7 +1706,7 @@ class UCBPEProgram(compute_ir.DesignerProgram):
                 # all-points rows get their own padded size (spare rows for
                 # the batch picks), so it is part of the shape identity.
                 pad.pad_trials(n_all + count),
-                designer._model,
+                *self._models(designer, count),
                 designer._ard,
                 designer._vec_opt,
                 designer._pick_vec_opt(count),
@@ -1816,24 +1719,64 @@ class UCBPEProgram(compute_ir.DesignerProgram):
         )
 
     def prepare(self, designer, count):
-        return _ucb_pe_prepare(designer, count, sparse=False)
+        """Host-side half of a batched UCB-PE suggest (single-objective).
+
+        Pads + warps this study's rows from the store and draws RNG keys in
+        exactly the sequential order: one train key, then one acquisition
+        key per ``_suggest_batch`` call the budget policy would make.
+        Host-only (numpy ModelData): GPData conversion, label stacking,
+        reference point, and prior features all happen inside the batched
+        device programs — prepare's only device work is the RNG splits.
+        """
+        cont, cat, raw = designer._completed_rows()
+        features, n_pad = designer._padded_features(cont, cat)
+        warped, warper = designer._warp_column(
+            raw[:, designer._objective_indices()[0]]
+        )
+        designer._metric_warpers = [warper]
+        designer._warpers_fitted = raw.shape[0] > 0
+        md = types.ModelData(features, designer._padded_labels(warped, n_pad))
+        rng_train = designer._next_rng()
+        return dict(
+            designer=designer,
+            count=count,
+            md=md,
+            all_md=designer._all_points_model_data(count),
+            first_has_new=np.asarray(designer._has_new_completed_trials()),
+            has_completed=np.asarray(bool(designer._trials)),
+            warm=designer._warm_params_me[0],
+            restarts=designer._batch_restarts(),
+            rng_train=rng_train,
+            rng_acq=designer._next_rng(),
+            rng_acq_rest=(
+                designer._next_rng()
+                if _ucb_pe_two_phase(designer, count)
+                else None
+            ),
+        )
 
     def device_program(self, items, pad_to=None, placement=None):
         from vizier_tpu.parallel import batch_executor
 
         d0: "VizierGPUCBPEBandit" = items[0]["designer"]
         count = items[0]["count"]
-        two_phase = (
-            d0.acquisition_budget_policy == "first_pick_full" and count > 1
+        two_phase = _ucb_pe_two_phase(d0, count)
+        names = (
+            "md", "all_md", "rng_train", "rng_acq", "warm", "first_has_new",
+            "has_completed",
         )
         stacked = batch_executor.stack_members(
-            items, _ucb_pe_stacked_names(two_phase), pad_to, placement
+            items,
+            names + ("rng_acq_rest",) if two_phase else names,
+            pad_to,
+            placement,
         )
         with jax_timing.device_phase(
             self.device_phase, **tracing_lib.FUSED_FLUSH
         ) as phase:
-            states, warm_next, data, segments = _ucb_pe_flush_program(
-                d0._model, d0._ard, d0._vec_opt, d0._pick_vec_opt(count),
+            states, warm_next, data, segments = self._flush(
+                *self._models(d0, count),
+                d0._ard, d0._vec_opt, d0._pick_vec_opt(count),
                 stacked["md"], stacked["all_md"],
                 stacked["rng_train"], stacked["rng_acq"],
                 stacked["rng_acq_rest" if two_phase else "rng_acq"],
@@ -1844,9 +1787,7 @@ class UCBPEProgram(compute_ir.DesignerProgram):
             )
             phase.block(segments)
         rows = [1, count - 1] if two_phase else [count]
-        return _ucb_pe_demux(
-            items, states, warm_next, data, segments, rows, sparse=False
-        )
+        return _ucb_pe_demux(items, states, warm_next, data, segments, rows)
 
     def finalize(self, designer, item, output):
         """Host-side demux: warm writeback, fit caching for predict/sample,
@@ -1860,112 +1801,57 @@ class UCBPEProgram(compute_ir.DesignerProgram):
             designer._warm_is_trained = True
         states_me = jax.tree_util.tree_map(lambda a: a[None], states)  # [1, E]
         designer._cached_states = (states_me, [output["data"]])
-        designer._last_predictive = gp_lib.EnsemblePredictive(states)
+        self._keep_fit(designer, states)
         return designer._decode_ucb_pe(output["segments"])
 
     def prewarm_factory(self, problem, **kwargs):
         return VizierGPUCBPEBandit(problem, **kwargs)
 
 
-class UCBPESparseProgram(compute_ir.DesignerProgram):
-    """Sparse UCB-PE flush: SGPR collapsed-bound train + the greedy batch
-    with pending-pick conditioning through the inducing-point posterior.
+class UCBPEProgram(_UCBPEFlush):
+    """Exact UCB-PE flush."""
 
-    Exists because the IR seam does: the program reuses the exact UCB-PE
-    prepare/demux shapes and the shared ``_sweep_batched`` body, swapping
-    only the train and the per-pick re-conditioning — 1000+-trial studies
-    on the service DEFAULT scale like the sparse GP-bandit path."""
+    kind = "gp_ucb_pe"
+    device_phase = "gp_ucb_pe.suggest_batched"
+    surrogate_family = "exact"
+    shardable_batch_axis = "study"
+    algorithms = ("DEFAULT", "GP_UCB_PE", "ALGORITHM_UNSPECIFIED")
+    surrogate_mode = surrogate_config_lib.MODE_EXACT
+
+    def _models(self, designer, count):
+        return (designer._model,)
+
+    def _flush(self, *args):
+        return _ucb_pe_flush_program(*args)
+
+    def _keep_fit(self, designer, states):
+        designer._last_predictive = gp_lib.EnsemblePredictive(states)
+
+
+class UCBPESparseProgram(_UCBPEFlush):
+    """Sparse UCB-PE flush: SGPR collapsed-bound train + the greedy batch
+    with pending-pick conditioning through the inducing-point posterior."""
 
     kind = "gp_ucb_pe_sparse"
     device_phase = "sparse_gp.ucb_pe_suggest_batched"
     surrogate_family = "sparse"
     shardable_batch_axis = "study"
     algorithms = ("DEFAULT", "GP_UCB_PE", "ALGORITHM_UNSPECIFIED")
+    surrogate_mode = surrogate_config_lib.MODE_SPARSE
 
-    def bucket_key(self, designer, count):
-        if _ucb_pe_unbatchable(designer, count):
-            return None
-        if (
-            designer._refresh_ucb_pe_surrogate_mode()
-            != surrogate_config_lib.MODE_SPARSE
-        ):
-            return None
-        pad = designer._converter.padding
-        n_all = len(designer._trials) + len(designer._active_trials)
-        return compute_ir.BucketKey(
-            kind=self.kind,
-            pad_trials=pad.pad_trials(len(designer._trials)),
-            cont_width=designer._cont_width,
-            cat_width=designer._cat_width,
-            metric_count=1,
-            count=count,
-            statics=(
-                pad.pad_trials(n_all + count),
-                # Both sparse models ride the statics: the m-bucket (train)
-                # AND the augmented-capacity model (re-conditioning), so
-                # equal keys ⇒ one compiled program per (n, m, count).
-                designer._sparse_model(),
-                designer._sparse_all_model(count),
-                designer._ard,
-                designer._vec_opt,
-                designer._pick_vec_opt(count),
-                designer._batch_restarts(),
-                designer._batch_ensemble(),
-                designer.config,
-                designer.use_trust_region,
-                designer.acquisition_budget_policy,
-            ),
-        )
+    def _models(self, designer, count):
+        # Both sparse models: the m-bucket (train) AND the augmented-
+        # capacity model (re-conditioning), so equal keys ⇒ one compiled
+        # program per (n, m, count).
+        return (designer._sparse_model(), designer._sparse_all_model(count))
 
-    def prepare(self, designer, count):
-        return _ucb_pe_prepare(designer, count, sparse=True)
+    def _flush(self, *args):
+        return _sparse_ucb_pe_flush_program(*args)
 
-    def device_program(self, items, pad_to=None, placement=None):
-        from vizier_tpu.parallel import batch_executor
-
-        d0: "VizierGPUCBPEBandit" = items[0]["designer"]
-        count = items[0]["count"]
-        two_phase = (
-            d0.acquisition_budget_policy == "first_pick_full" and count > 1
-        )
-        stacked = batch_executor.stack_members(
-            items, _ucb_pe_stacked_names(two_phase), pad_to, placement
-        )
-        with jax_timing.device_phase(
-            self.device_phase, **tracing_lib.FUSED_FLUSH
-        ) as phase:
-            states, warm_next, data, segments = _sparse_ucb_pe_flush_program(
-                d0._sparse_model(), d0._sparse_all_model(count),
-                d0._ard, d0._vec_opt, d0._pick_vec_opt(count),
-                stacked["md"], stacked["all_md"],
-                stacked["rng_train"], stacked["rng_acq"],
-                stacked["rng_acq_rest" if two_phase else "rng_acq"],
-                stacked["warm"], stacked["first_has_new"],
-                stacked["has_completed"],
-                items[0]["restarts"], d0._batch_ensemble(), count,
-                d0.config, d0.use_trust_region, two_phase,
-            )
-            phase.block(segments)
-        rows = [1, count - 1] if two_phase else [count]
-        return _ucb_pe_demux(
-            items, states, warm_next, data, segments, rows, sparse=True
-        )
-
-    def finalize(self, designer, item, output):
-        states = output["states"]  # sparse [E] leaves
-        designer._record_train()
-        if designer._warm_update_allowed():
-            designer._warm_params_me = [output["warm_next"]]
-            designer._warm_is_trained = True
-        states_me = jax.tree_util.tree_map(lambda a: a[None], states)
-        designer._cached_states = (states_me, [output["data"]])
+    def _keep_fit(self, designer, states):
         designer._last_predictive = sparse_gp.SparseEnsemblePredictive(states)
         designer._last_sparse_state = states
         designer._surrogate_counts["sparse_suggests"] += 1
-        return designer._decode_ucb_pe(output["segments"])
-
-    def prewarm_factory(self, problem, **kwargs):
-        return VizierGPUCBPEBandit(problem, **kwargs)
 
 
 compute_registry.register(VizierGPUCBPEBandit, UCBPEProgram())

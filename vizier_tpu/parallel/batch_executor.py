@@ -6,9 +6,10 @@ schedule (``converters.padding``) quantizes trials/features into a small
 grid of ``(pad_trials, cont_width, cat_width)`` buckets by construction —
 so concurrent designer computations from *different* studies can be
 collected into shape-bucket queues and executed as one ``jax.vmap``-ed
-dispatch over a leading study axis (``gp_bandit.train_batched`` /
-``suggest_batched``). That replaces N dispatches that each leave the MXU
-idle between kernel launches with one dispatch of N-fold work.
+dispatch over a leading study axis (the registered programs' flush
+bodies, e.g. ``gp_ucb_pe._ucb_pe_flush_program``). That replaces N
+dispatches that each leave the MXU idle between kernel launches with one
+dispatch of N-fold work.
 
 Scheduling is a bounded micro-batch window: a bucket flushes when it
 reaches ``max_batch_size`` slots ("full") or when its oldest slot has
@@ -19,7 +20,7 @@ bucket regardless of occupancy. A batch of one takes the ordinary
 sequential designer path (bit-identical to batching off when there is no
 concurrency).
 
-Fail isolation: a slot whose host-side ``batch_prepare`` raises is dropped
+Fail isolation: a slot whose host-side ``prepare`` raises is dropped
 from the batch before the device program runs; a device-program failure
 falls every slot back to its own sequential ``suggest`` (errors stay
 per-slot); a slot whose decoded suggestions contain non-finite parameters
@@ -75,8 +76,8 @@ registered in :mod:`vizier_tpu.compute.registry`; the executor resolves a
 designer's program there and consumes it generically — the same registry
 feeds the prewarm walker, chaos slot-isolation wrappers,
 the ``device.wait`` stage span's ``phase``, and the speculative lane.
-Designers carrying only the legacy duck-typed ``batch_*`` methods (test
-stubs, out-of-tree extensions) resolve to an adapter; anything else runs
+A wrapper designer hands its program over through a ``compute_program``
+hook; a designer with neither a hook nor a registered program runs
 sequentially.
 """
 
@@ -965,8 +966,7 @@ class BatchExecutor:
             # Slot 0's resolved program runs the bucket's device body (the
             # bucket key guarantees every slot resolves the same kind; a
             # chaos-wrapped slot 0 therefore poisons the shared program,
-            # exercising the whole-batch fallback — the IR-level twin of
-            # the old designer.batch_execute dispatch).
+            # exercising the whole-batch fallback).
             if shardable:
                 outputs = program.device_program(
                     [slot.item for slot in live],

@@ -188,7 +188,7 @@ class SpeculativeConfig:
         return cls(speculative=False)
 
     def as_dict(self) -> dict:
-        """JSON-stampable form (bench.py / tools artifacts)."""
+        """JSON-stampable form (tools artifacts)."""
         return {
             "speculative": self.speculative,
             "workers": self.workers,

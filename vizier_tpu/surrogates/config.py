@@ -144,7 +144,7 @@ class SurrogateConfig:
         )
 
     def as_dict(self) -> dict:
-        """JSON-stampable form (bench.py / tools artifacts)."""
+        """JSON-stampable form (tools artifacts)."""
         return {
             "sparse": self.sparse,
             "sparse_threshold_trials": self.sparse_threshold_trials,

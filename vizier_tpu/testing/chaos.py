@@ -95,6 +95,14 @@ class ChaosMonkey:
             return sum(c["faults"] for c in self._counts.values())
 
 
+def _strike_as_designer_failure(chaos: ChaosMonkey, site: str) -> None:
+    """A strike that surfaces designer-shaped (``FailedSuggestError``)."""
+    try:
+        chaos.strike(site)
+    except InjectedFaultError as e:
+        raise failing.FailedSuggestError(str(e)) from None
+
+
 class ChaosDesigner(core_lib.Designer):
     """Probabilistic-failure wrapper around any designer.
 
@@ -117,67 +125,25 @@ class ChaosDesigner(core_lib.Designer):
 
     def update(self, completed, all_active=core_lib.ActiveTrials()) -> None:
         if self._fail_updates:
-            try:
-                self._chaos.strike("designer.update")
-            except InjectedFaultError as e:
-                raise failing.FailedSuggestError(str(e)) from None
+            _strike_as_designer_failure(self._chaos, "designer.update")
         self._inner.update(completed, all_active)
 
     def suggest(self, count: Optional[int] = None) -> List[trial_.TrialSuggestion]:
-        try:
-            self._chaos.strike("designer.suggest")
-        except InjectedFaultError as e:
-            raise failing.FailedSuggestError(str(e)) from None
+        _strike_as_designer_failure(self._chaos, "designer.suggest")
         return list(self._inner.suggest(count))
 
-    # -- cross-study batch protocol (vizier_tpu.compute IR) -----------------
-    # Chaos-wrapped designers stay batchable: ``compute_program`` resolves
-    # the inner designer's registered DesignerProgram and wraps it in
-    # :class:`ChaosProgram`, so fault injection rides the IR generically —
-    # every registered program family (exact, sparse, UCB-PE, future
-    # designers) inherits slot-isolation chaos without per-designer method
-    # copies. A strike in the per-slot host-side hooks (prepare/finalize)
-    # degrades only that study; a strike in ``device_program`` poisons the
-    # shared device body, driving the whole-batch sequential fallback.
-
     def compute_program(self, count: Optional[int] = None):
+        """Chaos-wrapped designers stay batchable: the inner designer's
+        resolved ``DesignerProgram`` wrapped in :class:`ChaosProgram`, so
+        fault injection rides the IR generically — every registered program
+        family inherits slot-isolation chaos."""
         from vizier_tpu.compute import registry as compute_registry
 
         resolved = compute_registry.resolve(self._inner, count)
         if resolved is None:
             return None
         program, key = resolved
-        return ChaosProgram(program, self), key
-
-    # Legacy duck-typed surface (direct callers and tests).
-
-    def batch_bucket_key(self, count: Optional[int] = None):
-        key_fn = getattr(self._inner, "batch_bucket_key", None)
-        return key_fn(count) if key_fn is not None else None
-
-    def batch_prepare(self, count: Optional[int] = None) -> dict:
-        try:
-            self._chaos.strike("designer.batch_prepare")
-        except InjectedFaultError as e:
-            raise failing.FailedSuggestError(str(e)) from None
-        return self._inner.batch_prepare(count)
-
-    def batch_execute(
-        self, items, pad_to: Optional[int] = None, placement=None
-    ):
-        self._chaos.strike("designer.batch_execute")
-        if placement is not None:
-            return self._inner.batch_execute(
-                items, pad_to=pad_to, placement=placement
-            )
-        return self._inner.batch_execute(items, pad_to=pad_to)
-
-    def batch_finalize(self, item: dict, output) -> List[trial_.TrialSuggestion]:
-        try:
-            self._chaos.strike("designer.batch_finalize")
-        except InjectedFaultError as e:
-            raise failing.FailedSuggestError(str(e)) from None
-        return self._inner.batch_finalize(item, output)
+        return ChaosProgram(program, self._chaos), key
 
 
 class ChaosProgram:
@@ -187,17 +153,16 @@ class ChaosProgram:
     requires: wrapping happens at program resolution
     (``ChaosDesigner.compute_program``), so every registered program —
     exact, sparse, UCB-PE, future designers — is chaos-testable through
-    one seam. The host-side hooks route through the bound chaos designer's
-    striking ``batch_*`` methods (so per-test instance patches keep
-    working): a per-slot strike raises designer-shaped
-    ``FailedSuggestError`` and degrades only that study; a
-    ``device_program`` strike poisons the shared device body, driving the
-    executor's whole-batch sequential fallback.
+    one seam. Each hook strikes, then delegates to the wrapped program with
+    the chaos designer's inner designer: a per-slot strike (prepare /
+    finalize) raises designer-shaped ``FailedSuggestError`` and degrades
+    only that study; a ``device_program`` strike poisons the shared device
+    body, driving the executor's whole-batch sequential fallback.
     """
 
-    def __init__(self, inner, chaos_designer: ChaosDesigner):
+    def __init__(self, inner, chaos: ChaosMonkey):
         self._inner = inner
-        self._designer = chaos_designer
+        self._chaos = chaos
         self.kind = inner.kind
         self.device_phase = inner.device_phase
         self.surrogate_family = inner.surrogate_family
@@ -214,15 +179,18 @@ class ChaosProgram:
         )
 
     def prepare(self, designer, count):
-        return designer.batch_prepare(count)
+        _strike_as_designer_failure(self._chaos, "designer.batch_prepare")
+        return self._inner.prepare(designer._inner, count)
 
     def device_program(self, items, pad_to: Optional[int] = None, placement=None):
-        return self._designer.batch_execute(
+        self._chaos.strike("designer.batch_execute")
+        return self._inner.device_program(
             items, pad_to=pad_to, placement=placement
         )
 
     def finalize(self, designer, item, output):
-        return designer.batch_finalize(item, output)
+        _strike_as_designer_failure(self._chaos, "designer.batch_finalize")
+        return self._inner.finalize(designer._inner, item, output)
 
     def prewarm_factory(self, problem, **kwargs):
         return self._inner.prewarm_factory(problem, **kwargs)
