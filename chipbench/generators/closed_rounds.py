@@ -3,11 +3,15 @@ completes every returned trial, and asks again.
 
 Parameters (the traffic file): ``clients``, ``studies_per_client``,
 ``suggest_count``, ``start_trials``, ``max_rounds_per_study`` (optional cap
-below what the bucket allows), ``think_ms``. Each client thread walks its
+below what the bucket allows), ``think_ms`` (the worker's pause after it has
+completed a round's trials and before it asks again, slept inside the span
+``client.think``; no latency sample holds it). Each client thread walks its
 own studies round-robin under one fixed ``client_id``. A study leaves the
 rotation before a suggest would compile a new shape (``studies.bucket``);
 a client with no study left ends the run as not correct — the traffic file
-is then wrong for the window, not the program.
+is then wrong for the window, not the program. How near a window came to
+that is a number of every run: ``requests_available`` (the rounds the
+studies can still serve when the window opens) beside the window's count.
 """
 
 from __future__ import annotations
@@ -19,6 +23,7 @@ from typing import Any, Callable, Dict, List, Optional
 import numpy as np
 
 from chipbench.lib import checks
+from chipbench.lib import stages
 from chipbench.lib import studies as studies_lib
 
 MAX_WARM_ROUNDS = 6
@@ -27,6 +32,20 @@ MAX_WARM_ROUNDS = 6
 def study_count(traffic: Dict[str, Any]) -> int:
     """The studies a cell of this traffic opens (every cell: at most 64)."""
     return traffic["clients"] * traffic["studies_per_client"]
+
+
+def requests_after_setup(config: Dict[str, Any], traffic: Dict[str, Any], warm: int) -> int:
+    """The requests a window can be served before a client runs out of
+    studies, from the files alone: every study's rounds in its bucket, less
+    what ``Generator.setup`` spends — one cold round a study, one more on
+    the first study, ``warm`` warm rounds on each client's first study (1 to
+    ``MAX_WARM_ROUNDS``: as many as it takes to compile nothing) and, with
+    several clients, each first study's turn alone."""
+    clients, per_client = traffic["clients"], traffic["studies_per_client"]
+    rounds = studies_lib.rounds_in_bucket(
+        traffic["start_trials"], traffic["suggest_count"], traffic.get("max_rounds_per_study"))
+    alone = clients if clients > 1 else 0
+    return clients * per_client * rounds - (clients * per_client + 1 + warm * clients + alone)
 
 
 def check_data(config: Dict[str, Any], traffic: Dict[str, Any]) -> None:
@@ -155,6 +174,13 @@ class Generator:
     def _of(self, client: int) -> List[_Study]:
         return [s for s in self.studies if s.client == client]
 
+    def requests_available(self) -> int:
+        """The rounds the studies can still serve, as they stand: what the
+        clients are given before one of them is ``exhausted``."""
+        return sum(
+            min(s.rounds_left, studies_lib.rounds_in_bucket(s.completed, self.count))
+            for s in self.studies if self._eligible(s))
+
     def _each_client(self, work: Callable[[int, np.random.Generator], Any]) -> None:
         errors: List[BaseException] = []
         barrier = threading.Barrier(self.clients)
@@ -249,7 +275,8 @@ class Generator:
                 with self._lock:
                     self.records.append(record)
                 if think:
-                    time.sleep(think)
+                    with self.annotate(stages.THINK):
+                        time.sleep(think)
 
         # One start for every client, a little ahead so each thread is up.
         begin["t"] = time.perf_counter() + 0.05

@@ -15,8 +15,12 @@ keeps none of ``closed_rounds``'s rules and owes another (``check_data``):
 the configuration's ``warm_shapes`` name every (trained pad, all-points pad)
 a ``suggest(1)`` of the fill can compile for, and ``setup`` drives each one
 directly — a study a trained pad, ``c`` completed trials loaded and ACTIVE
-ones added until each all-points pad in turn is met — then whole fills until
-one compiles nothing. The cell reports ``compiles_in_window``.
+ones added until each all-points pad in turn is met, every direct suggest
+under a ``client_id`` of its own (the service hands a client that still
+holds an ACTIVE trial that trial back and computes nothing) — then whole
+fills until one compiles nothing. The cell reports ``compiles_in_window``,
+and every run ``requests_available``: the requests the unfilled studies
+hold when the window opens, beside the window's count.
 
 Each trial is recorded on the client's clock: request sent, response
 received, complete sent, complete acknowledged. From those the generator
@@ -37,6 +41,7 @@ import numpy as np
 
 from chipbench.lib import checks
 from chipbench.lib import pending as pending_lib
+from chipbench.lib import stages
 from chipbench.lib import studies as studies_lib
 
 MAX_WARM_FILLS = 3
@@ -64,17 +69,21 @@ def shapes_met(clients: int, trials_per_client: int, count: int) -> List[Tuple[i
     })
 
 
-def warm_plan(shapes, clients: int, trials_per_client: int, count: int) -> Dict[int, Tuple[int, List[int]]]:
+def warm_plan(shapes, clients: int, trials_per_client: int, count: int) -> Dict[int, Tuple[int, List[int], List[int]]]:
     """How set-up meets every shape on one study a trained pad: pad →
     (completed trials to load, the completed + ACTIVE + count to reach for
-    each all-points pad in turn). The completed trials are the most for
-    which one more still trains in the pad — from the 32 pad on that is past
-    the designer's ``warm_start_min_trials`` (20), so the train after the
-    first, cold, one is a warm one, as in a fill — and every step stays
-    inside what a fill can hold (others ACTIVE: at most one a worker)."""
+    each all-points pad in turn, the worker each direct suggest asks as).
+    The completed trials are the most for which one more still trains in
+    the pad — from the 32 pad on that is past the designer's
+    ``warm_start_min_trials`` (20), so the train after the first, cold, one
+    is a warm one, as in a fill — and every step stays inside what a fill
+    can hold (others ACTIVE: at most one a worker). The workers are one a
+    direct suggest — each step's, then the one that evaluates a trial, then
+    the one that asks after it: a worker that asked before still holds its
+    trial and would be handed it back, with no shape met."""
     total = clients * trials_per_client
     pad = studies_lib.pad_power_of_two
-    plan: Dict[int, Tuple[int, List[int]]] = {}
+    plan: Dict[int, Tuple[int, List[int], List[int]]] = {}
     for trained in sorted({shape[0] for shape in shapes}):
         completed = max(c for c in range(total - 1) if pad(c) == pad(c + 1) == trained)
         steps, reached = [], completed + count
@@ -85,8 +94,17 @@ def warm_plan(shapes, clients: int, trials_per_client: int, count: int) -> Dict[
                 f"from {completed} completed trials")
             steps.append(reached)
             reached += 1  # the suggest's own trial stays ACTIVE
-        plan[trained] = (completed, steps)
+        plan[trained] = (completed, steps, list(range(len(steps) + 2)))
     return plan
+
+
+def requests_after_setup(config: Dict[str, Any], traffic: Dict[str, Any], warm: int) -> int:
+    """The requests a window can be served before the workers run out of
+    studies, from the files alone: the studies the traffic opens, less a
+    study a trained pad and ``warm`` warm fills (1 to ``MAX_WARM_FILLS``),
+    each filled by every worker's ``trials_per_client`` requests."""
+    pads = len({tuple(shape)[0] for shape in config["warm_shapes"]})
+    return (traffic["studies"] - pads - warm) * traffic["clients"] * traffic["trials_per_client"]
 
 
 def check_data(config: Dict[str, Any], traffic: Dict[str, Any]) -> None:
@@ -103,7 +121,12 @@ def check_data(config: Dict[str, Any], traffic: Dict[str, Any]) -> None:
         f"a fill of {traffic['clients']} x {traffic['trials_per_client']} trials meets the shapes "
         f"{met}; the configuration's warm_shapes, which set-up warms up, are {warmed}")
     assert met[-1][0] < 512, f"a fill reaches the sparse switch at 512 trials: trained pad {met[-1][0]}"
-    pads = len(warm_plan(met, traffic["clients"], traffic["trials_per_client"], count))
+    plan = warm_plan(met, traffic["clients"], traffic["trials_per_client"], count)
+    for trained, (_, steps, workers) in plan.items():
+        assert len(set(workers)) == len(workers) == len(steps) + 2 and max(workers) < traffic["clients"], (
+            f"the direct suggests of the trained pad {trained} ask as the workers {workers}: each needs an id "
+            f"of its own among the fill's {traffic['clients']} (a worker that holds a trial is handed it back)")
+    pads = len(plan)
     floor = pads + MAX_WARM_FILLS + 1
     assert traffic["studies"] >= floor, (
         f"the traffic opens {traffic['studies']} studies; set-up alone takes {floor - 1} "
@@ -184,15 +207,16 @@ class Generator:
         # up to it and a suggest whose trial stays out (the first one trains,
         # cold; the others find the fit cached and only sweep). Then one
         # trial is evaluated, and a last suggest trains again: warm from 20
-        # completed trials on, as in a fill.
+        # completed trials on, as in a fill. Each asks as a worker that
+        # holds no trial, so each is computed and none handed back.
         rng = np.random.default_rng([self.seed, 3])
-        for study, (completed, steps) in zip(warm, plan.values()):
+        for study, (completed, steps, workers) in zip(warm, plan.values()):
             self._load(study, completed, 0)
-            for reached in steps:
+            for reached, worker in zip(steps, workers):
                 self._load(study, 0, reached - self.count - len(study.trials))
-                self._one_trial(study, 0, rng, evaluate=False)
-            self._one_trial(study, 0, rng)
-            self._one_trial(study, 0, rng, evaluate=False)
+                self._direct(study, worker, rng, evaluate=False)
+            self._direct(study, workers[-2], rng)
+            self._direct(study, workers[-1], rng, evaluate=False)
         shaped = time.perf_counter()
         # Then whole fills, as the window runs them, until one compiles nothing.
         warm_fills = 0
@@ -206,6 +230,21 @@ class Generator:
             "studies": len(self.studies), "open_s": opened - t0, "warm_shapes_s": shaped - opened,
             "warm_fills_s": time.perf_counter() - shaped, "warm_shapes": len(shapes), "warm_fills": warm_fills,
         }
+
+    def _direct(self, study: _Study, worker: int, rng: np.random.Generator, evaluate: bool = True) -> None:
+        """One of set-up's direct suggests, which has to be computed: a
+        trial the clients' record already has was handed back."""
+        known = len(study.trials)
+        self._one_trial(study, worker, rng, evaluate=evaluate)
+        if len(study.trials) != known + self.count:
+            raise RuntimeError(
+                f"set-up's suggest as client-{worker} on study {study.index} was handed back a trial "
+                f"it already held: no shape was met")
+
+    def requests_available(self) -> int:
+        """The requests the unfilled studies hold, as they stand: what the
+        workers are given before they are ``exhausted``."""
+        return len(self._unfilled) * self.clients * self.per_client
 
     def _load(self, study: _Study, completed: int, active: int) -> None:
         """``completed`` more seeded trials with their values and ``active``
@@ -319,7 +358,8 @@ class Generator:
                         if not more:
                             break
                         if think:
-                            time.sleep(think)
+                            with self.annotate(stages.THINK):
+                                time.sleep(think)
             except threading.BrokenBarrierError:
                 return
             except BaseException as e:  # re-raised on the caller's thread

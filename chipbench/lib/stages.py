@@ -17,15 +17,17 @@ PER_FLUSH = "flush"
 # One per request, on either path, before anything can fail: the divisor.
 REQUEST_STAGE = "service.read"
 # ``tracing.STAGES`` in a request's order (checked against the program's in
-# ``tests/chipbench/test_harness.py``), then the two other host spans a
-# traced run's idle gaps are named after: a waiter parked in the executor
-# and the worker completing its trials. Leaves only: ``client.suggest``
+# ``tests/chipbench/test_harness.py``), then the three other host spans a
+# traced run's idle gaps are named after: a waiter parked in the executor,
+# the worker completing its trials, and the worker's own pause between two
+# requests (the traffic's ``think_ms``). Leaves only: ``client.suggest``
 # covers all of them, and a gap goes to the name that covers most of it.
 STAGE_NAMES = (
     "service.read", "policy.load_trials", "designer.update", "designer.prepare",
     "flush.stack", "device.wait", "designer.decode", "service.write",
 )
-GAP_ANNOTATIONS = (*STAGE_NAMES, "batch_executor.queue_wait", "client.complete")
+THINK = "client.think"  # the span every generator sleeps its think time in
+GAP_ANNOTATIONS = (*STAGE_NAMES, "batch_executor.queue_wait", "client.complete", THINK)
 
 
 def series(evidence: Dict[str, Any]) -> Optional[Dict[Tuple[str, str], Tuple[int, float]]]:

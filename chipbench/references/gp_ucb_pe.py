@@ -18,6 +18,17 @@ and weighs the trained hyperparameters by the marginal likelihood. The constants
 centres) are the deployment's published settings and live in the
 configuration file's ``ucb_pe`` block.
 
+A PE pick is scored against a threshold: the completed-posterior mean at
+the argmax-UCB point among the trials and the pending picks. Where two of
+those points are near-tied, the program's float32 and this float64 may each
+put another one first, and the threshold moves by the gap of their means
+with every reading inside its limit. So a PE pick is judged under every
+threshold float32 could have chosen (``near_tie_tolerance``: the points
+whose UCB here lies within twice what the cell itself allows a reading to
+be off), and the threshold under which the sweep's own value reads nearest
+is kept. A flip inside the near-tied set is then no error; a wrong penalty
+or explore coefficient, or a threshold from a point outside the set, still is.
+
 ``posterior_bf16_matmul`` is the control that a test run can hold: the same
 posterior with the operands of its matmuls rounded to bfloat16, which is
 what a float32 matmul at the TPU's default precision multiplies.
@@ -26,7 +37,7 @@ what a float32 matmul at the TPU's default precision multiplies.
 from __future__ import annotations
 
 import math
-from typing import Any, Dict, List
+from typing import Any, Dict, List, Optional
 
 import numpy as np
 from scipy import special
@@ -113,6 +124,16 @@ def warp_labels(labels, goal: str) -> np.ndarray:
     return y - np.mean(y) * feasible - bad_value * (1.0 - feasible)
 
 
+def near_tie_tolerance(config: Dict[str, Any]) -> float:
+    """How far under the best UCB, in label stddevs, a trial's or a pending
+    pick's float64 UCB may lie for float32 to have put it first: two
+    readings of mean + coefficient x stddev, each off by at most what the
+    cell's own limits allow."""
+    limits = config["limits"]
+    return 2.0 * (limits["pick_mean_err_label_std"]
+                  + config["ucb_pe"]["ucb_coefficient"] * limits["pick_stddev_err_label_std"])
+
+
 # -- the acquisition, over a growing pending set --------------------------------
 
 
@@ -164,23 +185,30 @@ class _Batch:
         self.var_all = self.var_all - row * row
         self.pending.append(index)
 
-    def threshold(self) -> float:
+    def thresholds(self, tolerance: float = 0.0) -> List[float]:
         """Completed-posterior mean at the argmax-UCB point among the
-        trials and the pending picks."""
+        trials and the pending picks, first; then, distinct, at every other
+        such point whose UCB lies within ``tolerance`` of the best."""
         mean = np.concatenate([self.mean_x, self.mean[self.pending]])
         std = np.concatenate([self.std_x, self.std[self.pending]])
-        return float(mean[np.argmax(mean + self.ucb_pe["ucb_coefficient"] * std)])
+        ucb = mean + self.ucb_pe["ucb_coefficient"] * std
+        order = np.argsort(-ucb, kind="stable")  # the argmax first
+        near = order[ucb[order] >= ucb[order[0]] - tolerance]
+        return list(dict.fromkeys(float(m) for m in mean[near]))
 
-    def scores(self, use_ucb: bool, also_observed: List[int]) -> np.ndarray:
+    def scores(self, use_ucb: bool, also_observed: List[int], threshold: Optional[float] = None) -> np.ndarray:
         """The acquisition at every point; the trust region is around the
-        trials and the points ``also_observed``."""
+        trials and the points ``also_observed``. A PE score takes the
+        promising region's ``threshold`` (the argmax-UCB point's, if none)."""
         c = self.ucb_pe
         if use_ucb:
             value = self.mean + c["ucb_coefficient"] * self.std_all()
         else:
+            if threshold is None:
+                threshold = self.thresholds()[0]
             explore = self.mean + c["explore_region_ucb_coefficient"] * self.std
             value = self.std_all() + c["cb_violation_penalty_coefficient"] * np.minimum(
-                explore - self.threshold(), 0.0
+                explore - threshold, 0.0
             )
         grow = 0.1 * (len(self.x) + len(also_observed)) / math.sqrt(self.x.shape[1])
         radius = min(c["trust_region_min_radius"] + 0.05 * grow, 1.0)
@@ -243,16 +271,26 @@ def compare(study: Dict[str, Any], trained: Dict[str, Any], config: Dict[str, An
     count = len(picks)
     mean_err = np.max(np.abs(meta["mean"] - batch.mean[:count])) / scale
     std_err = np.max(np.abs(meta["stddev"] - batch.std[:count])) / scale
-    std_all_err, score_err, shortfall = [], [], []
+    std_all_err, score_err, shortfall, tried = [], [], [], []
     two_phase = ucb_pe["acquisition_budget_policy"] == "first_pick_full" and count > 1
+    tolerance = near_tie_tolerance(config) * scale
     for j in range(count):
         # The first pick's trust region is around the trials; where the
         # batch is made in two sweeps, the second's includes the first pick.
         observed = [0] if two_phase and j > 0 else []
         std_all_err.append(abs(meta["stddev_from_all"][j] - batch.std_all()[j]) / scale)
-        score = batch.scores(bool(meta["use_ucb"][j]), observed)
-        score_err.append(abs(meta["acquisition"][j] - score[j]) / scale)
-        shortfall.append((np.max(score[count:]) - score[j]) / scale)
+        # A UCB pick has one score; a PE pick one under each threshold that
+        # float32 could have chosen, and the nearest to the sweep's own counts.
+        use_ucb = bool(meta["use_ucb"][j])
+        under = [None] if use_ucb else batch.thresholds(tolerance)
+        tried.append(0 if use_ucb else len(under))
+        pairs = []
+        for threshold in under:
+            score = batch.scores(use_ucb, observed, threshold)
+            pairs.append((abs(meta["acquisition"][j] - score[j]) / scale, (np.max(score[count:]) - score[j]) / scale))
+        err, short = min(pairs, key=lambda pair: pair[0])
+        score_err.append(err)
+        shortfall.append(short)
         batch.add_pending(j)
     numbers["pick_mean_err_label_std"] = float(mean_err)
     numbers["pick_stddev_err_label_std"] = float(std_err)
@@ -264,6 +302,8 @@ def compare(study: Dict[str, Any], trained: Dict[str, Any], config: Dict[str, An
         "amplitude": float(hyper[0]), "noise_stddev": float(hyper[1]),
         "length_scale_min_max": [float(np.min(hyper[2])), float(np.max(hyper[2]))],
         "ucb_picks": int(np.sum(meta["use_ucb"])),
+        # The thresholds the first pick was judged under (0: a UCB pick), and the most any pick was.
+        "pe_thresholds_tried": tried[0], "pe_thresholds_tried_max": max(tried),
     }
     if count > 1:  # the later picks' shortfall, read and not judged (PERF.md, Open questions)
         later, ucb = np.asarray(shortfall[1:]), np.asarray(meta["use_ucb"][1:], bool)

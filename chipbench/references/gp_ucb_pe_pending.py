@@ -32,7 +32,7 @@ completed trial is a UCB pick (``ucb_or_pe_mismatch``).
 from __future__ import annotations
 
 import math
-from typing import Any, Dict
+from typing import Any, Dict, List, Optional
 
 import numpy as np
 from scipy import special
@@ -101,6 +101,16 @@ def candidates(x, y, pick, rng, spec) -> np.ndarray:
     return np.clip(np.concatenate(out), 0.0, 1.0)
 
 
+def near_tie_tolerance(config: Dict[str, Any]) -> float:
+    """How far under the best UCB, in label stddevs, a trial's or a pending
+    row's float64 UCB may lie for float32 to have put it first: two
+    readings of mean + coefficient x stddev, each off by at most what the
+    cell's own limits allow."""
+    limits = config["limits"]
+    return 2.0 * (limits["pick_mean_err_label_std"]
+                  + config["ucb_pe"]["ucb_coefficient"] * limits["pick_stddev_err_label_std"])
+
+
 # -- one pick, with other workers' trials pending -------------------------------
 
 
@@ -109,7 +119,7 @@ class Conditioned:
     completed trials' (``x``, ``y``; there may be none), and the all-points
     stddev, conditioned on the completed and the ``pending`` rows alike."""
 
-    def __init__(self, x, y, pending, points, amplitude, noise_stddev, length_scales, ucb_pe):
+    def __init__(self, x, y, pending, points, amplitude, noise_stddev, length_scales, ucb_pe, tolerance=0.0):
         self.ucb_pe = ucb_pe
         self.amplitude = float(amplitude)
         noise_var = float(noise_stddev) ** 2
@@ -130,23 +140,33 @@ class Conditioned:
         mean = v.T @ np.linalg.solve(chol, np.asarray(y, np.float64))
         self.mean, self.std = mean[: len(points)], stddev(v)[: len(points)]
         # The promising region's threshold: the completed-posterior mean at
-        # the argmax-UCB point among the trials and the pending rows.
+        # the argmax-UCB point among the trials and the pending rows, first;
+        # then, distinct, at every other such point whose UCB lies within
+        # ``tolerance`` of the best.
         mean_obs, std_obs = mean[len(points):], stddev(v)[len(points):]
-        self.threshold = float(mean_obs[np.argmax(mean_obs + ucb_pe["ucb_coefficient"] * std_obs)]) if len(mean_obs) else 0.0
+        self.thresholds: List[float] = [0.0]
+        if len(mean_obs):
+            ucb = mean_obs + ucb_pe["ucb_coefficient"] * std_obs
+            order = np.argsort(-ucb, kind="stable")  # the argmax first
+            near = order[ucb[order] >= ucb[order[0]] - tolerance]
+            self.thresholds = list(dict.fromkeys(float(m) for m in mean_obs[near]))
         # All-points posterior: the train's noise, or none to speak of when
         # the model reads the noise as high.
         high = (self.amplitude / float(noise_stddev)) ** 2 < ucb_pe["signal_to_noise_threshold"]
         self.std_all = stddev(solve(self.observed, PE_NOISE_STDDEV**2 if high else noise_var, points)[0])
 
-    def scores(self, use_ucb: bool) -> np.ndarray:
+    def scores(self, use_ucb: bool, threshold: Optional[float] = None) -> np.ndarray:
         """The acquisition at every point, with the trust region around the
-        completed and the pending rows."""
+        completed and the pending rows. A PE score takes the promising
+        region's ``threshold`` (the argmax-UCB point's, if none)."""
         c = self.ucb_pe
         if use_ucb:
             value = self.mean + c["ucb_coefficient"] * self.std_all
         else:
+            if threshold is None:
+                threshold = self.thresholds[0]
             explore = self.mean + c["explore_region_ucb_coefficient"] * self.std
-            value = self.std_all + c["cb_violation_penalty_coefficient"] * np.minimum(explore - self.threshold, 0.0)
+            value = self.std_all + c["cb_violation_penalty_coefficient"] * np.minimum(explore - threshold, 0.0)
         if not len(self.observed):
             return value  # no observation at all: everything is trusted
         grow = 0.1 * len(self.observed) / math.sqrt(self.points.shape[1])
@@ -254,18 +274,26 @@ def compare(study: Dict[str, Any], trained: Dict[str, Any], config: Dict[str, An
     # and its score against the best of a seeded candidate set.
     pick = np.asarray(last["row"], np.float64)[None]
     points = np.concatenate([pick, candidates(rows, y, pick, rng, config["check_candidates"])])
-    posterior = Conditioned(rows, y, pending_rows, points, *hyper, config["ucb_pe"])
-    score = posterior.scores(use_ucb)
+    posterior = Conditioned(rows, y, pending_rows, points, *hyper, config["ucb_pe"], near_tie_tolerance(config) * scale)
+    # A UCB pick has one score; a PE pick one under each threshold that
+    # float32 could have chosen, and the nearest to the sweep's own counts.
+    under = [None] if use_ucb else posterior.thresholds
+    pairs = []
+    for threshold in under:
+        score = posterior.scores(use_ucb, threshold)
+        pairs.append((abs(meta["acquisition"] - score[0]) / scale, (np.max(score[1:]) - score[0]) / scale))
+    acquisition_err, shortfall = min(pairs, key=lambda pair: pair[0])
     numbers["pick_mean_err_label_std"] = float(abs(meta["mean"] - posterior.mean[0]) / scale)
     numbers["pick_stddev_err_label_std"] = float(abs(meta["stddev"] - posterior.std[0]) / scale)
     numbers["pick_stddev_all_err_label_std"] = float(abs(meta["stddev_from_all"] - posterior.std_all[0]) / scale)
-    numbers["pick_acquisition_err_label_std"] = float(abs(meta["acquisition"] - score[0]) / scale)
-    numbers["first_pick_shortfall_label_std"] = float((np.max(score[1:]) - score[0]) / scale)
+    numbers["pick_acquisition_err_label_std"] = float(acquisition_err)
+    numbers["first_pick_shortfall_label_std"] = float(shortfall)
     seen = {
         "trials": len(rows), "pending": len(pending_rows), "handed_out_before_request": len(handed_out),
         "acked_before_request": len(acked), "label_std": scale, "nll_per_trial": nll / max(len(rows), 1),
         "amplitude": float(hyper[0]), "noise_stddev": float(hyper[1]),
         "length_scale_min_max": [float(np.min(hyper[2])), float(np.max(hyper[2]))],
         "use_ucb": use_ucb, "first_has_new": first_has_new,
+        "pe_thresholds_tried": 0 if use_ucb else len(under),  # (0: a UCB pick, judged under none)
     }
     return {"numbers": numbers, "seen": seen}

@@ -38,7 +38,8 @@ if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 HERE = os.path.join(ROOT, "chipbench")
 REQUIRED_PLATFORM = "tpu"
-TRACE_SECONDS = 1.0  # ~1.7 million device events per busy second, ~50 s to collect each
+TRACE_SECONDS = 1.0  # ~1.7 million device events per busy second, ~50 s to collect each;
+# a traffic file whose second holds more (many small programs) asks for less: ``trace_seconds``
 TRACE_LEAD_SHARE = 0.4  # of the window: past its start, where every client is released at once
 TRACE_DIR = os.path.join(ROOT, "chiprun_out", "chipbench_trace")
 from chipbench.lib.stages import GAP_ANNOTATIONS as ANNOTATIONS  # noqa: E402  (names of idle gaps)
@@ -233,13 +234,16 @@ def run_cell(args) -> int:
         setup_s = time.perf_counter() - _T0
         emit({"phase": "setup", "setup_s": setup_s, "wall_time": time.time(), **setup_report, **compiles.snapshot()})
 
+        # What the cell's studies can still serve, before the window takes
+        # from it: a window that comes near it is about to fail for being fast.
+        available = generator.requests_available()
         stats_before, hist_before = server.stats(), server.histograms()
         compiles_before = compiles.snapshot()
         done = threading.Event()
         tracer = None
         if args.trace:
             lead = TRACE_LEAD_SHARE * args.seconds
-            span = min(TRACE_SECONDS, max(0.1, args.seconds - 2 * lead))
+            span = min(float(traffic.get("trace_seconds", TRACE_SECONDS)), max(0.1, args.seconds - 2 * lead))
             tracer = threading.Thread(target=trace_window, args=(lead, span, done))
             tracer.start()
         try:
@@ -256,6 +260,7 @@ def run_cell(args) -> int:
             "seconds": args.seconds,
             "setup_s": setup_s,
             "attempted": len(records),
+            "requests_available": available,
             "failed": len(records) - len(good),
             "latencies_ms": [(r["t1"] - r["t0"]) * 1e3 for r in good],
             "completed_in_window": sum(r["t1"] <= window["t1"] for r in good),
@@ -269,7 +274,8 @@ def run_cell(args) -> int:
         evidence["batched_share_pct"] = (
             100.0 * evidence["stats_window"]["batched_suggests"] / len(records) if records else None
         )
-        emit({"phase": "window", "wall_time": time.time(), "requests": len(records), "failed": evidence["failed"],
+        emit({"phase": "window", "wall_time": time.time(), "requests": len(records),
+              "requests_available": available, "failed": evidence["failed"],
               "drained_after_window": sum(r["t1"] > window["t1"] for r in records),
               "failures": [f for r in records for f in r["failures"]][:10],
               "stats_window": {k: v for k, v in evidence["stats_window"].items() if v},

@@ -110,7 +110,9 @@ def test_the_idle_gaps_names_are_the_programs_stages():
 
     assert len(set(stages.STAGE_NAMES)) == len(stages.STAGE_NAMES)
     assert set(stages.STAGE_NAMES) == tracing.STAGES
-    assert stages.GAP_ANNOTATIONS == (*stages.STAGE_NAMES, "batch_executor.queue_wait", "client.complete")
+    assert stages.GAP_ANNOTATIONS == (
+        *stages.STAGE_NAMES, "batch_executor.queue_wait", "client.complete", "client.think")
+    assert stages.THINK == "client.think" and stages.THINK not in stages.STAGE_NAMES  # the harness's, not a stage
     assert stages.REQUEST_STAGE in stages.STAGE_NAMES
     assert run.ANNOTATIONS is stages.GAP_ANNOTATIONS  # what a traced run hands the reduction
 

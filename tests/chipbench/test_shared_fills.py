@@ -51,7 +51,7 @@ def test_a_fill_of_fifty_by_five_meets_sixteen_shapes():
 @pytest.mark.parametrize("shape", SHAPES, ids=lambda s: f"{s[0]}x{s[1]}")
 def test_set_up_meets_every_shape_inside_what_a_fill_can_hold(shape):
     pad = studies_lib.pad_power_of_two
-    completed, steps = fills.warm_plan(SHAPES, 50, 5, 1)[shape[0]]
+    completed, steps, _ = fills.warm_plan(SHAPES, 50, 5, 1)[shape[0]]
     assert pad(completed) == pad(completed + 1) == shape[0]  # one more completed trial trains in the same pad
     assert completed >= 20 or shape[0] < 32  # from the 32 pad on, that second train is a warm one
     reached = [r for r in steps if pad(r) == shape[1]]
