@@ -23,6 +23,7 @@ the service does not reach with the sparse switch at 512.
 from __future__ import annotations
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -135,8 +136,9 @@ def _lower_flush(monkeypatch, designer, flush_name: str, sharding):
     return key, jitted.lower(*_as_shapes(seen["args"], sharding))
 
 
-def _fits(compiled) -> None:
-    """The program's temporaries, arguments and outputs fit one v5e's HBM."""
+def _fits(compiled):
+    """The program's temporaries, arguments and outputs fit one v5e's HBM.
+    Returns the compiler's memory analysis."""
     mem = compiled.memory_analysis()
     total = (
         mem.temp_size_in_bytes
@@ -149,6 +151,36 @@ def _fits(compiled) -> None:
         f"{mem.output_size_in_bytes / 1e6:.1f} MB"
     )
     assert total < HBM_BYTES, mem
+    return mem
+
+
+def _batch_minor_copies(hlo_text: str, shape: str) -> list[str]:
+    """The ``copy`` instructions of ``shape`` (``f32[2,512,512]``) whose
+    result or operand is laid out with the leading axis minor — ``{0,...}``,
+    which on the TPU pads that axis to 128 lanes."""
+    typed = re.compile(r"(%[\w.\-]+) = " + re.escape(shape) + r"\{(\d)")
+    minor = dict(m.groups() for m in typed.finditer(hlo_text))
+    copy = re.compile(
+        r"(%[\w.\-]+) = " + re.escape(shape) + r"\{(\d)[^}]*\} copy\((%[\w.\-]+)\)"
+    )
+    return [
+        m.group(0)
+        for m in copy.finditer(hlo_text)
+        if m.group(2) == "0" or minor.get(m.group(3)) == "0"
+    ]
+
+
+def test_batch_minor_copies_are_recognised():
+    """The guard's reader on the two lines it has to tell apart: PR 33's
+    ``%copy.1090`` (restart axis made minor) and a plain transposing copy."""
+    text = (
+        "%gte.1 = f32[2,512,512]{2,1,0:T(8,128)} get-tuple-element(%p), index=3\n"
+        "%copy.1090 = f32[2,512,512]{0,2,1:T(8,128)} copy(%gte.1), metadata={}\n"
+        "%copy.1076 = f32[2,512,512]{2,1,0:T(8,128)S(1)} copy(%copy.1090)\n"
+        "%copy.1323 = f32[2,512,512]{1,2,0:T(8,128)S(1)} copy(%gte.1)\n"
+    )
+    found = _batch_minor_copies(text, "f32[2,512,512]")
+    assert [line.split(" ")[0] for line in found] == ["%copy.1090", "%copy.1076"]
 
 
 def test_exact_flush_pad512_compiles(monkeypatch, one_chip):
@@ -158,7 +190,10 @@ def test_exact_flush_pad512_compiles(monkeypatch, one_chip):
     )
     assert key.kind == "gp_ucb_pe" and key.pad_trials == 512
     assert designer._vec_opt.max_evaluations == 75_000
-    _fits(lowered.compile())
+    mem = _fits(lowered.compile())
+    # 1,299 MB while the NLL read diag(L) with ``jnp.diagonal`` (a batch-minor
+    # relayout of the [8, 5, 512, 512] factors), 255 MB since (PR 34).
+    assert mem.temp_size_in_bytes < 512 * 1024**2, mem
 
 
 def test_sparse_flush_pad1024_compiles(monkeypatch, one_chip):
@@ -201,25 +236,40 @@ def _gp_state_shapes(designer, n_pad: int, sharding):
     return _as_shapes(data, sharding), _as_shapes(states, sharding)
 
 
-def test_ard_train_1024_compiles(one_chip):
-    """``_train_gp``: 4 restarts × L-BFGS maxiter 50 at 1024×20, warm row."""
-    designer = _designer(1)
+def _lower_train(designer, n_pad: int, restarts: int, sharding):
+    """``_train_gp`` at ``n_pad`` rows: ``restarts`` random rows + the warm one."""
     model = designer._model
-    data, _ = _gp_state_shapes(designer, 1024, one_chip)
-    key = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=one_chip)
+    data, _ = _gp_state_shapes(designer, n_pad, sharding)
+    key = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=sharding)
     warm = _as_shapes(
         jax.eval_shape(
             lambda: model.param_collection().random_init_unconstrained(
                 jax.random.PRNGKey(0)
             )
         ),
-        one_chip,
+        sharding,
     )
+    return gp_bandit._train_gp.lower(
+        model, designer._ard, data, key, restarts, 1, warm
+    )
+
+
+def test_ard_train_1024_compiles(one_chip):
+    """``_train_gp``: 4 restarts × L-BFGS maxiter 50 at 1024×20, warm row."""
+    designer = _designer(1)
     assert (designer.ard_restarts, designer._ard.maxiter) == (4, 50)
-    lowered = gp_bandit._train_gp.lower(
-        model, designer._ard, data, key, designer.ard_restarts, 1, warm
-    )
-    _fits(lowered.compile())
+    _fits(_lower_train(designer, 1024, designer.ard_restarts, one_chip).compile())
+
+
+def test_warm_train_pad512_never_lays_its_factor_batch_minor(one_chip):
+    """``_train_gp`` as ``default20d.lone25`` runs it in every request —
+    pad 512, one restart + the warm row — reads ``diag(L)`` without copying
+    the ``[2, 512, 512]`` factor into a layout whose minor axis is the
+    restarts (``models.gp.cholesky_diagonal``; eight such 134 MB copies and
+    138.95 MB of temporaries with ``jnp.diagonal``, 2.74 MB without)."""
+    compiled = _lower_train(_designer(1), 512, 1, one_chip).compile()
+    assert _batch_minor_copies(compiled.as_text(), "f32[2,512,512]") == []
+    assert _fits(compiled).temp_size_in_bytes < 16 * 1024**2
 
 
 def test_sweep_75k_evaluations_compiles(one_chip):

@@ -628,7 +628,7 @@ def _suggest_set_pe(
             chol = jnp.linalg.cholesky(
                 cov + 1e-6 * jnp.eye(q, dtype=cov.dtype)
             )
-            logdet = 2.0 * jnp.sum(jnp.log(jnp.diagonal(chol)))
+            logdet = 2.0 * jnp.sum(jnp.log(gp_lib.cholesky_diagonal(chol)))
             logdet = jnp.where(jnp.isnan(logdet), -jnp.inf, logdet)
             mean_c, std_c = _mixture_predict(states_completed, query)  # [1, q]
             explore_ucb = (

@@ -61,6 +61,23 @@ POSTERIOR_PRECISION = jax.lax.Precision.HIGHEST
 NUGGET_TO_AMPLITUDE = 0.05
 
 
+def cholesky_diagonal(chol: Array) -> Array:
+    """diag(L) of a ``[..., n, n]`` factor, read as a masked row-sum.
+
+    Not ``jnp.diagonal``: under a ``vmap`` (the ARD restarts, a flush's
+    slots) that lowers on the TPU to a gather, and backward to a
+    scatter-add, which want the factor batch-minor: every ``[rows, n, n]``
+    factor is copied into a layout with ``rows`` padded to 128 lanes,
+    forward and backward, in every L-BFGS evaluation (at 2 x 512 x 512,
+    eight 134 MB copies: half of the warm train's loop; PERF.md, PR 34).
+    This is one fused pass over the factor the forward already holds, and
+    the same bits: a row's sum has one non-zero term, and the transpose of
+    the select is the select the scatter-add wrote.
+    """
+    eye = jnp.eye(chol.shape[-1], dtype=bool)
+    return jnp.sum(jnp.where(eye, chol, 0.0), axis=-1)
+
+
 def _conditioned(p: Params) -> Params:
     """``p`` with the nugget (``NUGGET_TO_AMPLITUDE``) in its noise."""
     p = dict(p)
@@ -253,7 +270,7 @@ class VizierGaussianProcess:
         # Padded rows: y = 0 and unit diag ⇒ zero contribution to each term.
         data_fit = 0.5 * jnp.dot(y, alpha)
         log_det = jnp.sum(
-            jnp.where(data.row_mask, jnp.log(jnp.diagonal(chol)), 0.0)
+            jnp.where(data.row_mask, jnp.log(cholesky_diagonal(chol)), 0.0)
         )
         nll = data_fit + log_det + 0.5 * n_valid * _LOG_2PI
         # (The priors are on the parameters, not on the noise with its nugget.)

@@ -229,7 +229,9 @@ class MultiTaskGaussianProcess:
         n_valid = jnp.sum(mask.astype(jnp.float32))
         nll = (
             0.5 * jnp.dot(y, alpha)
-            + jnp.sum(jnp.where(mask, jnp.log(jnp.diagonal(chol)), 0.0))
+            + jnp.sum(
+                jnp.where(mask, jnp.log(gp_lib.cholesky_diagonal(chol)), 0.0)
+            )
             + 0.5 * n_valid * _LOG_2PI
         )
         loss = nll + coll.regularization(p) + self._extra_regularization(p)

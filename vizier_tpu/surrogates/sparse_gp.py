@@ -249,7 +249,11 @@ class SparseGaussianProcess:
         y = data.labels
         n_valid = jnp.sum(data.row_mask.astype(y.dtype))
         log_det = n_valid * jnp.log(sigma2) + 2.0 * jnp.sum(
-            jnp.where(sdata.inducing_mask, jnp.log(jnp.diagonal(chol_b)), 0.0)
+            jnp.where(
+                sdata.inducing_mask,
+                jnp.log(gp_lib.cholesky_diagonal(chol_b)),
+                0.0,
+            )
         )
         quad = jnp.dot(y, y) / sigma2 - jnp.dot(c, c)
         amp2 = p["amplitude"] * p["amplitude"]
