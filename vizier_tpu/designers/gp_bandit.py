@@ -327,11 +327,15 @@ class VizierGPBandit(core_lib.Designer, core_lib.Predictor):
         # when somebody reads it (see the property).
         self._predictive = None
         self._unread_fit = None
-        # Production multi-chip path (SURVEY §2.10): when more than one
-        # device is visible, suggest() shards ARD restarts and acquisition
-        # pools over a mesh automatically — a user calling suggest() on a
-        # v5e-8 gets all 8 chips of work without any configuration.
+        # Multi-chip path (SURVEY §2.10): when more than one device is
+        # visible, suggest() shards the ARD restarts over a mesh of all of
+        # them and runs one full acquisition sweep a device, unasked. That
+        # is more work, not less time: on a 4-chip v5e host a lone 20-D
+        # suggest(25) takes about 1.3 times what it takes on one chip
+        # (PERF.md §5, `default20d-host4.lone25` beside `default20d.lone25`),
+        # and such a designer is never batched and never sparse.
         self._mesh = None
+        self._mesh_suggests = 0  # suggests whose sweeps ran on it (serving stats)
         if self.use_mesh is not None:
             want_mesh = self.use_mesh
         else:
@@ -421,6 +425,15 @@ class VizierGPBandit(core_lib.Designer, core_lib.Predictor):
 
     def _mesh_size(self) -> int:
         return len(self._mesh.devices.flat) if self._mesh is not None else 1
+
+    @property
+    def mesh_counts(self) -> dict:
+        """Suggests whose sweeps were launched on this designer's mesh (the
+        sparse surrogate's are not), and its width (0 without one)."""
+        return {
+            "suggests": self._mesh_suggests,
+            "devices": self._mesh_size() if self._mesh is not None else 0,
+        }
 
     def _train(
         self,
@@ -640,6 +653,7 @@ class VizierGPBandit(core_lib.Designer, core_lib.Predictor):
             )
         from vizier_tpu import parallel
 
+        self._mesh_suggests += 1  # a suggest of this class makes one sweep
         return parallel.maximize_acquisition_sharded(
             self._vec_opt, scoring, rng, count,
             self._mesh_size(), self._mesh, prior_features,
@@ -738,7 +752,7 @@ class VizierGPBandit(core_lib.Designer, core_lib.Predictor):
             # later op first synchronizes; the first call per process is
             # recorded as compile, the rest as steady-state execute.
             with jax_timing.device_phase(
-                "gp_bandit.train_gp", stage="train"
+                "gp_bandit.train_gp", stage="train", devices=self._mesh_size()
             ) as phase:
                 states = self._train(
                     data,
@@ -779,6 +793,7 @@ class VizierGPBandit(core_lib.Designer, core_lib.Predictor):
             vec = vectorized_lib.VectorizedOptimizer(
                 strategy, max_evaluations=self.max_acquisition_evaluations
             )
+            self._mesh_suggests += self._mesh is not None
             result = _maximize_q_batch(
                 vec,
                 states,
@@ -808,7 +823,7 @@ class VizierGPBandit(core_lib.Designer, core_lib.Predictor):
         prior = self._prior_features(data)
         with profiler.timeit("acquisition_optimizer"):
             with jax_timing.device_phase(
-                "gp_bandit.acquisition", stage="acquire"
+                "gp_bandit.acquisition", stage="acquire", devices=self._mesh_size()
             ) as phase:
                 result = self._maximize(scoring, self._next_rng(), count, prior)
                 jax.block_until_ready(result.scores)

@@ -1276,6 +1276,7 @@ class VizierGPUCBPEBandit(gp_bandit.VizierGPBandit):
         if getattr(self, "_priors", None):
             return self._suggest_with_priors(count)
 
+        self._mesh_suggests += self._mesh is not None
         tracer = tracing_lib.get_tracer()
         # What the host does before the device can start: the completed
         # trials' encode (skipped when the fit is cached) and everything of
@@ -1304,6 +1305,7 @@ class VizierGPUCBPEBandit(gp_bandit.VizierGPBandit):
             with jax_timing.device_phase(
                 "sparse_gp.ucb_pe_train_gp" if sparse_mode else "gp_ucb_pe.train_gp",
                 stage="train",
+                devices=self._mesh_size(),
             ) as phase:
                 states_me, datas = self._train_states_me(datas)
                 phase.block(states_me)
@@ -1354,6 +1356,7 @@ class VizierGPUCBPEBandit(gp_bandit.VizierGPBandit):
             if is_sparse
             else "gp_ucb_pe.acquisition",
             stage="acquire",
+            devices=self._mesh_size(),
         ):
             if self.acquisition_budget_policy == "first_pick_full" and count > 1:
                 # Full budget on the exploitation-critical first pick; one

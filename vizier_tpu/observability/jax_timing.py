@@ -14,9 +14,10 @@ spends blocked on the chip is attributed to the right phase:
 The span's attributes say which phase it was: ``phase`` (the name given
 here — for a flush program, its ``DesignerProgram.device_phase``), ``stage``
 (``train`` / ``acquire`` on the sequential path), ``path`` (``sequential`` /
-``fused``), ``per`` (``flush`` for a fused flush's one wait), ``first_call``
-and ``mode`` — the first occurrence of a phase name in the process is
-``mode="compile"`` (trace + lower + compile dominates it), later ones
+``fused``), ``per`` (``flush`` for a fused flush's one wait), ``devices``
+(the width of the designer mesh the phase's programs were partitioned over;
+1 without one), ``first_call`` and ``mode`` — the first occurrence of a
+phase name in the process is ``mode="compile"`` (trace + lower + compile dominates it), later ones
 ``mode="execute"``, the steady-state serving number. Like every stage span
 it is observed into ``vizier_suggest_stage_seconds{stage="device.wait",...}``
 in the serving runtime's registry and annotates the ``jax.profiler`` trace.
@@ -94,7 +95,9 @@ _DISABLED_PHASE = _Phase("", enabled=False, first_call=False)
 class _PhaseCM:
     __slots__ = ("_phase", "_span_cm")
 
-    def __init__(self, phase: _Phase, path: str, per: str, stage: Optional[str]):
+    def __init__(
+        self, phase: _Phase, path: str, per: str, stage: Optional[str], devices: int
+    ):
         self._phase = phase
         attributes = {"stage": stage} if stage else {}
         self._span_cm = tracing_lib.get_tracer().span(
@@ -102,6 +105,7 @@ class _PhaseCM:
             phase=phase.name,
             path=path,
             per=per,
+            devices=devices,
             first_call=phase.first_call,
             mode="compile" if phase.first_call else "execute",
             **attributes,
@@ -134,9 +138,10 @@ def device_phase(
     path: str = tracing_lib.PATH_SEQUENTIAL,
     per: str = tracing_lib.PER_REQUEST,
     stage: Optional[str] = None,
+    devices: int = 1,
 ):
     """Times one device phase (see module docstring for the contract)."""
     if not _jax_profiling_on():
         return _DISABLED_CM
     phase = _Phase(name, enabled=True, first_call=_mark_seen(name))
-    return _PhaseCM(phase, path, per, stage)
+    return _PhaseCM(phase, path, per, stage, devices)

@@ -11,8 +11,14 @@ path shard across a ``jax.sharding.Mesh``:
 
 All three are batch axes of already-vmapped jitted programs, so sharding is
 pure ``NamedSharding`` annotation — XLA partitions the programs and inserts
-any collectives over ICI. Gradients/Cholesky stay device-local: zero
-communication inside the hot loops, one gather at the end.
+any collectives over ICI. Gradients/Cholesky stay device-local. What the
+partitioner puts in, compiled for a 4-chip v5e at 512 rows (PERF.md §5,
+PR 35): one scalar all-reduce in the *condition* of the L-BFGS ``while`` and
+one in its line search's (the vmapped loops go on while any restart on any
+device does, so every iteration of both is a rendezvous of the mesh and the
+train lasts as long as its slowest restart), then one gather of the losses
+and of the best member; the sweep's ``while`` holds none, and each pick's
+top-k merge is two small all-reduces.
 """
 
 from __future__ import annotations
@@ -132,7 +138,8 @@ def train_gp_sharded(
 
     ``num_restarts`` should be a multiple of the mesh size. Data is
     replicated (it is small); each device runs its restarts locally; the
-    final top-k selection is the only cross-device reduction. ``warm_start``
+    loops' conditions and the final top-k selection are the cross-device
+    reductions (module docstring). ``warm_start``
     replaces the first restart here — unlike ``gp_bandit._train_gp``, which
     prepends it as an extra row — because appending would break the
     restarts-divisible-by-mesh sharding; at mesh-scale restart budgets the

@@ -127,6 +127,7 @@ class CachedDesignerStatePolicy(policy_lib.Policy):
         before = self._train_counts(designer)
         surrogate_before = self._surrogate_counts(designer)
         rows_before = self._row_counts(designer)
+        mesh_before = self._mesh_counts(designer)
         with tracer.span(
             "designer.update",
             designer=type(designer).__name__,
@@ -166,6 +167,7 @@ class CachedDesignerStatePolicy(policy_lib.Policy):
             surrogate_before, self._surrogate_counts(designer)
         )
         self._account_rows(rows_before, self._row_counts(designer))
+        self._account_mesh(mesh_before, self._mesh_counts(designer))
         # Mirror the trained unconstrained ARD params into the entry: the
         # stats/inspection surface for "what would seed the next train",
         # and the hand-off if the designer is ever rebuilt around them.
@@ -201,6 +203,25 @@ class CachedDesignerStatePolicy(policy_lib.Policy):
     def _row_counts(designer: Any) -> Optional[dict]:
         counts = getattr(designer, "encoded_row_counts", None)
         return dict(counts) if counts is not None else None
+
+    @staticmethod
+    def _mesh_counts(designer: Any) -> Optional[dict]:
+        counts = getattr(designer, "mesh_counts", None)
+        return dict(counts) if counts is not None else None
+
+    def _account_mesh(self, before: Optional[dict], after: Optional[dict]) -> None:
+        """Counts the suggests the designer ran on its mesh, and says on how
+        many devices on the span that encloses the computation
+        (``pythia.suggest_compute``; ``pythia.suggest`` without coalescing)."""
+        if before is None or after is None:
+            return
+        ran = after["suggests"] - before["suggests"]
+        if ran <= 0:
+            return
+        self._runtime.note_mesh_suggests(ran, after["devices"])
+        span = tracing_lib.get_tracer().current_span()
+        if span is not None:
+            span.set_attribute("devices", after["devices"])
 
     def _account_rows(self, before: Optional[dict], after: Optional[dict]) -> None:
         if before is None or after is None:

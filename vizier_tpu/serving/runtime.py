@@ -103,6 +103,12 @@ class ServingRuntime:
             "vizier_study_turn_seconds",
             help="Time a SuggestTrials held its study's turn (claim to write).",
         )
+        # The width of the designers' whole-host mesh, as the last GP
+        # suggest served on one reported it (serving.policy); 0 until then.
+        self._mesh_devices = self.metrics.gauge(
+            "vizier_serving_mesh_devices",
+            help="Devices of the designer mesh GP suggests run on (0: none has).",
+        )
         # Multi-tenant overload protection (vizier_tpu.serving.admission):
         # bounded in-flight admission + deadline-aware shedding + the
         # healthy→shedding→degraded state machine at the Pythia dispatch
@@ -351,11 +357,18 @@ class ServingRuntime:
         if self.speculative_engine is not None:
             self.speculative_engine.invalidate(study_name, reason=reason)
 
+    def note_mesh_suggests(self, suggests: int, devices: int) -> None:
+        """``suggests`` GP suggests ran on a designer mesh of ``devices``."""
+        self.stats.increment("mesh_suggests", suggests)
+        self._mesh_devices.set(devices)
+
     def snapshot(self) -> Dict[str, int]:
-        """All counters plus the current cache/breaker population."""
+        """All counters plus the current cache/breaker population and the
+        width of the designer mesh (0 when no suggest has run on one)."""
         out = self.stats.snapshot()
         out["cached_studies"] = len(self.designer_cache)
         out["open_breakers"] = self.breakers.open_count()
+        out["mesh_devices"] = int(self._mesh_devices.value())
         return out
 
     def admission_snapshot(self) -> Dict[str, Any]:

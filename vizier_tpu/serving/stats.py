@@ -71,6 +71,10 @@ class ServingStats:
         "batch_fallbacks",  # slots rerun sequentially after a batch failure
         "batch_slot_errors",  # slot-isolated prepare/finalize/NaN failures
         "mesh_flushes",  # flushes executed on a mesh placement worker
+        # The designers' whole-host mesh (designers/gp_bandit.py: built
+        # unasked where more than one device is visible). The width of that
+        # mesh is ``mesh_devices`` in the runtime's snapshot.
+        "mesh_suggests",  # GP suggests whose device programs ran on it
         # Scalable surrogates (vizier_tpu.surrogates).
         "sparse_suggests",  # suggests served by the sparse-GP posterior
         "surrogate_crossovers",  # exact<->sparse auto-switch transitions
