@@ -170,6 +170,59 @@ def _batch_minor_copies(hlo_text: str, shape: str) -> list[str]:
     ]
 
 
+def _custom_calls_by_while(hlo_text: str, target: str) -> dict[str, int]:
+    """For every ``while`` of the compiled text whose body holds (itself or
+    in a computation it calls) a custom-call to ``target``: how many."""
+    computations: dict[str, list[str]] = {}
+    current = None
+    for line in hlo_text.splitlines():
+        head = re.match(r"^(?:ENTRY )?(%[\w.\-]+) \(.*\{\s*$", line)
+        if head:
+            current = computations.setdefault(head.group(1), [])
+        elif line.startswith("}"):
+            current = None
+        elif current is not None:
+            current.append(line)
+    called = re.compile(
+        r"(?:body|condition|calls|to_apply|branch_computations)=\{?(%[\w.\-]+)"
+    )
+    totals: dict[str, int] = {}
+
+    def total(name: str) -> int:
+        if name not in totals:
+            lines = computations.get(name, [])
+            totals[name] = sum(
+                f'custom_call_target="{target}"' in line for line in lines
+            ) + sum(total(callee) for callee in set(called.findall("\n".join(lines))))
+        return totals[name]
+
+    whiles = re.compile(r"(%[\w.\-]+) = .* while\(.*body=(%[\w.\-]+)")
+    found = {
+        m.group(1): total(m.group(2))
+        for lines in computations.values()
+        for m in map(whiles.search, lines)
+        if m
+    }
+    return {name: count for name, count in found.items() if count}
+
+
+def test_custom_calls_are_counted_by_while():
+    text = (
+        "%inner (p: f32[4]) -> f32[4] {\n"
+        '  %c.1 = f32[4] custom-call(%p), custom_call_target="Cholesky"\n'
+        "}\n"
+        "%outer (p: f32[4]) -> f32[4] {\n"
+        '  %c.2 = f32[4] custom-call(%p), custom_call_target="Cholesky"\n'
+        "  %while.2 = f32[4] while(%c.2), condition=%cond, body=%inner\n"
+        "}\n"
+        "ENTRY %main (p: f32[4]) -> f32[4] {\n"
+        '  %c.3 = f32[4] custom-call(%p), custom_call_target="Cholesky"\n'
+        "  %while.1 = f32[4] while(%c.3), condition=%cond, body=%outer\n"
+        "}\n"
+    )
+    assert _custom_calls_by_while(text, "Cholesky") == {"%while.2": 1, "%while.1": 2}
+
+
 def test_batch_minor_copies_are_recognised():
     """The guard's reader on the two lines it has to tell apart: PR 33's
     ``%copy.1090`` (restart axis made minor) and a plain transposing copy."""
@@ -192,7 +245,10 @@ def test_exact_flush_pad512_compiles(monkeypatch, one_chip):
     assert designer._vec_opt.max_evaluations == 75_000
     mem = _fits(lowered.compile())
     # 1,299 MB while the NLL read diag(L) with ``jnp.diagonal`` (a batch-minor
-    # relayout of the [8, 5, 512, 512] factors), 255 MB since (PR 34).
+    # relayout of the [8, 5, 512, 512] factors), 255 MB since (PR 34), 111 MB
+    # since the L-BFGS step takes the accepted point's gradient from the
+    # search's evaluation (PR 36: the factors it carries, 1.1 MB a row, are
+    # less than the second forward pass's temporaries were).
     assert mem.temp_size_in_bytes < 512 * 1024**2, mem
 
 
@@ -266,10 +322,20 @@ def test_warm_train_pad512_never_lays_its_factor_batch_minor(one_chip):
     pad 512, one restart + the warm row — reads ``diag(L)`` without copying
     the ``[2, 512, 512]`` factor into a layout whose minor axis is the
     restarts (``models.gp.cholesky_diagonal``; eight such 134 MB copies and
-    138.95 MB of temporaries with ``jnp.diagonal``, 2.74 MB without)."""
+    138.95 MB of temporaries with ``jnp.diagonal``, 2.74 MB without, 2.65 MB
+    with the factor in the line search's carry, PR 36).
+
+    And an L-BFGS iteration factorises once outside its line search: the
+    evaluation at ``t0``. A factorisation at pad 512 is four ``Cholesky``
+    custom-calls (128-wide blocks), so the line search's ``while`` holds
+    four and the L-BFGS ``while`` around it eight; the parent's held twelve,
+    four of them ``value_and_grad`` at the point the search had just
+    evaluated."""
     compiled = _lower_train(_designer(1), 512, 1, one_chip).compile()
-    assert _batch_minor_copies(compiled.as_text(), "f32[2,512,512]") == []
+    text = compiled.as_text()
+    assert _batch_minor_copies(text, "f32[2,512,512]") == []
     assert _fits(compiled).temp_size_in_bytes < 16 * 1024**2
+    assert sorted(_custom_calls_by_while(text, "Cholesky").values()) == [4, 8]
 
 
 def test_sweep_75k_evaluations_compiles(one_chip):

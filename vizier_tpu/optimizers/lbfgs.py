@@ -63,6 +63,23 @@ class Optimizer(Protocol):
         ...
 
 
+def _keep_factorisations(prim, *_, **__) -> bool:
+    """The ``jax.checkpoint`` policy of a line-search evaluation: of a forward
+    pass keep the factors and the triangular solves, recompute the rest.
+
+    The search evaluates the loss at every trial point and the accepted
+    one's gradient is then taken from that evaluation (``lbfgs_minimize``),
+    so what the backward pass needs rides the search's loop carry. All of
+    it would be every intermediate of the loss (an exact GP's NLL at
+    512 x 20-D: 38.6 MB a row, a ``[512, 512, 20]`` tensor the fused Gram
+    never writes among them); the factor and its two solves are 1.1 MB, and
+    what the backward pass recomputes from them is the Gram, one fused pass.
+    A loss without a factorisation keeps nothing and its backward pass
+    recomputes the forward one, which is what ``value_and_grad`` cost.
+    """
+    return prim.name in ("cholesky", "triangular_solve")
+
+
 class _LbfgsState(NamedTuple):
     x: Array  # [n] current point
     f: Array  # scalar loss
@@ -112,32 +129,23 @@ def _two_loop_direction(state: _LbfgsState, memory: int) -> Array:
     return jax.lax.fori_loop(0, memory, fwd, r)
 
 
-def lbfgs_minimize(
+def _lbfgs_loop(
     loss_fn: Callable[[Array], Array],
     x0: Array,
     *,
-    maxiter: int = 50,
-    memory: int = 10,
-    max_linesearch_steps: int = 20,
-    gtol: float = 1e-5,
-    ftol: float = 1e-6,
-    ftol_patience: int = 2,
-    armijo_c1: float = 1e-4,
-) -> Tuple[Array, Array]:
-    """Minimizes a flat-vector loss; returns (x, f(x)). jit/vmap-safe.
+    maxiter: int,
+    memory: int,
+    max_linesearch_steps: int,
+    gtol: float,
+    ftol: float,
+    ftol_patience: int,
+    armijo_c1: float,
+):
+    """``(init, cond, step)`` of ``lbfgs_minimize``'s ``while_loop``.
 
-    ``ftol`` is a scipy-style relative-decrease stop: once ``ftol_patience``
-    CONSECUTIVE accepted steps each improve the loss by less than
-    ``ftol * max(|f|, 1)`` the run is converged (``ftol <= 0`` disables).
-    The patience matters: a single small decrease can come from a step
-    capped by the line-search warm start rather than a true plateau, and
-    stopping there returns a bad optimum on ill-scaled problems. Without
-    any ftol stop every restart burns the full ``maxiter`` budget — at
-    1000 trials each iteration is a padded-1024 Cholesky, and the ARD loss
-    plateaus ~25-40% before the budget (measured on the bench problem).
+    ``step`` returns the next state and the halvings its line search made.
     """
-    value_and_grad = jax.value_and_grad(loss_fn)
-    f0, g0 = value_and_grad(x0)
+    f0, g0 = jax.value_and_grad(loss_fn)(x0)
     n = x0.shape[0]
     init = _LbfgsState(
         x=x0,
@@ -151,11 +159,21 @@ def lbfgs_minimize(
         t_init=jnp.asarray(1.0, x0.dtype),
         small_count=jnp.asarray(0, jnp.int32),
     )
+    kept_loss = jax.checkpoint(loss_fn, policy=_keep_factorisations, prevent_cse=False)
+
+    def evaluate(x: Array):
+        """``loss_fn(x)`` and its pullback, flattened: the residual arrays (a
+        valid ``while_loop`` carry, which the pullback itself is not: its
+        treedef holds a jaxpr, and two traces' compare unequal) and the
+        treedef that makes any such list a pullback again."""
+        f, pullback = jax.vjp(kept_loss, x)
+        residuals, treedef = jax.tree_util.tree_flatten(pullback)
+        return f, residuals, treedef
 
     def cond(state: _LbfgsState) -> Array:
         return (state.k < maxiter) & ~state.done
 
-    def step(state: _LbfgsState) -> _LbfgsState:
+    def step(state: _LbfgsState) -> Tuple[_LbfgsState, Array]:
         d = -_two_loop_direction(state, memory)
         # Fall back to steepest descent if d is not a descent direction.
         gd = jnp.dot(state.g, d)
@@ -163,21 +181,43 @@ def lbfgs_minimize(
         d = jnp.where(bad, -state.g, d)
         gd = jnp.where(bad, -jnp.dot(state.g, state.g), gd)
 
-        # Armijo backtracking: t <- t/2 until sufficient decrease.
+        # Armijo backtracking: t <- t/2 until sufficient decrease. Each
+        # trial is a full evaluation (for an ARD loss a Cholesky), so an
+        # iteration makes only those whose results it keeps.
+        # Under a ``vmap`` over restarts the outer ``while_loop`` runs this
+        # step on EVERY row until the last row's ``cond`` is false and
+        # selects the old state for the rows that are done, and the search
+        # is a second batched ``while_loop`` that runs until every row's
+        # ``ls_cond`` is false. A finished row's state is frozen: it would
+        # take the same direction from the same point and fail Armijo the
+        # same number of times in every later iteration, with the live rows
+        # waiting for it (44-71 % of a warm train's halvings were such
+        # replays; PERF.md, PR 36). So ``ls_cond`` reads ``live``: a
+        # finished row's evaluation at ``t0`` still runs in lockstep, as it
+        # must, and its halvings do not.
+        live = cond(state)
+
         def ls_cond(carry):
-            t, f_new, i = carry
+            t, f_new, _, i = carry
             insufficient = f_new > state.f + armijo_c1 * t * gd
-            return (insufficient | ~jnp.isfinite(f_new)) & (i < max_linesearch_steps)
+            return (
+                live
+                & (insufficient | ~jnp.isfinite(f_new))
+                & (i < max_linesearch_steps)
+            )
 
         def ls_body(carry):
-            t, _, i = carry
+            t, _, _, i = carry
             t = t * 0.5
-            return t, loss_fn(state.x + t * d), i + 1
+            f_new, residuals, _ = evaluate(state.x + t * d)
+            return t, f_new, residuals, i + 1
 
         # Warm-started line search: restarting at t=1 every iteration costs
-        # ~6-8 halvings per iteration on ill-scaled ARD losses — each one a
-        # full Cholesky (measured 291-386 line-search evals per restart on
-        # the 1000x20d bench problem; the warm start cuts them to ~1-2).
+        # ~6-8 halvings per iteration on ill-scaled ARD losses (measured
+        # 291-386 line-search evals per restart on the 1000x20d bench
+        # problem); started at 4x the last accepted step, the live rows of a
+        # warm train make 0.7-1.6 an iteration (counted on the CPU at
+        # 512 x 20-D; PERF.md, PR 36).
         # When the warm-started t0 is accepted WITHOUT halving, larger steps
         # may have been available, so the next iteration resets to a full
         # step — otherwise a capped step cascade can stall ill-conditioned
@@ -185,13 +225,19 @@ def lbfgs_minimize(
         # No trial point lies further than MAX_STEP from the current one in
         # any coordinate.
         t0 = jnp.minimum(state.t_init, MAX_STEP / jnp.maximum(jnp.max(jnp.abs(d)), 1e-30))
-        t, f_new, num_halvings = jax.lax.while_loop(
-            ls_cond, ls_body, (t0, loss_fn(state.x + t0 * d), jnp.asarray(0))
+        f_t0, residuals_t0, pullback_tree = evaluate(state.x + t0 * d)
+        t, f_new, residuals, num_halvings = jax.lax.while_loop(
+            ls_cond, ls_body, (t0, f_t0, residuals_t0, jnp.asarray(0))
         )
         accepted = jnp.isfinite(f_new) & (f_new <= state.f)
         x_new = jnp.where(accepted, state.x + t * d, state.x)
+        # The accepted point was evaluated in the search: its gradient is
+        # that evaluation's backward pass, not a second forward one.
+        (g_trial,) = jax.tree_util.tree_unflatten(pullback_tree, residuals)(
+            jnp.ones_like(f_new)
+        )
         f_new = jnp.where(accepted, f_new, state.f)
-        g_new = jnp.where(accepted, value_and_grad(x_new)[1], state.g)
+        g_new = jnp.where(accepted, g_trial, state.g)
 
         s = x_new - state.x
         y = g_new - state.g
@@ -221,7 +267,7 @@ def lbfgs_minimize(
             jnp.asarray(1.0, state.x.dtype),
             jnp.minimum(jnp.asarray(1.0, state.x.dtype), t * 4.0),
         )
-        return _LbfgsState(
+        new_state = _LbfgsState(
             x=x_new,
             f=f_new,
             g=g_new,
@@ -233,8 +279,47 @@ def lbfgs_minimize(
             t_init=t_init_next,
             small_count=small_count,
         )
+        return new_state, num_halvings
 
-    final = jax.lax.while_loop(cond, step, init)
+    return init, cond, step
+
+
+def lbfgs_minimize(
+    loss_fn: Callable[[Array], Array],
+    x0: Array,
+    *,
+    maxiter: int = 50,
+    memory: int = 10,
+    max_linesearch_steps: int = 20,
+    gtol: float = 1e-5,
+    ftol: float = 1e-6,
+    ftol_patience: int = 2,
+    armijo_c1: float = 1e-4,
+) -> Tuple[Array, Array]:
+    """Minimizes a flat-vector loss; returns (x, f(x)). jit/vmap-safe.
+
+    ``ftol`` is a scipy-style relative-decrease stop: once ``ftol_patience``
+    CONSECUTIVE accepted steps each improve the loss by less than
+    ``ftol * max(|f|, 1)`` the run is converged (``ftol <= 0`` disables).
+    The patience matters: a single small decrease can come from a step
+    capped by the line-search warm start rather than a true plateau, and
+    stopping there returns a bad optimum on ill-scaled problems. Without
+    any ftol stop every restart burns the full ``maxiter`` budget — at
+    1000 trials each iteration is a padded-1024 Cholesky, and the ARD loss
+    plateaus ~25-40% before the budget (measured on the bench problem).
+    """
+    init, cond, step = _lbfgs_loop(
+        loss_fn,
+        x0,
+        maxiter=maxiter,
+        memory=memory,
+        max_linesearch_steps=max_linesearch_steps,
+        gtol=gtol,
+        ftol=ftol,
+        ftol_patience=ftol_patience,
+        armijo_c1=armijo_c1,
+    )
+    final = jax.lax.while_loop(cond, lambda state: step(state)[0], init)
     return final.x, final.f
 
 
