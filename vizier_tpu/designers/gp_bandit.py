@@ -363,7 +363,7 @@ class VizierGPBandit(core_lib.Designer, core_lib.Predictor):
         # random placeholder above) — gates the reduced warm restart budget
         # and the warm/cold accounting below.
         self._warm_is_trained = False
-        self._ard_train_counts = {"warm": 0, "cold": 0}
+        self._ard_train_counts = {"warm": 0, "cold": 0, "cached": 0}
         # Sparse-surrogate auto-switch state (vizier_tpu.surrogates): the
         # mode is sticky (hysteresis) and a crossover drops all warm/
         # posterior state so neither surrogate ever trains from the

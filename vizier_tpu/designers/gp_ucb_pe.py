@@ -1291,6 +1291,7 @@ class VizierGPUCBPEBandit(gp_bandit.VizierGPBandit):
             if self._cached_states is None:
                 datas = self._encode_datas()
             else:
+                self._ard_train_counts["cached"] += 1  # this suggest trains nothing
                 datas = self._cached_states[1]
             all_data = self._all_points_data(count)
             labels_mn, labels_mask, ref_point, prior_feats = self._sweep_inputs(

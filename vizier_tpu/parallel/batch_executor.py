@@ -917,6 +917,7 @@ class BatchExecutor:
                 # No batchmates and never prepared: hand back the plain
                 # sequential path, bit-identical to batching off (and no
                 # vmap overhead). The waiter runs it on its own thread.
+                self._increment("lone_handbacks")
                 slots[0].action = "sequential"
                 slots[0].event.set()
                 return
@@ -946,6 +947,7 @@ class BatchExecutor:
             live.append(slot)
         if not live:
             return
+        self._increment("lone_flushes", len(live) == 1)
         # A lone prepare survivor still goes through the batched program:
         # its RNG draws already happened in batch order, and pad_partial
         # keeps the compiled shape identical either way.

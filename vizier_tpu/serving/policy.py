@@ -257,3 +257,6 @@ class CachedDesignerStatePolicy(policy_lib.Policy):
             stats.increment("warm_trains", warm)
         if cold > 0:
             stats.increment("cold_trains", cold)
+        cached = after.get("cached", 0) - before.get("cached", 0)
+        if cached > 0:
+            stats.increment("cached_fit_suggests", cached)

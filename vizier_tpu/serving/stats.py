@@ -38,6 +38,9 @@ class ServingStats:
         "coalesced_computations",  # leader runs that had >= 1 follower
         "warm_trains",
         "cold_trains",
+        # A suggest that found its study's fit cached (no completion since
+        # the last train) and skipped the train: sequential and unbatchable.
+        "cached_fit_suggests",
         # The policy's delta trial read (serving.policy): reused / (reused +
         # fetched) is the share of a study a suggest did not re-read.
         "trials_fetched",  # trial protos converted to pyvizier for an update
@@ -67,6 +70,11 @@ class ServingStats:
         "admission_transitions",  # overload state-machine transitions
         # Cross-study batching (vizier_tpu.parallel.batch_executor).
         "batch_flushes",  # bucket flushes (full / timeout / drain)
+        # Of those, the flushes that held one real slot: handed back to the
+        # sequential path unprepared, or run through the fused program with
+        # every other slot a padded copy.
+        "lone_handbacks",
+        "lone_flushes",
         "batched_suggests",  # slots served from a shared vmapped program
         "batch_fallbacks",  # slots rerun sequentially after a batch failure
         "batch_slot_errors",  # slot-isolated prepare/finalize/NaN failures
