@@ -170,9 +170,9 @@ def _batch_minor_copies(hlo_text: str, shape: str) -> list[str]:
     ]
 
 
-def _custom_calls_by_while(hlo_text: str, target: str) -> dict[str, int]:
-    """For every ``while`` of the compiled text whose body holds (itself or
-    in a computation it calls) a custom-call to ``target``: how many."""
+def _computations(hlo_text: str) -> dict[str, list[str]]:
+    """The compiled text's computations by name: each one's instruction
+    lines."""
     computations: dict[str, list[str]] = {}
     current = None
     for line in hlo_text.splitlines():
@@ -183,6 +183,13 @@ def _custom_calls_by_while(hlo_text: str, target: str) -> dict[str, int]:
             current = None
         elif current is not None:
             current.append(line)
+    return computations
+
+
+def _custom_calls_by_while(hlo_text: str, target: str) -> dict[str, int]:
+    """For every ``while`` of the compiled text whose body holds (itself or
+    in a computation it calls) a custom-call to ``target``: how many."""
+    computations = _computations(hlo_text)
     called = re.compile(
         r"(?:body|condition|calls|to_apply|branch_computations)=\{?(%[\w.\-]+)"
     )
@@ -206,6 +213,31 @@ def _custom_calls_by_while(hlo_text: str, target: str) -> dict[str, int]:
     return {name: count for name, count in found.items() if count}
 
 
+_LAUNCHED = ("fusion", "copy", "sort", "custom-call")
+
+
+def _launched_by_while(hlo_text: str) -> dict[str, list[str]]:
+    """For every ``while`` of the compiled text: the instructions its body
+    launches itself, one device operation each — fusions, copies, sorts and
+    custom-calls. What a fusion holds is inside its one launch, and what a
+    nested ``while`` launches is counted under that ``while``."""
+    computations = _computations(hlo_text)
+    launched = re.compile(
+        r"^\s*(?:ROOT )?%[\w.\-]+ = .*? (" + "|".join(_LAUNCHED) + r")\("
+    )
+    whiles = re.compile(r"(%[\w.\-]+) = .* while\(.*body=(%[\w.\-]+)")
+    return {
+        m.group(1): [
+            line.strip()
+            for line in computations.get(m.group(2), [])
+            if launched.match(line)
+        ]
+        for lines in computations.values()
+        for m in map(whiles.search, lines)
+        if m
+    }
+
+
 def test_custom_calls_are_counted_by_while():
     text = (
         "%inner (p: f32[4]) -> f32[4] {\n"
@@ -221,6 +253,37 @@ def test_custom_calls_are_counted_by_while():
         "}\n"
     )
     assert _custom_calls_by_while(text, "Cholesky") == {"%while.2": 1, "%while.1": 2}
+
+
+def test_launched_operations_are_counted_by_while():
+    """A body's own fusions, copies, sorts and custom-calls, one each; what
+    is not launched (a bitcast, a get-tuple-element, the nested ``while``
+    itself) is not counted, and a nested body counts under its own name."""
+    text = (
+        "%fused_computation.1 (p: f32[4]) -> f32[4] {\n"
+        "  %copy.9 = f32[4] copy(%p)\n"
+        "}\n"
+        "%inner (p: f32[4]) -> f32[4] {\n"
+        "  %gte.1 = f32[4] get-tuple-element(%p), index=0\n"
+        "  %sort.1 = (f32[4], s32[4]) sort(%gte.1, %iota), dimensions={0}\n"
+        "  ROOT %fusion.2 = f32[4] fusion(%sort.1), kind=kLoop, calls=%fused_computation.1\n"
+        "}\n"
+        "%outer (p: f32[4]) -> f32[4] {\n"
+        "  %bitcast.1 = f32[4] bitcast(%p)\n"
+        "  %copy.1 = f32[4] copy(%bitcast.1)\n"
+        '  %c.2 = f32[4] custom-call(%copy.1), custom_call_target="Cholesky"\n'
+        "  %while.2 = f32[4] while(%c.2), condition=%cond, body=%inner\n"
+        "}\n"
+        "ENTRY %main (p: f32[4]) -> f32[4] {\n"
+        "  %fusion.1 = f32[4] fusion(%p), kind=kLoop, calls=%fused_computation.1\n"
+        "  %while.1 = f32[4] while(%fusion.1), condition=%cond, body=%outer\n"
+        "}\n"
+    )
+    found = {
+        name: [re.match(r"(?:ROOT )?(%[\w.\-]+)", line).group(1) for line in lines]
+        for name, lines in _launched_by_while(text).items()
+    }
+    assert found == {"%while.2": ["%sort.1", "%fusion.2"], "%while.1": ["%copy.1", "%c.2"]}
 
 
 def test_batch_minor_copies_are_recognised():
@@ -403,3 +466,61 @@ def test_sequential_crossings_compile(one_chip):
     _fits(gp_ucb_pe._sweep_inputs.lower((data,)).compile())
     _fits(gp_ucb_pe._stack_fits.lower((states,)).compile())
     _fits(gp_ucb_pe._append_first_pick.lower(data, picked).compile())
+
+
+def _lower_suggest_batch(designer, n_pad: int, count: int, sharding):
+    """``_suggest_batch`` as the sequential path calls it on the exact GP:
+    one metric, one member, the trained and the all-points data at
+    ``n_pad`` rows, trust region on, no mesh."""
+    data, states = _gp_state_shapes(designer, n_pad, sharding)
+    states_me = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct((1,) + a.shape, a.dtype, sharding=sharding),
+        states,
+    )
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=sharding)
+
+    prior = kernels.MixedFeatures(
+        shape((10, DIM), jnp.float32), shape((10, 0), jnp.int32)
+    )
+    return gp_ucb_pe._suggest_batch.lower(
+        designer._model, designer._vec_opt, states_me, data,
+        shape((1, n_pad), jnp.float32), shape((n_pad,), bool),
+        shape((1,), jnp.float32), prior, shape((2,), jnp.uint32),
+        shape((), bool), shape((), bool), count, designer.config, True, None, None,
+    )
+
+
+@pytest.mark.parametrize("count", [1, 24])
+def test_eagle_loop_body_launches_what_it_needs(one_chip, count):
+    """An iteration of the 75,000-evaluation sweep is bound on the chip by
+    how many operations its body launches (32 us for a floor of ~3; PERF.md,
+    PR 38), so the count is held: ``_suggest_batch`` at pad 512 x 20-D as
+    ``default20d.lone25`` runs it (``count`` 1: the first pick's sweep;
+    ``count`` 24: the loop over the other picks, the eagle loop inside it).
+
+    The parent's body launched 47: 14 of them key derivations
+    (``_threefry_split``), a sort for the best of 51, and the candidates'
+    ``[50, 512]`` cross-covariance twice, once a posterior."""
+    designer = _designer(1)
+    assert designer._vec_opt.strategy.batch_size == 50
+    assert designer._vec_opt.max_evaluations == 75_000
+    text = _lower_suggest_batch(designer, 512, count, one_chip).compile().as_text()
+    # The eagle loop is the one ``while`` whose body evaluates the candidates
+    # against the data; the loop over picks holds it and evaluates one point.
+    eagle = [
+        ops
+        for ops in _launched_by_while(text).values()
+        if any(" f32[50,512]" in op for op in ops)
+    ]
+    assert len(eagle) == 1, [len(ops) for ops in eagle]
+    (ops,) = eagle
+    print(f"count {count}: {len(ops)} launched operations an iteration")
+    assert len(ops) <= 30, "\n".join(ops)
+    assert not [op for op in ops if " sort(" in op]
+    assert len([op for op in ops if "_threefry_split" in op]) <= 1
+    passes = [
+        op for op in ops if " f32[50,512]" in op and re.search(r'op_name="[^"]*reduce_sum"', op)
+    ]
+    assert len(passes) == 1, "\n".join(passes)

@@ -240,8 +240,13 @@ class TrustRegion:
         # No observations at all -> everything is trusted.
         return jnp.where(jnp.isfinite(dist), dist, 0.0)
 
-    def penalty(self, query: kernels.MixedFeatures) -> Array:
-        excess = jnp.maximum(self.linf_distance(query) - self.trust_radius(), 0.0)
+    def penalty(
+        self, query: kernels.MixedFeatures, radius: Optional[Array] = None
+    ) -> Array:
+        """``radius`` is ``trust_radius()``, from a caller that scores many
+        queries against one region and computed it once (a sweep's loop)."""
+        radius = self.trust_radius() if radius is None else radius
+        excess = jnp.maximum(self.linf_distance(query) - radius, 0.0)
         return self.penalty_weight * excess
 
 

@@ -135,11 +135,47 @@ def _mixture_predict(
     ``states``: GPState pytree with leading axes [M, E]. Returns
     ([M, Q] mean, [M, Q] stddev).
     """
-    means, stddevs = jax.vmap(jax.vmap(lambda s: s.predict(query)))(states)
+    return _moment_match(*jax.vmap(jax.vmap(lambda s: s.predict(query)))(states))
+
+
+def _moment_match(means: Array, stddevs: Array) -> Tuple[Array, Array]:
+    """[M, E, Q] member means and stddevs -> the uniform mixture's [M, Q]."""
     mean = jnp.mean(means, axis=1)
     second = jnp.mean(stddevs**2 + means**2, axis=1)
     var = jnp.maximum(second - mean**2, 1e-12)
     return mean, jnp.sqrt(var)
+
+
+def _exact_posterior_pair(
+    states_completed: gp_lib.GPState,  # [M, E]
+    states_all: gp_lib.GPState,  # [M, E], over the all-points rows
+    rows_all: kernels.ScaledRows,  # [M, E] ``states_all.kernel_rows()``
+    query: kernels.MixedFeatures,
+) -> Tuple[Array, Array, Array]:
+    """(mean, stddev) of the completed posterior and the all-points stddev
+    at ``query``, each [M, Q], from ONE cross-covariance a member.
+
+    ``_mixture_predict`` of each side built its own k(query, X): the sweep's
+    dearest pass, twice an iteration. The all-points hyperparameters are the
+    trained ones but for the noise (``_pe_conditioning``), which the kernel
+    does not read, and the completed rows are the leading rows of the
+    all-points data (``_all_points_model_data``), so k(query, X_completed)
+    is the leading block of k(query, X_all); each posterior masks it with
+    its own ``row_mask``.
+    """
+    n_completed = states_completed.alpha.shape[-1]
+
+    def member(completed, everything, rows):
+        k_all = everything.cross_covariance(query, rows)
+        return (
+            *completed.predict_from_cross(k_all[:, :n_completed]),
+            *everything.predict_from_cross(k_all),
+        )
+
+    mean_c, std_c, mean_all, std_all = jax.vmap(jax.vmap(member))(
+        states_completed, states_all, rows_all
+    )
+    return (*_moment_match(mean_c, std_c), _moment_match(mean_all, std_all)[1])
 
 
 def _mt_mixture_predict(
@@ -402,6 +438,7 @@ def _suggest_batch(
     # swap the posterior ops; every acquisition formula below is shared.
     is_mt = isinstance(model, mtgp.MultiTaskGaussianProcess)
     is_sparse = isinstance(model, sparse_gp.SparseGaussianProcess)
+    is_exact = not (is_mt or is_sparse)
     if is_sparse:
         # Pending-pick conditioning through the inducing-point posterior:
         # ``all_data`` is a SparseGPData (completed+active rows + the
@@ -434,6 +471,12 @@ def _suggest_batch(
             / (mt_p["noise_stddev"][:, None] ** 2)
         )  # [E, M]
     else:
+        if states_completed.alpha.shape[-1] > all_data.num_rows:
+            raise ValueError(
+                "The all-points data must hold the completed rows as its "
+                f"leading rows: trained pad {states_completed.alpha.shape[-1]} "
+                f"> all-points pad {all_data.num_rows}."
+            )
         mixture = _mixture_predict
         base_data = lambda d: d  # noqa: E731
         append = _append_row
@@ -450,6 +493,9 @@ def _suggest_batch(
         acquisitions.TrustRegion.from_data(base_data(all_data))
         if use_trust_region
         else None
+    )
+    trust_radius = (
+        trust.trust_radius() if trust is not None else jnp.asarray(jnp.inf)
     )
 
     def pick(b, carry):
@@ -484,9 +530,21 @@ def _suggest_batch(
         )
         weights = weights / jnp.linalg.norm(weights, axis=-1, keepdims=True)
 
+        # query -> (mean, stddev) of the completed posterior and the
+        # all-points stddev, [M, Q] each.
+        if is_exact:
+            # The data side of the candidates' cross-covariance, once a pick.
+            rows_all = jax.vmap(jax.vmap(lambda s: s.kernel_rows()))(states_all)
+            posteriors = functools.partial(
+                _exact_posterior_pair, states_completed, states_all, rows_all
+            )
+        else:
+            posteriors = lambda q: (  # noqa: E731
+                *mixture(states_completed, q), mixture(states_all, q)[1]
+            )
+
         def score_fn(query: kernels.MixedFeatures) -> Array:
-            mean_c, std_c = mixture(states_completed, query)  # [M, Q]
-            _, std_all = mixture(states_all, query)  # [M, Q]
+            mean_c, std_c, std_all = posteriors(query)
             ucb_vals = mean_c + config.ucb_coefficient * std_all
             if num_metrics == 1:
                 ucb_score = ucb_vals[0]
@@ -510,7 +568,7 @@ def _suggest_batch(
                 # both the UCB and PE scores, `gp_ucb_pe.py:377,419`).
                 value = value + prior_acquisition(query)
             if trust is not None:
-                value = value - trust.penalty(query)
+                value = value - trust.penalty(query, trust_radius)
             return value
 
         if mesh is None:
@@ -558,9 +616,7 @@ def _suggest_batch(
     _, out_cont, out_cat, out_scores, aux, _ = jax.lax.fori_loop(
         0, count, pick, init
     )
-    aux["trust_radius"] = (
-        trust.trust_radius() if trust is not None else jnp.asarray(jnp.inf)
-    )
+    aux["trust_radius"] = trust_radius
     return (
         vectorized_lib.VectorizedOptimizerResult(
             kernels.MixedFeatures(out_cont, out_cat), out_scores
@@ -1236,7 +1292,8 @@ class VizierGPUCBPEBandit(gp_bandit.VizierGPBandit):
         on every update), the ACTIVE trials'. Every suggest path reads its
         all-points rows here once, so this is where the store's read is
         counted."""
-        cont, cat, _ = self._completed_rows()
+        completed_cont, completed_cat, _ = self._completed_rows()
+        cont, cat = completed_cont, completed_cat
         active = self._active_trials
         self._store.tally(also_encoded=len(active))
         if active:
@@ -1245,6 +1302,23 @@ class VizierGPUCBPEBandit(gp_bandit.VizierGPBandit):
             cat = np.concatenate([cat, active_cat])
         num_rows = cont.shape[0]
         features, n_pad = self._padded_features(cont, cat, extra_rows=count)
+        # The sweeps read k(query, completed rows) as the leading block of
+        # k(query, all points) (``_exact_posterior_pair``): the completed
+        # rows lead, as the train's data holds them, bit for bit.
+        lead = np.s_[: len(completed_cont)]
+        if not (
+            np.array_equal(
+                features.continuous.padded_array[lead, : completed_cont.shape[1]],
+                completed_cont,
+            )
+            and np.array_equal(
+                features.categorical.padded_array[lead, : completed_cat.shape[1]],
+                completed_cat,
+            )
+        ):
+            raise RuntimeError(
+                "The all-points rows must begin with the completed rows."
+            )
         spare = n_pad - num_rows
         if spare < count:  # capacity guard: _append_row must never no-op
             raise RuntimeError(

@@ -232,17 +232,29 @@ class VizierGaussianProcess:
     def _kernel(
         self, p: Params, f1: kernels.MixedFeatures, f2: kernels.MixedFeatures, data: GPData
     ) -> Array:
+        return self._kernel_to_rows(p, f1, self._kernel_rows(p, f2, data), data)
+
+    def _kernel_rows(
+        self, p: Params, f2: kernels.MixedFeatures, data: GPData
+    ) -> kernels.ScaledRows:
+        """The kernel's second argument as the kernel reads it (warped, over
+        the length scales, masked dimensions zeroed): whoever holds ``f2``
+        fixed over many ``f1`` prepares it once (``GPState.kernel_rows``)."""
         cont_ls = p.get("continuous_length_scales", jnp.ones((self.num_continuous,)))
+        return kernels.scaled_rows(
+            self._warp_features(p, f2), cont_ls, data.cont_dim_mask
+        )
+
+    def _kernel_to_rows(
+        self, p: Params, f1: kernels.MixedFeatures, rows: kernels.ScaledRows, data: GPData
+    ) -> Array:
+        """``_kernel(p, f1, f2, data)`` for ``rows = _kernel_rows(p, f2, data)``."""
         cat_ls = p.get("categorical_length_scales", jnp.ones((self.num_categorical,)))
-        f1 = self._warp_features(p, f1)
-        f2 = self._warp_features(p, f2)
-        return kernels.matern52_ard(
-            f1,
-            f2,
+        return kernels.matern52_ard_to_rows(
+            self._warp_features(p, f1),
+            rows,
             amplitude=p["amplitude"],
-            continuous_length_scales=cont_ls,
             categorical_length_scales=cat_ls,
-            continuous_dim_mask=data.cont_dim_mask,
             categorical_dim_mask=data.cat_dim_mask,
         )
 
@@ -314,12 +326,31 @@ class GPState:
     alpha: Array  # [N]
     linv: Array  # [N, N] = chol^-1 (matmul-only predicts; MXU-friendly)
 
-    def predict(
-        self, query: kernels.MixedFeatures, *, include_noise: bool = False
+    def kernel_rows(self) -> kernels.ScaledRows:
+        """The data side of :meth:`cross_covariance`, prepared once for many
+        queries (a sweep's 1,500 candidate batches)."""
+        return self.model._kernel_rows(self.params, self.data.features(), self.data)
+
+    def cross_covariance(
+        self,
+        query: kernels.MixedFeatures,
+        rows: Optional[kernels.ScaledRows] = None,
+    ) -> Array:
+        """k(query, X) over every padded row, [M, N], not yet masked.
+        ``rows`` is :meth:`kernel_rows`, from a caller that kept it."""
+        rows = self.kernel_rows() if rows is None else rows
+        return self.model._kernel_to_rows(self.params, query, rows, self.data)
+
+    def predict_from_cross(
+        self, k_star: Array, *, include_noise: bool = False
     ) -> Tuple[Array, Array]:
-        """Posterior mean and stddev at query points ([M], [M])."""
-        model, p, data = self.model, self.params, self.data
-        k_star = model._kernel(p, query, data.features(), data)  # [M, N]
+        """Posterior mean and stddev ([M], [M]) from ``cross_covariance``.
+
+        The kernel reads neither the noise nor the labels, so one
+        cross-covariance serves every posterior over the same rows with the
+        same kernel hyperparameters, and its leading columns a posterior over
+        the leading rows (GP-UCB-PE's completed and all-points posteriors)."""
+        p, data = self.params, self.data
         k_star = jnp.where(data.row_mask[None, :], k_star, 0.0)
         mean = jnp.matmul(k_star, self.alpha, precision=POSTERIOR_PRECISION)
         # [N, M] — pure matmul in the hot loop
@@ -329,6 +360,14 @@ class GPState:
         if include_noise:
             var = var + p["noise_stddev"] * p["noise_stddev"]
         return mean, jnp.sqrt(jnp.maximum(var, 1e-12))
+
+    def predict(
+        self, query: kernels.MixedFeatures, *, include_noise: bool = False
+    ) -> Tuple[Array, Array]:
+        """Posterior mean and stddev at query points ([M], [M])."""
+        return self.predict_from_cross(
+            self.cross_covariance(query), include_noise=include_noise
+        )
 
     def predict_joint(self, query: kernels.MixedFeatures) -> Tuple[Array, Array]:
         """Posterior mean [M] and full covariance [M, M] at query points.

@@ -36,22 +36,16 @@ def matern52(sq_dist: Array) -> Array:
 _DIRECT_DIST_MAX_DIM = 64
 
 
-def scaled_sq_distance_continuous(
-    x1: Array, x2: Array, length_scales: Array, *, dim_mask: Optional[Array] = None
-) -> Array:
-    """[N, D], [M, D] -> [N, M] sum_d ((x1-x2)/l)^2, optionally dim-masked.
+def sq_distance_of_scaled(a: Array, b: Array) -> Array:
+    """[N, D], [M, D] -> [N, M] sum_d (a-b)^2 of rows already over their
+    length scales (``x * ScaledRows.inverse_scales``).
 
     For D <= 64 (the typical Vizier regime) uses exact elementwise diffs —
     the ||a||²-2a·b+||b||² MXU expansion suffers f32 cancellation (~1e-3
     absolute on near-duplicate points), which poisons the Cholesky diagonal.
     Wide feature spaces fall back to the matmul expansion with clamping.
     """
-    inv = 1.0 / length_scales
-    if dim_mask is not None:
-        inv = jnp.where(dim_mask, inv, 0.0)
-    a = x1 * inv
-    b = x2 * inv
-    if x1.shape[-1] <= _DIRECT_DIST_MAX_DIM:
+    if a.shape[-1] <= _DIRECT_DIST_MAX_DIM:
         diff = a[:, None, :] - b[None, :, :]
         return jnp.sum(diff * diff, axis=-1)
     a2 = jnp.sum(a * a, axis=-1, keepdims=True)  # [N, 1]
@@ -82,6 +76,28 @@ class MixedFeatures(NamedTuple):
     categorical: Array  # [N, Ds] int
 
 
+class ScaledRows(NamedTuple):
+    """The kernel's second argument as the kernel reads it. None of it
+    depends on the first argument, so a caller that holds the rows fixed
+    over many first arguments — an acquisition sweep's data — makes it once
+    (:func:`scaled_rows`)."""
+
+    continuous: Array  # [M, Dc] over the length scales, masked dimensions 0
+    categorical: Array  # [M, Ds] int
+    inverse_scales: Array  # [Dc] what scaled them, and scales the other side
+
+
+def scaled_rows(
+    f2: MixedFeatures,
+    continuous_length_scales: Array,
+    continuous_dim_mask: Optional[Array] = None,
+) -> ScaledRows:
+    inv = 1.0 / continuous_length_scales
+    if continuous_dim_mask is not None:
+        inv = jnp.where(continuous_dim_mask, inv, 0.0)
+    return ScaledRows(f2.continuous * inv, f2.categorical, inv)
+
+
 def matern52_ard(
     f1: MixedFeatures,
     f2: MixedFeatures,
@@ -101,11 +117,28 @@ def matern52_ard(
     (round 2) and removed: the op is bandwidth/dispatch-bound and the
     compiler already schedules it optimally.
     """
-    sq = scaled_sq_distance_continuous(
-        f1.continuous, f2.continuous, continuous_length_scales, dim_mask=continuous_dim_mask
+    return matern52_ard_to_rows(
+        f1,
+        scaled_rows(f2, continuous_length_scales, continuous_dim_mask),
+        amplitude=amplitude,
+        categorical_length_scales=categorical_length_scales,
+        categorical_dim_mask=categorical_dim_mask,
     )
+
+
+def matern52_ard_to_rows(
+    f1: MixedFeatures,
+    rows: ScaledRows,
+    *,
+    amplitude: Array,
+    categorical_length_scales: Array,
+    categorical_dim_mask: Optional[Array] = None,
+) -> Array:
+    """:func:`matern52_ard` against rows already scaled: the same arithmetic
+    in the same order, so the same bits."""
+    sq = sq_distance_of_scaled(f1.continuous * rows.inverse_scales, rows.continuous)
     sq = sq + categorical_sq_distance(
-        f1.categorical, f2.categorical, categorical_length_scales,
+        f1.categorical, rows.categorical, categorical_length_scales,
         dim_mask=categorical_dim_mask,
     )
     return (amplitude * amplitude) * matern52(sq)

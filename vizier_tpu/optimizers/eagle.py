@@ -66,8 +66,15 @@ class VectorizedEagleStrategy:
 
     # -- init --------------------------------------------------------------
 
+    def _draw_keys(self, rng: Array) -> Tuple[Array, Optional[Array]]:
+        """(the continuous draw's key, the categorical draws' key). A key is
+        split only where there are two draws to make: without categoricals
+        ``rng`` is the continuous draw's own, and the sweep's loop launches
+        no key derivation (PERF.md, PR 38)."""
+        return tuple(jax.random.split(rng)) if self.num_categorical else (rng, None)
+
     def _random_features(self, rng: Array, n: int) -> Tuple[Array, Array]:
-        c_rng, s_rng = jax.random.split(rng)
+        c_rng, s_rng = self._draw_keys(rng)
         cont = jax.random.uniform(c_rng, (n, self.num_continuous), dtype=jnp.float32)
         if self.num_categorical:
             sizes = jnp.asarray(self.category_sizes, dtype=jnp.int32)
@@ -116,7 +123,7 @@ class VectorizedEagleStrategy:
             cfg.pool_size - 1, 1
         )
 
-        p_rng, c_rng = jax.random.split(rng)
+        p_rng, c_rng = self._draw_keys(rng)
         noise = jax.random.normal(p_rng, x.shape, dtype=x.dtype)
         new_x = x + pull + state.perturbations[:, None] * noise
         new_x = jnp.clip(new_x, 0.0, 1.0)

@@ -68,34 +68,53 @@ class VectorizedOptimizer:
         batch = strategy.batch_size
         iterations = max(self.max_evaluations // batch, 1)
 
-        rng, init_rng = jax.random.split(rng)
-        state = strategy.init_state(init_rng, prior_features=prior_features)
+        # Every key the sweep uses comes from ONE split, made before the
+        # loop: iteration i reads row i. Derived inside the body, each key
+        # was a chain of word-sized device operations that cost what any
+        # launch costs (14 of the body's 47 on a v5e; PERF.md, PR 38).
+        keys = jax.random.split(rng, 1 + 2 * iterations)
+        state = strategy.init_state(keys[0], prior_features=prior_features)
+        suggest_keys, update_keys = keys[1::2], keys[2::2]
 
         def body(i, carry):
-            state, best_feats, best_scores, rng = carry
-            rng, s_rng, u_rng = jax.random.split(rng, 3)
-            candidates = strategy.suggest(state, s_rng)
+            state, best_feats, best_scores = carry
+            candidates = strategy.suggest(state, suggest_keys[i])
             scores = score_fn(candidates)
             scores = jnp.where(jnp.isfinite(scores), scores, -jnp.inf)
-            state = strategy.update(state, u_rng, candidates, scores)
+            state = strategy.update(state, update_keys[i], candidates, scores)
+            if count == 1:
+                # The best of one: a max, not a sort. The same answer to the
+                # bit as the top-1 of [best, scores]: ``top_k`` takes the
+                # lowest index among ties — the best so far, then the first
+                # of the candidates' maxima — and no score is NaN here.
+                idx = jnp.argmax(scores)
+                better = scores[idx] > best_scores[0]
+                return (
+                    state,
+                    kernels.MixedFeatures(
+                        jnp.where(better, candidates.continuous[idx], best_feats.continuous),
+                        jnp.where(better, candidates.categorical[idx], best_feats.categorical),
+                    ),
+                    jnp.where(better, scores[idx], best_scores),
+                )
             # Merge into running top-k.
             all_scores = jnp.concatenate([best_scores, scores])
             all_cont = jnp.concatenate([best_feats.continuous, candidates.continuous])
             all_cat = jnp.concatenate([best_feats.categorical, candidates.categorical])
             top_scores, idx = jax.lax.top_k(all_scores, count)
             new_best = kernels.MixedFeatures(all_cont[idx], all_cat[idx])
-            return state, new_best, top_scores, rng
+            return state, new_best, top_scores
 
         # Initialize the top-k buffer with the right static shapes.
-        probe = strategy.suggest(state, rng)
+        probe = jax.eval_shape(strategy.suggest, state, keys[0])
         best_feats = kernels.MixedFeatures(
             jnp.zeros((count,) + probe.continuous.shape[1:], probe.continuous.dtype),
             jnp.zeros((count,) + probe.categorical.shape[1:], probe.categorical.dtype),
         )
         best_scores = jnp.full((count,), -jnp.inf, dtype=jnp.float32)
 
-        state, best_feats, best_scores, _ = jax.lax.fori_loop(
-            0, iterations, body, (state, best_feats, best_scores, rng)
+        state, best_feats, best_scores = jax.lax.fori_loop(
+            0, iterations, body, (state, best_feats, best_scores)
         )
         return VectorizedOptimizerResult(best_feats, best_scores)
 
