@@ -95,8 +95,8 @@ class TestArdVsScipy:
         model = gp_lib.VizierGaussianProcess(num_continuous=3, num_categorical=0)
         data = _data()
         opt = lbfgs_lib.LbfgsOptimizer(maxiter=30)
-        s1 = _train_gp(model, opt, data, jax.random.PRNGKey(7), 4, 1)
-        s2 = _train_gp(model, opt, data, jax.random.PRNGKey(7), 4, 1)
+        s1, _ = _train_gp(model, opt, data, jax.random.PRNGKey(7), 4, 1)
+        s2, _ = _train_gp(model, opt, data, jax.random.PRNGKey(7), 4, 1)
         for a, b in zip(
             jax.tree_util.tree_leaves(s1.params), jax.tree_util.tree_leaves(s2.params)
         ):

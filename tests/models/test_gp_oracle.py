@@ -222,7 +222,7 @@ class TestKernelProperties:
         y = (y - y.mean()) / y.std()
         model = gp_lib.VizierGaussianProcess(num_continuous=dc, num_categorical=0)
         data = _make_data(x, np.zeros((n, 0), np.int64), y, n_pad=64)
-        states = _train_gp(
+        states, _ = _train_gp(
             model, lbfgs_lib.LbfgsOptimizer(maxiter=60), data,
             jax.random.PRNGKey(0), num_restarts=4, ensemble_size=1,
         )

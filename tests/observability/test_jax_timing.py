@@ -34,7 +34,7 @@ class TestDevicePhase:
             with jax_timing.device_phase("unit.phase"):
                 pass
         hist = registry.get("vizier_suggest_stage_seconds")
-        assert hist.count(stage="device.wait", path="sequential", per="request") == 3
+        assert hist.count(stage="device.wait", path="sequential", per="request", phase="") == 3
         modes = [s.attributes["mode"] for s in tracer.finished_spans()]
         assert modes == ["compile", "execute", "execute"]
 
@@ -45,8 +45,10 @@ class TestDevicePhase:
         with jax_timing.device_phase("b", path="fused", per="flush"):
             pass
         hist = registry.get("vizier_suggest_stage_seconds")
-        assert hist.count(stage="device.wait", path="sequential", per="request") == 1
-        assert hist.count(stage="device.wait", path="fused", per="flush") == 1
+        assert hist.count(
+            stage="device.wait", path="sequential", per="request", phase="train"
+        ) == 1
+        assert hist.count(stage="device.wait", path="fused", per="flush", phase="flush") == 1
         by_phase = {s.attributes["phase"]: s.attributes for s in tracer.finished_spans()}
         assert by_phase["a"]["mode"] == by_phase["b"]["mode"] == "compile"
         assert by_phase["a"]["stage"] == "train" and "stage" not in by_phase["b"]
@@ -78,7 +80,7 @@ class TestDevicePhase:
         # The failed phase was not observed: a stage whose body raised is
         # not a sample of that stage's time.
         hist = registry.get("vizier_suggest_stage_seconds")
-        assert hist.count(stage="device.wait", path="sequential", per="request") == 0
+        assert hist.count(stage="device.wait", path="sequential", per="request", phase="") == 0
 
     def test_disabled_is_inert(self, fresh_state):
         tracer, registry = fresh_state
@@ -88,7 +90,7 @@ class TestDevicePhase:
             assert phase.block("anything") == "anything"
             assert not phase.enabled
         hist = registry.get("vizier_suggest_stage_seconds")
-        assert hist.count(stage="device.wait", path="sequential", per="request") == 0
+        assert hist.count(stage="device.wait", path="sequential", per="request", phase="") == 0
         assert tracer.finished_spans() == []
         # Nothing on the served path observes into the process-global registry.
         assert metrics_lib.default_registry().names() == []

@@ -333,3 +333,99 @@ def test_carried_gradient_is_the_accepted_points(rows):
         np.testing.assert_array_equal(np.asarray(after.g)[stuck], np.asarray(before.g)[stuck])
         accepted_steps += int(moved.sum())
     assert accepted_steps >= 20 * rows
+
+
+# -- the train returns its own work (PR 39) -----------------------------------
+# ``lbfgs_minimize_counted`` hands out, beside (x, f), the iterations a row ran
+# and the loss evaluations it made; ``_drive`` above makes the same counts by
+# hand, one program iteration at a time.
+
+COUNTED_PROBLEMS = pytest.mark.parametrize(
+    "problem, rows",
+    [(LOCKSTEP_PROBLEM, 2), (dict(pad=64, trials=60, dim=20, seed=3), 5),
+     (dict(pad=64, trials=60, dim=20, seed=5), 2)],
+    ids=["lockstep_seed15_rows2", "seed3_rows5", "seed5_rows2"],
+)
+
+
+def _counted(loss, x0s):
+    return jax.jit(jax.vmap(lambda x0: lbfgs.lbfgs_minimize_counted(loss, x0, **OPTIONS)))(x0s)
+
+
+@COUNTED_PROBLEMS
+def test_returned_counts_are_the_hand_driven_loops(problem, rows):
+    """Row for row under the ``vmap``: iterations = the program iterations the
+    row was live in, evaluations = the one at its start, then the one at
+    ``t0`` and one a halving in each of them. A finished row's counts stop
+    while the loop goes on for the others."""
+    loss, _, starts = _exact_gp_nll(**problem)
+    x0s = _flat(starts(rows))
+    iterations = np.zeros(rows, np.int64)
+    evaluations = np.ones(rows, np.int64)
+    trips = 0
+    for _, _, halvings, live in _drive(lbfgs._lbfgs_loop, loss, x0s):
+        iterations += live
+        evaluations += np.where(live, 1 + halvings, 0)
+        trips += 1
+    _, _, got_iterations, got_evaluations = _counted(loss, x0s)
+    assert got_iterations.dtype == got_evaluations.dtype == jnp.int32
+    np.testing.assert_array_equal(np.asarray(got_iterations), iterations)
+    np.testing.assert_array_equal(np.asarray(got_evaluations), evaluations)
+    # The loop ran for its slowest row, and some row stopped before it.
+    assert iterations.max() == trips and iterations.min() < trips, iterations
+    assert np.all(evaluations >= 1 + iterations)
+
+
+@COUNTED_PROBLEMS
+def test_counting_moves_no_bit(problem, rows):
+    """``x`` and ``f`` are those of a run that carries no counter: the file's
+    own ``_minimize`` over ``_lbfgs_loop``, and ``lbfgs_minimize``."""
+    loss, _, starts = _exact_gp_nll(**problem)
+    x0s = _flat(starts(rows))
+    x, f, _, _ = _counted(loss, x0s)
+    x_plain, f_plain = _run(lbfgs._lbfgs_loop, loss, x0s, rows)
+    np.testing.assert_array_equal(np.asarray(x), np.asarray(x_plain))
+    np.testing.assert_array_equal(np.asarray(f), np.asarray(f_plain))
+    x_two, f_two = jax.jit(jax.vmap(lambda x0: lbfgs.lbfgs_minimize(loss, x0, **OPTIONS)))(x0s)
+    np.testing.assert_array_equal(np.asarray(x), np.asarray(x_two))
+    np.testing.assert_array_equal(np.asarray(f), np.asarray(f_two))
+
+
+@pytest.mark.parametrize("best_n", [None, 2], ids=["best", "top2"])
+def test_optimizer_result_carries_every_rows_counts(best_n):
+    """``OptimizeResult.iterations`` / ``.evaluations`` are ``[num_restarts]``
+    whatever ``best_n`` keeps, and ``work()`` stacks them for ONE read."""
+    loss, tree_loss, starts = _exact_gp_nll(**LOCKSTEP_PROBLEM)
+    inits = starts(3)
+    result = jax.jit(lambda i: lbfgs.LbfgsOptimizer()(tree_loss, i, best_n=best_n))(inits)
+    _, _, iterations, evaluations = _counted(loss, _flat(inits))
+    np.testing.assert_array_equal(np.asarray(result.iterations), np.asarray(iterations))
+    np.testing.assert_array_equal(np.asarray(result.evaluations), np.asarray(evaluations))
+    work = np.asarray(result.work())
+    assert work.shape == (2, 3) and work.dtype == np.int32
+    np.testing.assert_array_equal(work, np.stack([iterations, evaluations]))
+
+
+def test_adam_counts_its_scan():
+    _, tree_loss, starts = _exact_gp_nll(**LOCKSTEP_PROBLEM)
+    result = jax.jit(lambda i: lbfgs.AdamOptimizer(maxiter=7)(tree_loss, i))(starts(2))
+    np.testing.assert_array_equal(np.asarray(result.work()), [[7, 7], [8, 8]])
+
+
+@pytest.mark.parametrize(
+    "work, expected",
+    [
+        # One sequential train: the warm seed's row done after 3 of 5 trips.
+        ([[3, 5], [7, 12]],
+         dict(programs=1, loop_trips=5, rows=2, row_trips=10, row_iterations=8, evaluations=19)),
+        # A fused flush of two slots (the second a padded copy): ONE loop.
+        ([[[3, 5], [7, 12]], [[2, 9], [4, 20]]],
+         dict(programs=1, loop_trips=9, rows=4, row_trips=36, row_iterations=19, evaluations=43)),
+        # Nothing iterated (every row converged at its start).
+        ([[0, 0], [1, 1]],
+         dict(programs=1, loop_trips=0, rows=2, row_trips=0, row_iterations=0, evaluations=2)),
+    ],
+    ids=["sequential", "flush_slots", "no_iteration"],
+)
+def test_work_counts_of_one_program(work, expected):
+    assert lbfgs.work_counts(np.asarray(work, np.int32)) == expected

@@ -53,7 +53,7 @@ class TestShardedTrain:
         data = _data()
         mesh = parallel.create_mesh()
         opt = lbfgs_lib.AdamOptimizer(maxiter=30)
-        states = parallel.train_gp_sharded(
+        states, _ = parallel.train_gp_sharded(
             model, opt, data, jax.random.PRNGKey(0), 8, 2, mesh
         )
         assert states.alpha.shape[0] == 2  # ensemble of 2
@@ -128,7 +128,7 @@ class TestMultihostInit:
         assert len(mesh.devices.flat) == len(jax.devices())
         # Sharded train accepts the returned mesh unchanged.
         model = gp_lib.VizierGaussianProcess(num_continuous=2, num_categorical=0)
-        states = parallel.train_gp_sharded(
+        states, _ = parallel.train_gp_sharded(
             model, lbfgs_lib.AdamOptimizer(maxiter=5), _data(),
             jax.random.PRNGKey(0), num_restarts=8, ensemble_size=1, mesh=mesh,
         )

@@ -141,7 +141,7 @@ def measure_latency(args) -> dict:
                     vec_opt, scoring, k_acq, args.batch, prior(data)
                 )
             else:
-                states = _train_gp(model=base, optimizer=ard, data=data,
+                states, _ = _train_gp(model=base, optimizer=ard, data=data,
                                    rng=k_train, num_restarts=restarts,
                                    ensemble_size=1)
                 scoring = scoring_for(gp_lib.EnsemblePredictive(states), data)

@@ -118,9 +118,9 @@ def measure_latency(args) -> dict:
             k_train, k_acq = jax.random.split(key)
             t0 = time.perf_counter()
             if warm and prev_params is not None:
-                states = _train_gp(model, ard, data, k_train, 1, 1, prev_params)
+                states, _ = _train_gp(model, ard, data, k_train, 1, 1, prev_params)
             else:
-                states = _train_gp(model, ard, data, k_train, cold_restarts, 1)
+                states, _ = _train_gp(model, ard, data, k_train, cold_restarts, 1)
             result = sweep(states, data, k_acq)
             jax.block_until_ready(result)
             elapsed = (time.perf_counter() - t0) * 1000.0

@@ -21,7 +21,7 @@ from __future__ import annotations
 import abc
 import dataclasses
 import functools
-from typing import Any, List, Optional, Sequence
+from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -75,8 +75,10 @@ def _train_gp(
     num_restarts: int,
     ensemble_size: int,
     warm_start: Optional[gp_lib.Params] = None,
-) -> gp_lib.GPState:
-    """ARD: restarts → L-BFGS (vmapped) → top-k precomputed posteriors.
+) -> Tuple[gp_lib.GPState, Array]:
+    """ARD: restarts → L-BFGS (vmapped) → top-k precomputed posteriors, and
+    the optimizer's own count of its work (``OptimizeResult.work``: one small
+    integer array, which ``read_train_work`` fetches where a phase is timed).
 
     ``warm_start`` (previous suggest's best unconstrained params) is
     prepended as an EXTRA restart row — steady-state hyperparameters move
@@ -96,7 +98,35 @@ def _train_gp(
         )
     loss_fn = lambda p: model.neg_log_likelihood(p, data)
     result = optimizer(loss_fn, inits, best_n=ensemble_size)
-    return jax.vmap(lambda p: model.precompute(p, data))(result.params)
+    states = jax.vmap(lambda p: model.precompute(p, data))(result.params)
+    return states, result.work()
+
+
+# Of ``lbfgs.work_counts``: what a train's ``device.wait`` span says of the
+# program(s) it waited for, and what ``ard_train_counts`` adds up under the
+# names ``serving_stats()`` has for them (``train_<name>``).
+_WORK_SPAN_ATTRIBUTES = ("loop_trips", "rows", "row_iterations", "evaluations")
+_WORK_COUNTERS = ("programs", "loop_trips", "row_trips", "row_iterations", "evaluations")
+
+
+def read_train_work(phase, works: Sequence[Array]) -> Optional[Dict[str, int]]:
+    """After ``phase.block``: what the phase's train programs counted of
+    their own work (``lbfgs.work_counts``, summed over the programs), ONE
+    small read a program, also written on the phase's span. None — nothing
+    read, nothing counted — when the phase is inert or trained nothing (a
+    ``work`` of None is a program whose trainer counts nothing)."""
+    total: Optional[Dict[str, int]] = None
+    for work in works:
+        if work is None:
+            continue
+        fetched = phase.read(work)
+        if fetched is None:
+            return None
+        counts = lbfgs_lib.work_counts(fetched)
+        total = counts if total is None else {k: total[k] + counts[k] for k in counts}
+    if total is not None:
+        phase.set_attributes(**{k: total[k] for k in _WORK_SPAN_ATTRIBUTES})
+    return total
 
 
 @functools.partial(jax.jit, static_argnames=("vec_opt", "count"))
@@ -194,7 +224,7 @@ def _gp_bandit_flush_program(
     serving happens once per BATCH instead of ~3·N times.
     """
     data = jax.vmap(lambda m: gp_lib.GPData.from_model_data(m))(md)
-    states = jax.vmap(
+    states, work = jax.vmap(
         lambda d, k, w: _train_gp(
             model, optimizer, d, k, num_restarts, ensemble_size, w
         )
@@ -204,7 +234,7 @@ def _gp_bandit_flush_program(
             vec_opt, acquisition, s, d, k, count, use_trust_region
         )
     )(states, data, rng_acq)
-    return states, _warm_next_batched(model, states), result
+    return states, _warm_next_batched(model, states), result, work
 
 
 @functools.partial(
@@ -363,7 +393,10 @@ class VizierGPBandit(core_lib.Designer, core_lib.Predictor):
         # random placeholder above) — gates the reduced warm restart budget
         # and the warm/cold accounting below.
         self._warm_is_trained = False
-        self._ard_train_counts = {"warm": 0, "cold": 0, "cached": 0}
+        self._ard_train_counts = {
+            "warm": 0, "cold": 0, "cached": 0,
+            **{f"train_{name}": 0 for name in _WORK_COUNTERS},
+        }
         # Sparse-surrogate auto-switch state (vizier_tpu.surrogates): the
         # mode is sticky (hysteresis) and a crossover drops all warm/
         # posterior state so neither surrogate ever trains from the
@@ -442,8 +475,9 @@ class VizierGPBandit(core_lib.Designer, core_lib.Predictor):
         ensemble_size: int,
         warm_start: Optional[gp_lib.Params] = None,
         num_restarts: Optional[int] = None,
-    ) -> gp_lib.GPState:
+    ) -> Tuple[gp_lib.GPState, Array]:
         """ARD train; restarts shard over the mesh when one is present.
+        Returns the fit and the program's count of its work (``_train_gp``).
 
         ``num_restarts`` overrides ``self.ard_restarts`` (the warm-started
         steady-state path trains with ``warm_ard_restarts``); it is floored
@@ -487,6 +521,12 @@ class VizierGPBandit(core_lib.Designer, core_lib.Predictor):
             "warm" if (self.use_warm_start_ard and self._warm_is_trained) else "cold"
         ] += 1
 
+    def _record_train_work(self, counts: Optional[Dict[str, int]]) -> None:
+        """``read_train_work``'s counts into ``ard_train_counts`` (a fused
+        flush's, once: through the flush's first member)."""
+        for name in _WORK_COUNTERS if counts else ():
+            self._ard_train_counts[f"train_{name}"] += counts[name]
+
     # -- serving warm-start surface (vizier_tpu.serving) --------------------
 
     def warm_start_state(self) -> Optional[gp_lib.Params]:
@@ -500,7 +540,8 @@ class VizierGPBandit(core_lib.Designer, core_lib.Predictor):
 
     @property
     def ard_train_counts(self) -> dict:
-        """Copies of the warm/cold ARD train counters (serving stats)."""
+        """Copies of the warm/cold ARD train counters and of what the timed
+        train programs counted of their own work (serving stats)."""
         return dict(self._ard_train_counts)
 
     # -- scalable-surrogate auto-switch (vizier_tpu.surrogates) -------------
@@ -754,7 +795,7 @@ class VizierGPBandit(core_lib.Designer, core_lib.Predictor):
             with jax_timing.device_phase(
                 "gp_bandit.train_gp", stage="train", devices=self._mesh_size()
             ) as phase:
-                states = self._train(
+                states, work = self._train(
                     data,
                     self._next_rng(),
                     self.ensemble_size,
@@ -762,6 +803,7 @@ class VizierGPBandit(core_lib.Designer, core_lib.Predictor):
                     num_restarts=self._warm_restart_budget(),
                 )
                 phase.block(states)
+                self._record_train_work(read_train_work(phase, (work,)))
         self._record_train()
         if self._warm_update_allowed():
             # Warm-start the next suggest from this one's best member
@@ -1052,7 +1094,7 @@ class VizierGPBandit(core_lib.Designer, core_lib.Predictor):
             if len(self._trials) < max(self.num_seed_trials, 1):
                 raise ValueError("Not enough completed trials to predict.")
             data = gp_lib.GPData.from_model_data(self._warped_model_data())
-            states = self._train(data, self._next_rng(), self.ensemble_size)
+            states, _ = self._train(data, self._next_rng(), self.ensemble_size)
             self._last_predictive = gp_lib.EnsemblePredictive(states)
         return self._last_predictive
 
@@ -1176,10 +1218,12 @@ def _gp_bandit_unbatchable(designer: "VizierGPBandit", count: int) -> bool:
     )
 
 
-def _gp_bandit_demux(items, states, warm_next, result):
+def _gp_bandit_demux(items, states, warm_next, result, train_work):
     """ONE device->host fetch for the whole batch; per-slot demux is then
     free numpy views (per-slot device slices would be ~20 dispatches per
-    slot and dominated the executor's wall time)."""
+    slot and dominated the executor's wall time). ``train_work`` (the
+    flush's one train program: ``read_train_work``) goes to the first
+    member alone, so that it is counted once a flush."""
     from vizier_tpu.parallel import batch_executor
 
     # The per-flush half of the fused path's designer.decode stage
@@ -1193,6 +1237,7 @@ def _gp_bandit_demux(items, states, warm_next, result):
                 states=batch_executor.slice_pytree(states, i),
                 warm_next=batch_executor.slice_pytree(warm_next, i),
                 result=batch_executor.slice_pytree(result, i),
+                train_work=train_work if i == 0 else None,
             )
             for i in range(len(items))
         ]
@@ -1220,7 +1265,8 @@ class _GPBanditFlush(compute_ir.DesignerProgram):
     def _flush(self, *args):
         """The jitted flush program of this family, looked up in its
         module when called (``tests/compute/test_tpu_compile.py`` swaps
-        it there)."""
+        it there): its outputs, the train's count of its work last (None
+        from a family whose trainer counts nothing)."""
 
     @abc.abstractmethod
     def _keep_fit(self, designer: "VizierGPBandit", states) -> None:
@@ -1290,7 +1336,7 @@ class _GPBanditFlush(compute_ir.DesignerProgram):
         with jax_timing.device_phase(
             self.device_phase, **tracing_lib.FUSED_FLUSH
         ) as phase:
-            states, warm_next, result = self._flush(
+            states, warm_next, result, work = self._flush(
                 self._model(d0), d0._ard, d0._vec_opt, d0._make_acquisition(),
                 stacked["md"], stacked["rng_train"], stacked["rng_acq"],
                 stacked["warm"],
@@ -1298,12 +1344,14 @@ class _GPBanditFlush(compute_ir.DesignerProgram):
                 items[0]["count"], d0.use_trust_region,
             )
             phase.block(result)
-        return _gp_bandit_demux(items, states, warm_next, result)
+            train_work = read_train_work(phase, (work,))
+        return _gp_bandit_demux(items, states, warm_next, result, train_work)
 
     def finalize(self, designer, item, output):
         """Host-side demux: per-study warm-param writeback + decode — the
         same state transitions the sequential suggest performs."""
         designer._record_train()
+        designer._record_train_work(output["train_work"])
         if designer._warm_update_allowed():
             # The unconstrain already ran (vmapped) inside the flush program.
             designer._warm_params = output["warm_next"]
@@ -1361,7 +1409,7 @@ class GPBanditSparseProgram(_GPBanditFlush):
         return designer._sparse_model()
 
     def _flush(self, *args):
-        return sparse_bandit._sparse_flush_program(*args)
+        return (*sparse_bandit._sparse_flush_program(*args), None)
 
     def _keep_fit(self, designer, states):
         designer._last_predictive = sparse_gp.SparseEnsemblePredictive(states)

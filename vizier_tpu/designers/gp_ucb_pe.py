@@ -787,7 +787,7 @@ def _ucb_pe_flush_program(
     """
     data = jax.vmap(lambda m: gp_lib.GPData.from_model_data(m))(md)
     all_data = jax.vmap(lambda m: gp_lib.GPData.from_model_data(m))(all_md)
-    states = jax.vmap(
+    states, work = jax.vmap(
         lambda d, k, w: gp_bandit._train_gp(
             model, optimizer, d, k, num_restarts, ensemble_size, w
         )
@@ -816,7 +816,7 @@ def _ucb_pe_flush_program(
             first_has_new, has_completed, count, config, use_trust_region,
         )
         segments = ((batch, aux),)
-    return states, warm_next, data, segments
+    return states, warm_next, data, segments, work
 
 
 @functools.partial(
@@ -979,6 +979,11 @@ class VizierGPUCBPEBandit(gp_bandit.VizierGPBandit):
         # Trained per-metric states, reused until new data arrives (predict/
         # sample after a suggest must not pay a second ARD optimization).
         self._cached_states = None
+        # What the last exact train's program(s) counted of their own work
+        # (``gp_bandit._train_gp``), on the device until a suggest's timed
+        # train phase reads it; a train made for ``sample`` is read by the
+        # next suggest.
+        self._unread_train_work: tuple = ()
         # (datas, _sweep_inputs(datas)) of the last fit's datas: made once
         # a fit, found again by the identity of the list.
         self._sweep_inputs_of: Optional[tuple] = None
@@ -1205,7 +1210,7 @@ class VizierGPUCBPEBandit(gp_bandit.VizierGPBandit):
 
                 ndev = self._mesh_size()
                 restarts = -(-self.ard_restarts // ndev) * ndev
-                states = parallel.train_gp_sharded(
+                states, _ = parallel.train_gp_sharded(
                     mt_model, self._ard, mt_data, self._next_rng(),
                     restarts, ensemble, self._mesh,
                 )
@@ -1220,18 +1225,20 @@ class VizierGPUCBPEBandit(gp_bandit.VizierGPBandit):
         # restart early-exits the L-BFGS while random restarts burn the
         # full budget).
         warm_budget = self._warm_restart_budget()
-        states_list = [
-            self._train(
-                data,
-                self._next_rng(),
-                ensemble,
-                warm_start=self._warm_params_me[j],
-                num_restarts=warm_budget,
+        states_list, self._unread_train_work = zip(
+            *(
+                self._train(
+                    data,
+                    self._next_rng(),
+                    ensemble,
+                    warm_start=self._warm_params_me[j],
+                    num_restarts=warm_budget,
+                )
+                for j, data in enumerate(datas)
             )
-            for j, data in enumerate(datas)
-        ]
+        )
         self._record_train()
-        states_me, best = _stack_fits(tuple(states_list))
+        states_me, best = _stack_fits(states_list)
         self._seed_next_trains(best)
         self._cached_states = (states_me, datas)
         return self._cached_states
@@ -1384,6 +1391,11 @@ class VizierGPUCBPEBandit(gp_bandit.VizierGPBandit):
             ) as phase:
                 states_me, datas = self._train_states_me(datas)
                 phase.block(states_me)
+                if phase.enabled:
+                    works, self._unread_train_work = self._unread_train_work, ()
+                    self._record_train_work(
+                        gp_bandit.read_train_work(phase, works)
+                    )
         is_mt = isinstance(states_me, mtgp.MultiTaskGPState)
         is_sparse = isinstance(states_me, sparse_gp.SparseGPState)
         num_metrics = len(datas)
@@ -1709,10 +1721,13 @@ def _ucb_pe_two_phase(designer: "VizierGPUCBPEBandit", count: int) -> bool:
     return designer.acquisition_budget_policy == "first_pick_full" and count > 1
 
 
-def _ucb_pe_demux(items, states, warm_next, data, segments, rows):
+def _ucb_pe_demux(items, states, warm_next, data, segments, rows, train_work):
     """ONE device->host fetch for everything the demux needs; per-slot
     slices below are then free numpy views. The per-flush half of the fused
-    path's ``designer.decode`` stage (``finalize`` is the per-slot half)."""
+    path's ``designer.decode`` stage (``finalize`` is the per-slot half).
+    ``train_work`` (the flush's one train program:
+    ``gp_bandit.read_train_work``) goes to the first member alone, so that
+    it is counted once a flush."""
     from vizier_tpu.parallel import batch_executor
 
     with tracing_lib.get_tracer().span(
@@ -1734,6 +1749,7 @@ def _ucb_pe_demux(items, states, warm_next, data, segments, rows):
                     )
                     for (result, aux), n in zip(segments, rows)
                 ],
+                train_work=train_work if i == 0 else None,
             )
             for i in range(len(items))
         ]
@@ -1760,7 +1776,8 @@ class _UCBPEFlush(compute_ir.DesignerProgram):
     def _flush(self, *args):
         """The jitted flush program of this family, looked up in its
         module when called (``tests/compute/test_tpu_compile.py`` swaps
-        it there)."""
+        it there): its outputs, the train's count of its work last (None
+        from a family whose trainer counts nothing)."""
 
     @abc.abstractmethod
     def _keep_fit(self, designer: "VizierGPUCBPEBandit", states) -> None:
@@ -1852,7 +1869,7 @@ class _UCBPEFlush(compute_ir.DesignerProgram):
         with jax_timing.device_phase(
             self.device_phase, **tracing_lib.FUSED_FLUSH
         ) as phase:
-            states, warm_next, data, segments = self._flush(
+            states, warm_next, data, segments, work = self._flush(
                 *self._models(d0, count),
                 d0._ard, d0._vec_opt, d0._pick_vec_opt(count),
                 stacked["md"], stacked["all_md"],
@@ -1864,8 +1881,11 @@ class _UCBPEFlush(compute_ir.DesignerProgram):
                 d0.config, d0.use_trust_region, two_phase,
             )
             phase.block(segments)
+            train_work = gp_bandit.read_train_work(phase, (work,))
         rows = [1, count - 1] if two_phase else [count]
-        return _ucb_pe_demux(items, states, warm_next, data, segments, rows)
+        return _ucb_pe_demux(
+            items, states, warm_next, data, segments, rows, train_work
+        )
 
     def finalize(self, designer, item, output):
         """Host-side demux: warm writeback, fit caching for predict/sample,
@@ -1873,6 +1893,7 @@ class _UCBPEFlush(compute_ir.DesignerProgram):
         transitions."""
         states = output["states"]  # [E] leaves (this study's ensemble)
         designer._record_train()
+        designer._record_train_work(output["train_work"])
         if designer._warm_update_allowed():
             # The unconstrain already ran (vmapped) inside the flush program.
             designer._warm_params_me = [output["warm_next"]]
@@ -1924,7 +1945,7 @@ class UCBPESparseProgram(_UCBPEFlush):
         return (designer._sparse_model(), designer._sparse_all_model(count))
 
     def _flush(self, *args):
-        return _sparse_ucb_pe_flush_program(*args)
+        return (*_sparse_ucb_pe_flush_program(*args), None)
 
     def _keep_fit(self, designer, states):
         designer._last_predictive = sparse_gp.SparseEnsemblePredictive(states)

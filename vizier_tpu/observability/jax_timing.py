@@ -20,11 +20,16 @@ here — for a flush program, its ``DesignerProgram.device_phase``), ``stage``
 phase name in the process is ``mode="compile"`` (trace + lower + compile dominates it), later ones
 ``mode="execute"``, the steady-state serving number. Like every stage span
 it is observed into ``vizier_suggest_stage_seconds{stage="device.wait",...}``
-in the serving runtime's registry and annotates the ``jax.profiler`` trace.
+in the serving runtime's registry — there under the label ``phase``, which is
+this span's ``stage`` (``train`` / ``acquire``) or ``flush`` for a fused
+flush's one wait — and annotates the ``jax.profiler`` trace. A train phase
+also carries what its program counted of its own work (``phase.read`` after
+the block, ``phase.set_attributes``): ``loop_trips``, ``rows``,
+``row_iterations``, ``evaluations``.
 
 With observability (or the JAX knob) off, the phase object is inert and —
-deliberately — does NOT ``block_until_ready``: the production path keeps
-JAX's async pipelining, so the off switch costs nothing.
+deliberately — does NOT ``block_until_ready`` and reads nothing: the
+production path keeps JAX's async pipelining, so the off switch costs nothing.
 """
 
 from __future__ import annotations
@@ -72,12 +77,13 @@ def _mark_seen(name: str) -> bool:
 class _Phase:
     """Yielded by :func:`device_phase`; ``block()`` pins device time here."""
 
-    __slots__ = ("name", "enabled", "first_call")
+    __slots__ = ("name", "enabled", "first_call", "_span")
 
     def __init__(self, name: str, enabled: bool, first_call: bool):
         self.name = name
         self.enabled = enabled
         self.first_call = first_call
+        self._span: Any = None  # the open ``device.wait`` span
 
     def block(self, outputs: Any) -> Any:
         """``jax.block_until_ready`` on ``outputs`` (pytree-ok), returned
@@ -87,6 +93,23 @@ class _Phase:
 
             jax.block_until_ready(outputs)
         return outputs
+
+    def read(self, small: Any) -> Any:
+        """ONE device-to-host read of a small output the phase has blocked
+        on — what its program counted of its own work — as NumPy. None, and
+        nothing read, when profiling is off."""
+        if not self.enabled:
+            return None
+        import jax
+
+        return jax.device_get(small)
+
+    def set_attributes(self, **attributes: Any) -> None:
+        """Attributes of the phase's ``device.wait`` span (what ``read``
+        found: the ring and the span log then show it per request)."""
+        if self._span is not None:
+            for key, value in attributes.items():
+                self._span.set_attribute(key, value)
 
 
 _DISABLED_PHASE = _Phase("", enabled=False, first_call=False)
@@ -101,7 +124,7 @@ class _PhaseCM:
         self._phase = phase
         attributes = {"stage": stage} if stage else {}
         self._span_cm = tracing_lib.get_tracer().span(
-            "device.wait",
+            tracing_lib.DEVICE_WAIT,
             phase=phase.name,
             path=path,
             per=per,
@@ -112,7 +135,7 @@ class _PhaseCM:
         )
 
     def __enter__(self) -> _Phase:
-        self._span_cm.__enter__()
+        self._phase._span = self._span_cm.__enter__()
         return self._phase
 
     def __exit__(self, exc_type, exc, tb) -> bool:

@@ -19,13 +19,16 @@ trace's host planes, on the device trace's clock; with none active a TraceMe
 costs under a microsecond. A **stage span** is a span whose name is in
 :data:`STAGES` — the fixed vocabulary of what a served suggest spends its
 host time on. On a clean exit it also observes its duration into ONE
-histogram, ``vizier_suggest_stage_seconds{stage,path,per}``, in the registry
-the serving runtime bound with :meth:`Tracer.bind_registry` (none bound:
-nothing observed). ``path`` is the span's ``path`` attribute: ``fused`` at the
+histogram, ``vizier_suggest_stage_seconds{stage,path,per,phase}``, in the
+registry the serving runtime bound with :meth:`Tracer.bind_registry` (none
+bound: nothing observed). ``path`` is the span's ``path`` attribute: ``fused`` at the
 sites inside a batch-executor flush, ``sequential`` everywhere else (the
 service and policy stages run the same code whichever way the designer
 computes); ``per`` is ``flush`` where a site runs once for all members of a
-fused flush, else ``request``.
+fused flush, else ``request``; ``phase`` says which device program a
+``device.wait`` waited for — the span's ``stage`` attribute (``train`` /
+``acquire``: the sequential and mesh paths time the two apart), ``flush`` for
+a fused flush's one wait — and is empty on every other stage.
 No bookkeeping of a span — annotation, histogram, export — can raise into
 the request: a failure there is dropped and counted in
 ``vizier_tracing_errors_total``.
@@ -74,6 +77,8 @@ PER_REQUEST = "request"
 PER_FLUSH = "flush"
 # The attributes of a stage site that runs once a fused flush.
 FUSED_FLUSH = {"path": PATH_FUSED, "per": PER_FLUSH}
+DEVICE_WAIT = "device.wait"
+PHASE_FLUSH = "flush"  # the ``phase`` of a fused flush's one ``device.wait``
 
 # jax.profiler.TraceAnnotation, imported on the first span: None = not yet
 # looked for, False = not importable (a stdlib-only process).
@@ -438,11 +443,18 @@ class Tracer:
             and span.status == "ok"
         ):
             try:
+                per = span.attributes.get("per", PER_REQUEST)
+                phase = ""
+                if span.name == DEVICE_WAIT:
+                    phase = span.attributes.get("stage") or (
+                        PHASE_FLUSH if per == PER_FLUSH else ""
+                    )
                 self._stage_seconds.observe(
                     span.duration_secs,
                     stage=span.name,
                     path=span.attributes.get("path", PATH_SEQUENTIAL),
-                    per=span.attributes.get("per", PER_REQUEST),
+                    per=per,
+                    phase=phase,
                 )
             except Exception:
                 self._dropped()

@@ -18,11 +18,12 @@ fixed-shape ops.
 from __future__ import annotations
 
 import dataclasses
-from typing import Callable, NamedTuple, Optional, Protocol, Tuple
+from typing import Callable, Dict, NamedTuple, Optional, Protocol, Tuple
 
 import jax
 import jax.flatten_util
 import jax.numpy as jnp
+import numpy as np
 import optax
 
 from vizier_tpu.models import params as params_lib
@@ -52,6 +53,39 @@ class OptimizeResult(NamedTuple):
     params: Params  # best (or top-k stacked) unconstrained params
     losses: Array  # [num_restarts] final losses
     best_loss: Array
+    # What each restart row cost (int32): under the ``vmap`` over restarts
+    # the rows share one ``while_loop``, which runs ``max(iterations)`` trips.
+    iterations: Array  # [num_restarts] iterations the row ran
+    evaluations: Array  # [num_restarts] loss evaluations the row made
+
+    def work(self) -> Array:
+        """``[2, num_restarts]`` int32, iterations over evaluations: the one
+        small array a train program hands out beside its fit
+        (:func:`work_counts` reads it on the host)."""
+        return jnp.stack([self.iterations, self.evaluations])
+
+
+def work_counts(work) -> Dict[str, int]:
+    """What ONE train program's batched L-BFGS loop did, from the fetched
+    :meth:`OptimizeResult.work` of every row it ran in lockstep (any leading
+    axes: a fused flush's slots, padded ones included).
+
+    ``loop_trips`` is the loop's own count, the largest row's; ``row_trips``
+    what lockstep ran (rows x trips) against ``row_iterations``, what the
+    rows needed; ``evaluations`` the rows' loss evaluations (for an ARD loss
+    a Cholesky each).
+    """
+    work = np.asarray(work)
+    iterations, evaluations = work[..., 0, :], work[..., 1, :]
+    trips = int(iterations.max())
+    return {
+        "programs": 1,
+        "loop_trips": trips,
+        "rows": int(iterations.size),
+        "row_trips": trips * int(iterations.size),
+        "row_iterations": int(iterations.sum()),
+        "evaluations": int(evaluations.sum()),
+    }
 
 
 class Optimizer(Protocol):
@@ -284,7 +318,7 @@ def _lbfgs_loop(
     return init, cond, step
 
 
-def lbfgs_minimize(
+def lbfgs_minimize_counted(
     loss_fn: Callable[[Array], Array],
     x0: Array,
     *,
@@ -295,8 +329,12 @@ def lbfgs_minimize(
     ftol: float = 1e-6,
     ftol_patience: int = 2,
     armijo_c1: float = 1e-4,
-) -> Tuple[Array, Array]:
-    """Minimizes a flat-vector loss; returns (x, f(x)). jit/vmap-safe.
+) -> Tuple[Array, Array, Array, Array]:
+    """Minimizes a flat-vector loss; returns (x, f(x)) and the run's own
+    work: the iterations it made and its loss evaluations (the one at ``x0``,
+    then the one at ``t0`` and one a halving in every iteration), both
+    int32. jit/vmap-safe; under a ``vmap`` a finished row's counts stop
+    while the loop goes on for the others.
 
     ``ftol`` is a scipy-style relative-decrease stop: once ``ftol_patience``
     CONSECUTIVE accepted steps each improve the loss by less than
@@ -319,19 +357,45 @@ def lbfgs_minimize(
         ftol_patience=ftol_patience,
         armijo_c1=armijo_c1,
     )
-    final = jax.lax.while_loop(cond, lambda state: step(state)[0], init)
-    return final.x, final.f
+
+    def body(carry):
+        state, evaluations = carry
+        state, num_halvings = step(state)
+        return state, evaluations + 1 + num_halvings
+
+    final, evaluations = jax.lax.while_loop(
+        lambda carry: cond(carry[0]), body, (init, jnp.asarray(1, jnp.int32))
+    )
+    return final.x, final.f, final.k, evaluations
 
 
-def _select_best(finals: Params, losses: Array, best_n: Optional[int]) -> OptimizeResult:
+def lbfgs_minimize(
+    loss_fn: Callable[[Array], Array], x0: Array, **options
+) -> Tuple[Array, Array]:
+    """:func:`lbfgs_minimize_counted` (its options) without the counts."""
+    x, f, _, _ = lbfgs_minimize_counted(loss_fn, x0, **options)
+    return x, f
+
+
+def _select_best(
+    finals: Params,
+    losses: Array,
+    best_n: Optional[int],
+    iterations: Array,
+    evaluations: Array,
+) -> OptimizeResult:
     losses = jnp.where(jnp.isfinite(losses), losses, jnp.inf)
     if best_n is None:
         best = jnp.argmin(losses)
         best_params = jax.tree_util.tree_map(lambda a: a[best], finals)
-        return OptimizeResult(best_params, losses, losses[best])
+        return OptimizeResult(
+            best_params, losses, losses[best], iterations, evaluations
+        )
     _, top_idx = jax.lax.top_k(-losses, best_n)
     top_params = jax.tree_util.tree_map(lambda a: a[top_idx], finals)
-    return OptimizeResult(top_params, losses, losses[top_idx[0]])
+    return OptimizeResult(
+        top_params, losses, losses[top_idx[0]], iterations, evaluations
+    )
 
 
 @dataclasses.dataclass(frozen=True)
@@ -354,9 +418,9 @@ class LbfgsOptimizer:
         def flat_loss(x: Array) -> Array:
             return loss_fn(unravel(x))
 
-        def run_one(init: Params) -> Tuple[Params, Array]:
+        def run_one(init: Params) -> Tuple[Params, Array, Array, Array]:
             x0, _ = jax.flatten_util.ravel_pytree(init)
-            x, f = lbfgs_minimize(
+            x, f, iterations, evaluations = lbfgs_minimize_counted(
                 flat_loss,
                 x0,
                 maxiter=self.maxiter,
@@ -366,10 +430,10 @@ class LbfgsOptimizer:
                 ftol=self.ftol,
                 ftol_patience=self.ftol_patience,
             )
-            return unravel(x), f
+            return unravel(x), f, iterations, evaluations
 
-        finals, losses = jax.vmap(run_one)(init_batch)
-        return _select_best(finals, losses, best_n)
+        finals, losses, iterations, evaluations = jax.vmap(run_one)(init_batch)
+        return _select_best(finals, losses, best_n, iterations, evaluations)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -398,7 +462,9 @@ class AdamOptimizer:
             return final, loss_fn(final)
 
         finals, losses = jax.vmap(run_single)(init_batch)
-        return _select_best(finals, losses, best_n)
+        # A scan: every row makes every step, and one evaluation more.
+        steps = jnp.full(losses.shape, self.maxiter, jnp.int32)
+        return _select_best(finals, losses, best_n, steps, steps + 1)
 
 
 def default_optimizer() -> Optimizer:

@@ -133,8 +133,10 @@ def train_gp_sharded(
     ensemble_size: int,
     mesh: Mesh,
     warm_start: Optional[dict] = None,
-) -> gp_lib.GPState:
-    """Multi-restart ARD with the restart axis sharded over the mesh.
+) -> Tuple[gp_lib.GPState, Array]:
+    """Multi-restart ARD with the restart axis sharded over the mesh: the
+    fit and, as ``gp_bandit._train_gp``, the optimizer's count of its work
+    (every device's rows: the loop's condition is an all-reduce).
 
     ``num_restarts`` should be a multiple of the mesh size. Data is
     replicated (it is small); each device runs its restarts locally; the
@@ -157,7 +159,8 @@ def train_gp_sharded(
     data = jax.lax.with_sharding_constraint(data, replicated(mesh))
     loss_fn = lambda p: model.neg_log_likelihood(p, data)
     result = optimizer(loss_fn, inits, best_n=ensemble_size)
-    return jax.vmap(lambda p: model.precompute(p, data))(result.params)
+    states = jax.vmap(lambda p: model.precompute(p, data))(result.params)
+    return states, result.work()
 
 
 # ---------------------------------------------------------------------------
@@ -241,7 +244,7 @@ def suggest_step_sharded(
 ) -> vectorized_lib.VectorizedOptimizerResult:
     """Full GP-bandit compute step over the mesh: train → score → sweep."""
     train_rng, acq_rng = jax.random.split(rng)
-    states = train_gp_sharded(
+    states, _ = train_gp_sharded(
         model, optimizer, data, train_rng, num_restarts, ensemble_size, mesh
     )
     predictive = gp_lib.EnsemblePredictive(states)

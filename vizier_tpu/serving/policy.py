@@ -260,3 +260,11 @@ class CachedDesignerStatePolicy(policy_lib.Policy):
         cached = after.get("cached", 0) - before.get("cached", 0)
         if cached > 0:
             stats.increment("cached_fit_suggests", cached)
+        # What the timed train programs counted of their own work, under
+        # the serving counters' own names (``train_programs`` ...; a fused
+        # flush's comes through its first member, once a flush).
+        for field in after:
+            if field.startswith("train_"):
+                gained = after[field] - before.get(field, 0)
+                if gained > 0:
+                    stats.increment(field, gained)

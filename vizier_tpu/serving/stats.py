@@ -41,6 +41,14 @@ class ServingStats:
         # A suggest that found its study's fit cached (no completion since
         # the last train) and skipped the train: sequential and unbatchable.
         "cached_fit_suggests",
+        # What the ARD train programs counted of their own work, read after
+        # each timed train phase (optimizers.lbfgs.work_counts; nothing is
+        # read, and these stay, with VIZIER_OBSERVABILITY_JAX=0).
+        "train_programs",  # sequential or mesh: one a training suggest; fused: one a flush
+        "train_loop_trips",  # trips of the batched L-BFGS loop: its largest row's
+        "train_row_trips",  # rows x trips: what lockstep ran, padded slots too
+        "train_row_iterations",  # iterations the rows needed
+        "train_evaluations",  # loss evaluations of the rows (a Cholesky each)
         # The policy's delta trial read (serving.policy): reused / (reused +
         # fetched) is the share of a study a suggest did not re-read.
         "trials_fetched",  # trial protos converted to pyvizier for an update
