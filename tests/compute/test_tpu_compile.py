@@ -29,8 +29,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.sharding import SingleDeviceSharding
+from jax.sharding import Mesh, NamedSharding, PartitionSpec, SingleDeviceSharding
 
+from vizier_tpu import parallel
 from vizier_tpu import pyvizier as vz
 from vizier_tpu.algorithms import core as core_lib
 from vizier_tpu.compute import registry as compute_registry
@@ -61,6 +62,12 @@ def topo():
 @pytest.fixture(scope="module")
 def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def host4(topo):
+    """The described host's four chips as the designers' 1-D mesh."""
+    return Mesh(np.asarray(topo.devices), (parallel.DEVICE_AXIS,))
 
 
 @pytest.fixture(scope="module", autouse=True)
@@ -186,13 +193,15 @@ def _computations(hlo_text: str) -> dict[str, list[str]]:
     return computations
 
 
+_CALLED = re.compile(
+    r"(?:body|condition|calls|to_apply|branch_computations)=\{?(%[\w.\-]+)"
+)
+
+
 def _custom_calls_by_while(hlo_text: str, target: str) -> dict[str, int]:
     """For every ``while`` of the compiled text whose body holds (itself or
     in a computation it calls) a custom-call to ``target``: how many."""
     computations = _computations(hlo_text)
-    called = re.compile(
-        r"(?:body|condition|calls|to_apply|branch_computations)=\{?(%[\w.\-]+)"
-    )
     totals: dict[str, int] = {}
 
     def total(name: str) -> int:
@@ -200,7 +209,7 @@ def _custom_calls_by_while(hlo_text: str, target: str) -> dict[str, int]:
             lines = computations.get(name, [])
             totals[name] = sum(
                 f'custom_call_target="{target}"' in line for line in lines
-            ) + sum(total(callee) for callee in set(called.findall("\n".join(lines))))
+            ) + sum(total(callee) for callee in set(_CALLED.findall("\n".join(lines))))
         return totals[name]
 
     whiles = re.compile(r"(%[\w.\-]+) = .* while\(.*body=(%[\w.\-]+)")
@@ -468,10 +477,11 @@ def test_sequential_crossings_compile(one_chip):
     _fits(gp_ucb_pe._append_first_pick.lower(data, picked).compile())
 
 
-def _lower_suggest_batch(designer, n_pad: int, count: int, sharding):
+def _lower_suggest_batch(designer, n_pad: int, count: int, sharding, mesh=None):
     """``_suggest_batch`` as the sequential path calls it on the exact GP:
     one metric, one member, the trained and the all-points data at
-    ``n_pad`` rows, trust region on, no mesh."""
+    ``n_pad`` rows, trust region on; with a ``mesh``, the pools of each
+    pick's sweep over its devices."""
     data, states = _gp_state_shapes(designer, n_pad, sharding)
     states_me = jax.tree_util.tree_map(
         lambda a: jax.ShapeDtypeStruct((1,) + a.shape, a.dtype, sharding=sharding),
@@ -488,8 +498,31 @@ def _lower_suggest_batch(designer, n_pad: int, count: int, sharding):
         designer._model, designer._vec_opt, states_me, data,
         shape((1, n_pad), jnp.float32), shape((n_pad,), bool),
         shape((1,), jnp.float32), prior, shape((2,), jnp.uint32),
-        shape((), bool), shape((), bool), count, designer.config, True, None, None,
+        shape((), bool), shape((), bool), count, designer.config, True, mesh, None,
     )
+
+
+def _eagle_body(hlo_text: str) -> list[str]:
+    """The launched operations of the eagle loop: the one ``while`` whose
+    body evaluates the candidates against the data (the loop over picks
+    holds it and evaluates one point)."""
+    eagle = [
+        ops
+        for ops in _launched_by_while(hlo_text).values()
+        if any(" f32[50,512]" in op for op in ops)
+    ]
+    assert len(eagle) == 1, [len(ops) for ops in eagle]
+    return eagle[0]
+
+
+def _assert_launches_what_it_needs(ops: list[str]):
+    assert len(ops) <= 30, "\n".join(ops)
+    assert not [op for op in ops if " sort(" in op]
+    assert len([op for op in ops if "_threefry_split" in op]) <= 1
+    passes = [
+        op for op in ops if " f32[50,512]" in op and re.search(r'op_name="[^"]*reduce_sum"', op)
+    ]
+    assert len(passes) == 1, "\n".join(passes)
 
 
 @pytest.mark.parametrize("count", [1, 24])
@@ -507,20 +540,72 @@ def test_eagle_loop_body_launches_what_it_needs(one_chip, count):
     assert designer._vec_opt.strategy.batch_size == 50
     assert designer._vec_opt.max_evaluations == 75_000
     text = _lower_suggest_batch(designer, 512, count, one_chip).compile().as_text()
-    # The eagle loop is the one ``while`` whose body evaluates the candidates
-    # against the data; the loop over picks holds it and evaluates one point.
-    eagle = [
-        ops
-        for ops in _launched_by_while(text).values()
-        if any(" f32[50,512]" in op for op in ops)
-    ]
-    assert len(eagle) == 1, [len(ops) for ops in eagle]
-    (ops,) = eagle
+    ops = _eagle_body(text)
     print(f"count {count}: {len(ops)} launched operations an iteration")
-    assert len(ops) <= 30, "\n".join(ops)
-    assert not [op for op in ops if " sort(" in op]
-    assert len([op for op in ops if "_threefry_split" in op]) <= 1
-    passes = [
-        op for op in ops if " f32[50,512]" in op and re.search(r'op_name="[^"]*reduce_sum"', op)
-    ]
-    assert len(passes) == 1, "\n".join(passes)
+    _assert_launches_what_it_needs(ops)
+
+
+_COLLECTIVE = re.compile(
+    r" (?:all-reduce|all-gather|all-to-all|collective-permute|reduce-scatter"
+    r"|collective-broadcast)(?:-start)?\("
+)
+
+
+def _called_by(hlo_text: str, lines: list[str]) -> list[str]:
+    """The instruction lines of every computation ``lines`` call, and of
+    those they call."""
+    computations = _computations(hlo_text)
+    seen: set[str] = set()
+    todo = list(lines)
+    found = []
+    while todo:
+        for name in set(_CALLED.findall(todo.pop())) - seen:
+            seen.add(name)
+            found += computations.get(name, [])
+            todo += computations.get(name, [])
+    return found
+
+
+@pytest.mark.parametrize("count", [1, 24])
+def test_eagle_loop_body_on_a_mesh_is_the_one_chip_body(host4, count):
+    """On a four-chip host a device's sweep is the sweep one chip runs:
+    ``_suggest_batch`` with the designers' mesh, every operand replicated.
+    The pools are a manual axis (``parallel.maximize_score_fn_sharded``), so
+    no operation of the eagle loop carries a pool axis. As a ``vmap`` axis
+    that the partitioner split, each device's unit pool axis sat second-minor
+    in the body's broadcast-difference-reduce fusions, tiled ``T(1,128)`` —
+    one sublane of a register's eight: the pull was fused over
+    ``f32[1,50,20,50]{3,0,2,1:T(1,128)}`` at ~15 us for one chip's 0.65, the
+    trust region's pass over ``f32[1,50,512,20]`` at ~4.4 for 1.0, and the
+    body launched 33 operations for one chip's 27 (PERF.md, PR 40).
+
+    What the compiler puts in for the mesh: nothing inside the eagle loop,
+    and two all-reduces a pick — the scores' and the winner's row's."""
+    designer = _designer(1)
+    replicated = NamedSharding(host4, PartitionSpec())
+    text = (
+        _lower_suggest_batch(designer, 512, count, replicated, host4)
+        .compile()
+        .as_text()
+    )
+    ops = _eagle_body(text)
+    print(f"count {count}, four chips: {len(ops)} launched operations an iteration")
+    _assert_launches_what_it_needs(ops)
+    inside = _called_by(text, ops)
+    fused = set(re.findall(r" (f32\[[\d,]+\]\{[^}]*\})", "\n".join(inside)))
+    pooled = sorted(
+        shape
+        for shape in fused
+        if re.match(r"f32\[(?:1,)+(?:50,20,50|50,512,20|50,50,20)\]", shape)
+        # One chip's own: the cross-covariance pass carries the unit metric
+        # and member axes of ``_exact_posterior_pair``'s vmaps (PERF.md §7).
+        and not shape.startswith("f32[1,1,50,512,20]")
+    )
+    assert not pooled, pooled
+    assert [s for s in fused if re.match(r"f32\[50,20,50\]\{[\d,]+:T\(8,128\)", s)], fused
+    assert [s for s in fused if re.match(r"f32\[50,512,20\]\{[\d,]+:T\(8,128\)", s)], fused
+    assert not [line for line in ops + inside if _COLLECTIVE.search(line)]
+    # One pick's merge, whether the loop over picks is there (24) or
+    # unrolled away (1).
+    collectives = [line.strip() for line in text.splitlines() if _COLLECTIVE.search(line)]
+    assert len(collectives) <= 2, "\n".join(collectives)

@@ -6,8 +6,12 @@ agrees with the single-device suggest() within tolerance on a peaked
 objective.
 """
 
+import functools
+
 import numpy as np
 import jax
+import jax.numpy as jnp
+import pytest
 
 from vizier_tpu import pyvizier as vz
 from vizier_tpu.algorithms import core as core_lib
@@ -231,7 +235,7 @@ class TestShardedQEI:
         q, dc = 2, 2
         target = jnp.asarray([0.2, 0.8, 0.7, 0.3])  # one optimum per slot
 
-        def score_fn(feats):
+        def score_fn(target, feats):
             return -jnp.sum((feats.continuous - target) ** 2, axis=-1)
 
         strategy = eagle_lib.VectorizedEagleStrategy(
@@ -242,10 +246,14 @@ class TestShardedQEI:
         n_pools = len(mesh.devices.flat)
         key = jax.random.PRNGKey(9)
         sharded = parallel.maximize_score_fn_sharded(
-            vec, score_fn, key, count=1, num_pools=n_pools, mesh=mesh
+            vec, score_fn, target, key, count=1, num_pools=n_pools, mesh=mesh
         )
         pool_best = [
-            float(vec(score_fn, jnp.asarray(k), count=1).scores[0])
+            float(
+                vec(
+                    functools.partial(score_fn, target), jnp.asarray(k), count=1
+                ).scores[0]
+            )
             for k in np.asarray(jax.random.split(key, n_pools))
         ]
         np.testing.assert_allclose(
@@ -256,3 +264,137 @@ class TestShardedQEI:
             np.asarray(sharded.features.continuous[0]), np.asarray(target),
             atol=0.1,
         )
+
+
+def _bowl(operands, feats):
+    """A score with everything it reads in ``operands``: two planted optima,
+    the better one sharper."""
+    near, far = operands
+    d_near = jnp.sum((feats.continuous - near) ** 2, axis=-1)
+    d_far = jnp.sum((feats.continuous - far) ** 2, axis=-1)
+    return jnp.maximum(-d_near, -0.5 - 4.0 * d_far)
+
+
+def _vmapped_pools(
+    vec_opt, score_fn, operands, rng, count, num_pools, mesh, prior_features=None
+):
+    """The sharded sweep as it was while the pools were a ``vmap`` axis for
+    the partitioner to split (before PR 40), under today's signature."""
+    from vizier_tpu import parallel
+
+    keys = jax.lax.with_sharding_constraint(
+        jax.random.split(rng, num_pools), parallel.batch_sharded(mesh)
+    )
+    results = jax.vmap(
+        lambda key: vec_opt(
+            functools.partial(score_fn, operands),
+            key,
+            count=count,
+            prior_features=prior_features,
+        )
+    )(keys)
+    scores = results.scores.reshape(num_pools * count)
+    top_scores, idx = jax.lax.top_k(scores, count)
+    return type(results)(
+        jax.tree_util.tree_map(
+            lambda a: a.reshape((num_pools * count,) + a.shape[2:])[idx],
+            results.features,
+        ),
+        top_scores,
+    )
+
+
+def _eagle_sweep(max_evaluations: int):
+    from vizier_tpu.optimizers import eagle as eagle_lib
+    from vizier_tpu.optimizers import vectorized as vectorized_lib
+
+    strategy = eagle_lib.VectorizedEagleStrategy(num_continuous=3, category_sizes=())
+    return vectorized_lib.VectorizedOptimizer(strategy, max_evaluations=max_evaluations)
+
+
+class TestShardedSweepIsItsDefinition:
+    """The pools are a manual axis of the mesh: each device runs the plain
+    ``vec_opt`` on its own key(s). The same work as pool by pool on one
+    device: the same keys, every pool its full budget, one top-k."""
+
+    @pytest.mark.parametrize(
+        "num_pools,devices,count",
+        [(2, 2, 1), (2, 2, 3), (4, 4, 1), (4, 4, 3), (4, 2, 1), (4, 2, 3)],
+    )
+    def test_sharded_sweep_is_the_top_k_over_its_pools(
+        self, num_pools, devices, count
+    ):
+        from vizier_tpu import parallel
+        from vizier_tpu.models import kernels
+
+        vec = _eagle_sweep(400)
+        operands = (jnp.asarray([0.2, 0.8, 0.5]), jnp.asarray([0.7, 0.3, 0.1]))
+        prior = kernels.MixedFeatures(
+            jnp.asarray([[0.6, 0.4, 0.2], [0.1, 0.9, 0.4]]),
+            jnp.zeros((2, 0), jnp.int32),
+        )
+        key = jax.random.PRNGKey(11)
+        mesh = parallel.create_mesh(devices)
+        sharded = parallel.maximize_score_fn_sharded(
+            vec, _bowl, operands, key, count, num_pools, mesh, prior
+        )
+        pools = [
+            vec(
+                functools.partial(_bowl, operands),
+                k,
+                count=count,
+                prior_features=prior,
+            )
+            for k in jax.random.split(key, num_pools)
+        ]
+        scores = np.concatenate([np.asarray(p.scores) for p in pools])
+        cont = np.concatenate([np.asarray(p.features.continuous) for p in pools])
+        # ``top_k``'s order: descending, the lowest index among ties.
+        best = np.argsort(-scores, kind="stable")[:count]
+        assert sharded.scores.shape == (count,)
+        # To the bit on the CPU: a device's program is the pool's own.
+        np.testing.assert_array_equal(np.asarray(sharded.scores), scores[best])
+        np.testing.assert_array_equal(
+            np.asarray(sharded.features.continuous), cont[best]
+        )
+
+    def test_pools_must_tile_the_mesh(self):
+        from vizier_tpu import parallel
+
+        with pytest.raises(ValueError, match="multiple"):
+            parallel.maximize_score_fn_sharded(
+                _eagle_sweep(100), _bowl, (jnp.zeros(3), jnp.ones(3)), jax.random.PRNGKey(0),
+                1, 3, parallel.create_mesh(2),
+            )
+
+    def test_mesh_suggest_returns_what_the_vmapped_pools_returned(
+        self, monkeypatch
+    ):
+        """A UCB-PE suggest on the mesh, as the service's policy makes it,
+        returns the suggestions it returned while the pools were a ``vmap``
+        axis: the same keys, the same sweeps, the same merges."""
+        from vizier_tpu import parallel
+        from vizier_tpu.designers import gp_ucb_pe
+
+        def suggest():
+            d = VizierGPUCBPEBandit(
+                _problem(),
+                use_mesh=True,
+                ard_restarts=8,
+                ard_optimizer=_FAST_ARD,
+                max_acquisition_evaluations=600,
+                rng_seed=7,
+                num_seed_trials=2,
+            )
+            assert d._mesh is not None and len(d._mesh.devices.flat) == 8
+            return _suggest_xy(d, count=25)
+
+        manual = suggest()
+        monkeypatch.setattr(parallel, "maximize_score_fn_sharded", _vmapped_pools)
+        gp_ucb_pe._suggest_batch.clear_cache()  # it was traced with the map
+        try:
+            vmapped = suggest()
+        finally:
+            gp_ucb_pe._suggest_batch.clear_cache()
+        assert manual.shape == (25, 2)
+        np.testing.assert_array_equal(manual, vmapped)

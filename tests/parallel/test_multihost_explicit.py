@@ -80,7 +80,7 @@ _WORKER = textwrap.dedent(
 
     target = jnp.asarray([0.25, 0.75])
 
-    def score_fn(feats):
+    def score_fn(target, feats):
         return -jnp.sum((feats.continuous - target) ** 2, axis=-1)
 
     strategy = eagle_lib.VectorizedEagleStrategy(
@@ -91,7 +91,7 @@ _WORKER = textwrap.dedent(
     @jax.jit
     def run(key):
         res = parallel.maximize_score_fn_sharded(
-            vec, score_fn, key, 1, n_global, mesh
+            vec, score_fn, target, key, 1, n_global, mesh
         )
         return jax.lax.with_sharding_constraint(
             res, parallel.replicated(mesh)

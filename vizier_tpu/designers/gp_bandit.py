@@ -1149,7 +1149,11 @@ def _maximize_q_batch(
     ds = states.data.categorical.shape[-1]
     mc_rng = jax.random.fold_in(rng, 7)
 
-    def score_fn(flat: kernels.MixedFeatures) -> Array:
+    # Every array the score reads: the mesh sweep's replicated operands.
+    operands = (states, best_label, trust, mc_rng)
+
+    def score(operands, flat: kernels.MixedFeatures) -> Array:
+        states, best_label, trust, mc_rng = operands
         b = flat.continuous.shape[0]
         pts = flat.continuous.reshape(b, q, dc)
 
@@ -1184,14 +1188,17 @@ def _maximize_q_batch(
 
         return parallel.maximize_score_fn_sharded(
             vec_opt,
-            score_fn,
+            score,
+            operands,
             rng,
             count=1,
             num_pools=len(mesh.devices.flat),
             mesh=mesh,
             prior_features=prior,
         )
-    return vec_opt(score_fn, rng, count=1, prior_features=prior)
+    return vec_opt(
+        functools.partial(score, operands), rng, count=1, prior_features=prior
+    )
 
 
 # -- compute-IR programs (vizier_tpu.compute) --------------------------------

@@ -37,7 +37,7 @@ from __future__ import annotations
 import abc
 import dataclasses
 import functools
-from typing import Any, Callable, List, Optional, Sequence, Tuple
+from typing import Any, Callable, List, NamedTuple, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -408,6 +408,22 @@ def _scalarize_penalty(penalty: Array, mode: str) -> Array:
     return jnp.mean(penalty, axis=0)
 
 
+class _ScoreOperands(NamedTuple):
+    """Every array a pick's score reads (:func:`_suggest_batch`)."""
+
+    states_completed: Any  # GPState [M, E], or MultiTaskGPState [E]
+    states_all: Any  # the same, conditioned on the pending rows too
+    rows_all: Any  # exact GP: ``states_all``'s ``kernel_rows()``; else None
+    threshold: Array  # [M]
+    use_ucb: Array  # scalar bool
+    weights: Array  # [num_scalarizations, M]
+    trust: Optional[acquisitions.TrustRegion]
+    trust_radius: Array
+    ref_point: Array  # [M]
+    labels_mn: Array  # [M, N1]
+    labels_mask: Array  # [N1]
+
+
 @functools.partial(
     jax.jit,
     static_argnames=(
@@ -530,31 +546,48 @@ def _suggest_batch(
         )
         weights = weights / jnp.linalg.norm(weights, axis=-1, keepdims=True)
 
-        # query -> (mean, stddev) of the completed posterior and the
-        # all-points stddev, [M, Q] each.
-        if is_exact:
+        # Every array a score reads, as one pytree ``o``: under a mesh the
+        # sweep's manual map takes them as replicated operands (a closure's
+        # values cannot enter it); one chip binds them below.
+        operands = _ScoreOperands(
+            states_completed=states_completed,
+            states_all=states_all,
             # The data side of the candidates' cross-covariance, once a pick.
-            rows_all = jax.vmap(jax.vmap(lambda s: s.kernel_rows()))(states_all)
-            posteriors = functools.partial(
-                _exact_posterior_pair, states_completed, states_all, rows_all
-            )
-        else:
-            posteriors = lambda q: (  # noqa: E731
-                *mixture(states_completed, q), mixture(states_all, q)[1]
-            )
+            rows_all=(
+                jax.vmap(jax.vmap(lambda s: s.kernel_rows()))(states_all)
+                if is_exact
+                else None
+            ),
+            threshold=threshold,
+            use_ucb=use_ucb,
+            weights=weights,
+            trust=trust,
+            trust_radius=trust_radius,
+            ref_point=ref_point,
+            labels_mn=labels_mn,
+            labels_mask=labels_mask,
+        )
 
-        def score_fn(query: kernels.MixedFeatures) -> Array:
-            mean_c, std_c, std_all = posteriors(query)
+        def score(o: _ScoreOperands, query: kernels.MixedFeatures) -> Array:
+            # (mean, stddev) of the completed posterior and the all-points
+            # stddev, [M, Q] each.
+            if is_exact:
+                mean_c, std_c, std_all = _exact_posterior_pair(
+                    o.states_completed, o.states_all, o.rows_all, query
+                )
+            else:
+                mean_c, std_c = mixture(o.states_completed, query)
+                std_all = mixture(o.states_all, query)[1]
             ucb_vals = mean_c + config.ucb_coefficient * std_all
             if num_metrics == 1:
                 ucb_score = ucb_vals[0]
             else:
                 ucb_score = _hv_scalarized(
-                    ucb_vals, weights, ref_point, labels_mn, labels_mask
+                    ucb_vals, o.weights, o.ref_point, o.labels_mn, o.labels_mask
                 )
             explore_ucb = mean_c + config.explore_region_ucb_coefficient * std_c
             penalty = config.cb_violation_penalty_coefficient * jnp.minimum(
-                explore_ucb - threshold[:, None], 0.0
+                explore_ucb - o.threshold[:, None], 0.0
             )
             if num_metrics == 1:
                 pe_score = std_all[0] + penalty[0]
@@ -562,24 +595,27 @@ def _suggest_batch(
                 pe_score = jnp.mean(std_all, axis=0) + _scalarize_penalty(
                     penalty, config.multimetric_promising_region_penalty_type
                 )
-            value = jnp.where(use_ucb, ucb_score, pe_score)
+            value = jnp.where(o.use_ucb, ucb_score, pe_score)
             if prior_acquisition is not None:
                 # Additive user prior over the space (reference adds it to
                 # both the UCB and PE scores, `gp_ucb_pe.py:377,419`).
                 value = value + prior_acquisition(query)
-            if trust is not None:
-                value = value - trust.penalty(query, trust_radius)
+            if o.trust is not None:
+                value = value - o.trust.penalty(query, o.trust_radius)
             return value
 
         if mesh is None:
             result = vec_opt(
-                score_fn, opt_rng, count=1, prior_features=prior_features
+                functools.partial(score, operands),
+                opt_rng,
+                count=1,
+                prior_features=prior_features,
             )
         else:
             from vizier_tpu import parallel
 
             result = parallel.maximize_score_fn_sharded(
-                vec_opt, score_fn, opt_rng, 1,
+                vec_opt, score, operands, opt_rng, 1,
                 len(mesh.devices.flat), mesh, prior_features,
             )
         x = kernels.MixedFeatures(

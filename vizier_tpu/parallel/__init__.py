@@ -9,16 +9,26 @@ path shard across a ``jax.sharding.Mesh``:
 - **pools** — independent Eagle pools of the acquisition sweep (each device
   runs its own ask-evaluate-tell loop; results merge with one final top-k).
 
-All three are batch axes of already-vmapped jitted programs, so sharding is
-pure ``NamedSharding`` annotation — XLA partitions the programs and inserts
-any collectives over ICI. Gradients/Cholesky stay device-local. What the
-partitioner puts in, compiled for a 4-chip v5e at 512 rows (PERF.md §5,
-PR 35): one scalar all-reduce in the *condition* of the L-BFGS ``while`` and
-one in its line search's (the vmapped loops go on while any restart on any
-device does, so every iteration of both is a rendezvous of the mesh and the
-train lasts as long as its slowest restart), then one gather of the losses
-and of the best member; the sweep's ``while`` holds none, and each pick's
-top-k merge is two small all-reduces.
+Restarts and ensemble members are batch axes of already-vmapped jitted
+programs, so sharding them is pure ``NamedSharding`` annotation — XLA
+partitions the train and inserts any collectives over ICI. Gradients/Cholesky
+stay device-local. What the partitioner puts in, compiled for a 4-chip v5e at
+512 rows (PERF.md §5, PR 35): one scalar all-reduce in the *condition* of the
+L-BFGS ``while`` and one in its line search's (the vmapped loops go on while
+any restart on any device does, so every iteration of both is a rendezvous of
+the mesh and the train lasts as long as its slowest restart), then one gather
+of the losses and of the best member.
+
+The pools are a *manual* axis instead (``jax.shard_map`` in
+:func:`maximize_score_fn_sharded`): a device's program is the plain
+``VectorizedOptimizer`` sweep on its own key, what one chip runs — compiled
+for the same 4-chip v5e, 27 launched operations an eagle iteration, none with
+a pool axis, no collective inside the loop — and each pick's global top-k
+merge is two small all-reduces (the scores, the winner's row). As a ``vmap``
+axis that the partitioner split, every device kept a pool axis of one, which
+the compiler laid second-minor in the body's reduce fusions and tiled
+``T(1,128)``, one sublane of eight: a device's sweep took 2.4 × one chip's
+(PERF.md §6, PR 40; ``tests/compute/test_tpu_compile.py`` holds the body).
 """
 
 from __future__ import annotations
@@ -171,6 +181,7 @@ def train_gp_sharded(
 def maximize_score_fn_sharded(
     vec_opt: vectorized_lib.VectorizedOptimizer,
     score_fn,
+    operands,
     rng: Array,
     count: int,
     num_pools: int,
@@ -183,14 +194,52 @@ def maximize_score_fn_sharded(
     ``num_pools ×`` that, wall-clock ≈ one pool when num_pools == mesh size.
     The merge is a single global top-k. Traceable (callable from inside
     larger jitted programs, e.g. the UCB-PE batch loop).
+
+    The pools are a *manual* axis (``jax.shard_map``), not a ``vmap`` axis
+    for the partitioner to split: a device's program is ``vec_opt`` itself on
+    that device's key — the sweep a one-chip caller runs, with no pool axis
+    in it (module docstring). ``score_fn(operands, query) -> [Q]`` reads
+    every array it needs from ``operands``, a pytree that reaches each device
+    replicated: the values a closure would capture are laid out on the
+    caller's automatic mesh, and the manual one refuses them. ``num_pools``
+    is a multiple of the mesh size; a device with more than one pool runs
+    them one after another.
     """
-    keys = jax.random.split(rng, num_pools)
-    keys = jax.lax.with_sharding_constraint(keys, batch_sharded(mesh))
+    axis = mesh.axis_names[0]
+    per_device, remainder = divmod(num_pools, mesh.shape[axis])
+    if remainder or not per_device:
+        raise ValueError(
+            f"num_pools={num_pools} must be a positive multiple of the "
+            f"mesh's {mesh.shape[axis]} devices."
+        )
+    keys = jax.random.split(rng, num_pools)  # pool i on device i // per_device
 
-    def run_pool(key: Array) -> vectorized_lib.VectorizedOptimizerResult:
-        return vec_opt(score_fn, key, count=count, prior_features=prior_features)
+    def run_pools(keys, operands, prior_features):
+        # This device's [per_device] keys -> its [per_device, count, ...] best.
+        def run_pool(key: Array) -> vectorized_lib.VectorizedOptimizerResult:
+            return vec_opt(
+                functools.partial(score_fn, operands),
+                key,
+                count=count,
+                prior_features=prior_features,
+            )
 
-    results = jax.vmap(run_pool)(keys)  # [pools, count, ...]
+        if per_device == 1:
+            return jax.tree_util.tree_map(lambda a: a[None], run_pool(keys[0]))
+        return jax.lax.map(run_pool, keys)
+
+    sweep = jax.shard_map(
+        run_pools,
+        mesh=mesh,
+        in_specs=(P(axis), P(), P()),
+        out_specs=P(axis),
+        # The sweep's loop starts from constants (zeros, -inf) and carries
+        # per-device values: typed, every such carry would need a cast.
+        check_vma=False,
+    )
+    # [pools, count, ...] over the devices. The ``jit`` is for a caller
+    # outside one (a map run eagerly refuses its own output's sharding).
+    results = jax.jit(sweep)(keys, operands, prior_features)
     flat = num_pools * count  # explicit: -1 breaks on zero-width categorical
     flat_scores = results.scores.reshape(flat)
     flat_cont = results.features.continuous.reshape(
@@ -218,9 +267,15 @@ def maximize_acquisition_sharded(
     prior_features: Optional[kernels.MixedFeatures] = None,
 ) -> vectorized_lib.VectorizedOptimizerResult:
     """Pool-sharded sweep of a ScoringFunction pytree (jitted entry point)."""
-    scoring = jax.lax.with_sharding_constraint(scoring, replicated(mesh))
     return maximize_score_fn_sharded(
-        vec_opt, scoring.score, rng, count, num_pools, mesh, prior_features
+        vec_opt,
+        lambda scoring, query: scoring.score(query),
+        scoring,
+        rng,
+        count,
+        num_pools,
+        mesh,
+        prior_features,
     )
 
 
