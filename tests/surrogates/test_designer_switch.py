@@ -66,7 +66,9 @@ class TestAutoSwitch:
         d.update(core_lib.CompletedTrials(_trials(1, 5, seed=0)))
         d.suggest(1)
         assert d.surrogate_mode == config_lib.MODE_EXACT
-        assert d.surrogate_counts == {"sparse_suggests": 0, "crossovers": 0}
+        assert d.surrogate_counts == {
+            "sparse_suggests": 0, "crossovers": 0, "nystrom_augments": 0,
+        }
 
         d.update(core_lib.CompletedTrials(_trials(6, 3, seed=1)))
         out = d.suggest(1)

@@ -192,7 +192,7 @@ class TestTraining:
         _, model = _models(d, m)
         opt = lbfgs_lib.LbfgsOptimizer(maxiter=30)
 
-        state = sparse_bandit._train_sparse_gp(
+        state, _ = sparse_bandit._train_sparse_gp(
             model, opt, data, jax.random.PRNGKey(0), 4, 1, None
         )
         mean, _ = jax.tree_util.tree_map(lambda a: a[0], state).predict(
@@ -208,7 +208,7 @@ class TestTraining:
         warm = coll.unconstrain(
             jax.tree_util.tree_map(lambda a: a[0], state.params)
         )
-        warm_state = sparse_bandit._train_sparse_gp(
+        warm_state, _ = sparse_bandit._train_sparse_gp(
             model, opt, data, jax.random.PRNGKey(1), 2, 1, warm
         )
         mean2, _ = jax.tree_util.tree_map(lambda a: a[0], warm_state).predict(
@@ -240,7 +240,7 @@ class TestTraining:
         data = _data(n, d, seed=29)
         _, model = _models(d, m)
         opt = lbfgs_lib.LbfgsOptimizer(maxiter=10)
-        states = sparse_bandit._train_sparse_gp(
+        states, _ = sparse_bandit._train_sparse_gp(
             model, opt, data, jax.random.PRNGKey(2), 4, 2, None
         )
         pred = sparse_gp.SparseEnsemblePredictive(states)

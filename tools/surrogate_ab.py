@@ -131,7 +131,7 @@ def measure_latency(args) -> dict:
             k_train, k_acq = jax.random.split(key)
             t0 = time.perf_counter()
             if sparse:
-                states = sparse_bandit._train_sparse_gp(
+                states, _ = sparse_bandit._train_sparse_gp(
                     sparse_model, ard, data, k_train, restarts, 1, None
                 )
                 scoring = scoring_for(

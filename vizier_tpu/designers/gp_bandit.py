@@ -113,12 +113,9 @@ def read_train_work(phase, works: Sequence[Array]) -> Optional[Dict[str, int]]:
     """After ``phase.block``: what the phase's train programs counted of
     their own work (``lbfgs.work_counts``, summed over the programs), ONE
     small read a program, also written on the phase's span. None — nothing
-    read, nothing counted — when the phase is inert or trained nothing (a
-    ``work`` of None is a program whose trainer counts nothing)."""
+    read, nothing counted — when the phase is inert or trained nothing."""
     total: Optional[Dict[str, int]] = None
     for work in works:
-        if work is None:
-            continue
         fetched = phase.read(work)
         if fetched is None:
             return None
@@ -404,7 +401,9 @@ class VizierGPBandit(core_lib.Designer, core_lib.Predictor):
         self._surrogate_mode = surrogate_config_lib.MODE_EXACT
         self._sparse_model_cache: Optional[sparse_gp.SparseGaussianProcess] = None
         self._last_sparse_state: Optional[sparse_gp.SparseGPState] = None
-        self._surrogate_counts = {"sparse_suggests": 0, "crossovers": 0}
+        self._surrogate_counts = {
+            "sparse_suggests": 0, "crossovers": 0, "nystrom_augments": 0,
+        }
 
     # -- Designer ----------------------------------------------------------
 
@@ -621,7 +620,7 @@ class VizierGPBandit(core_lib.Designer, core_lib.Predictor):
         )
         with profiler.timeit("train_gp"):
             with jax_timing.device_phase("sparse_gp.train", stage="train") as phase:
-                states = sparse_bandit._train_sparse_gp(
+                states, work = sparse_bandit._train_sparse_gp(
                     model,
                     self._ard,
                     data,
@@ -631,6 +630,7 @@ class VizierGPBandit(core_lib.Designer, core_lib.Predictor):
                     self._warm_params,
                 )
                 phase.block(states)
+                self._record_train_work(read_train_work(phase, (work,)))
         self._record_train()
         if self._warm_update_allowed():
             coll = self._model.param_collection()
@@ -1272,8 +1272,7 @@ class _GPBanditFlush(compute_ir.DesignerProgram):
     def _flush(self, *args):
         """The jitted flush program of this family, looked up in its
         module when called (``tests/compute/test_tpu_compile.py`` swaps
-        it there): its outputs, the train's count of its work last (None
-        from a family whose trainer counts nothing)."""
+        it there): its outputs, the train's count of its work last."""
 
     @abc.abstractmethod
     def _keep_fit(self, designer: "VizierGPBandit", states) -> None:
@@ -1416,7 +1415,7 @@ class GPBanditSparseProgram(_GPBanditFlush):
         return designer._sparse_model()
 
     def _flush(self, *args):
-        return (*sparse_bandit._sparse_flush_program(*args), None)
+        return sparse_bandit._sparse_flush_program(*args)
 
     def _keep_fit(self, designer, states):
         designer._last_predictive = sparse_gp.SparseEnsemblePredictive(states)

@@ -297,7 +297,7 @@ def _append_row_sparse(
         ref_state.params, x, ref_state.sdata.z_features(), ref_state.sdata.data
     )  # [1, m]
     kz = jnp.where(ref_state.sdata.inducing_mask[None, :], kz, 0.0)
-    t1 = ref_state.linv @ kz[0]
+    t1 = jnp.matmul(ref_state.linv, kz[0], precision=gp_lib.POSTERIOR_PRECISION)
     amp2 = ref_state.params["amplitude"] * ref_state.params["amplitude"]
     residual = amp2 - jnp.sum(t1 * t1)
     augment = residual > _NYSTROM_RESIDUAL_FRACTION * amp2
@@ -322,10 +322,11 @@ def _append_row_sparse(
     )
 
 
-# The sequential path's three crossings between its compiled programs. Each
+# The sequential path's crossings between its compiled programs. Each
 # is ONE small program, compiled in set-up with the shapes it serves, where
 # the eager form launched one program per operation and per leaf.
-# ``_stack_fits`` and ``_append_first_pick`` only move and select values.
+# ``_stack_fits``, ``_sparse_all_points`` and ``_append_first_pick`` only move
+# and select values.
 # ``_sweep_inputs`` computes (the reference point is a min, a max, a multiply
 # and a subtract per metric; the prior features a ``top_k`` and a sum), so
 # that its bits are the eager form's is measured, not given: suggestions and
@@ -357,6 +358,19 @@ def _sweep_inputs(datas: Tuple[gp_lib.GPData, ...]):
     ref_point = acquisitions.get_reference_point(labels_mn, labels_mask)
     prior = gp_bandit._prior_features_from_data(datas[0])
     return labels_mn, labels_mask, ref_point, prior
+
+
+@functools.partial(jax.jit, static_argnames=("count",))
+def _sparse_all_points(
+    states_me: sparse_gp.SparseGPState, all_data: gp_lib.GPData, count: int
+) -> sparse_gp.SparseGPData:
+    """The all-points rows over the trained inducing set (metric 0's member
+    0: every member shares it) with ``count`` spare Nystrom slots. Eager,
+    this was a slice a leaf and four concatenates between the train and the
+    sweeps: ~43 ms of a sparse suggest on a v5e that no stage span named
+    (PERF.md section 6, PR 41)."""
+    sdata0 = jax.tree_util.tree_map(lambda a: a[0, 0], states_me.sdata)
+    return sparse_gp.with_pending_capacity(sdata0, all_data, count)
 
 
 @jax.jit
@@ -649,10 +663,16 @@ def _suggest_batch(
         init_aux,
         rng,
     )
-    _, out_cont, out_cat, out_scores, aux, _ = jax.lax.fori_loop(
+    grown, out_cont, out_cat, out_scores, aux, _ = jax.lax.fori_loop(
         0, count, pick, init
     )
     aux["trust_radius"] = trust_radius
+    if is_sparse:
+        # Picks of this sweep that joined the inducing set (a sparse
+        # program's own result: the exact and multitask programs have none).
+        aux["nystrom_augments"] = jnp.sum(
+            grown.inducing_mask.astype(jnp.int32)
+        ) - jnp.sum(all_data.inducing_mask.astype(jnp.int32))
     return (
         vectorized_lib.VectorizedOptimizerResult(
             kernels.MixedFeatures(out_cont, out_cat), out_scores
@@ -887,12 +907,13 @@ def _sparse_ucb_pe_flush_program(
     """The sparse twin of :func:`_ucb_pe_flush_program`: ONE device program
     per bucket flush — encode → k-center inducing selection → collapsed-
     bound ARD → the greedy UCB-PE batch with pending-pick conditioning
-    through the inducing posterior (Nyström-augmented) → warm seed. A
+    through the inducing posterior (Nyström-augmented) → warm seed, and
+    the train's own work counts last, as the exact twin hands them out. A
     slot matches its study run alone through the sequential sparse path.
     """
     data = jax.vmap(lambda m: gp_lib.GPData.from_model_data(m))(md)
     all_gp = jax.vmap(lambda m: gp_lib.GPData.from_model_data(m))(all_md)
-    states = jax.vmap(
+    states, work = jax.vmap(
         lambda d, k, w: sparse_bandit._train_sparse_gp(
             model, optimizer, d, k, num_restarts, ensemble_size, w
         )
@@ -929,7 +950,7 @@ def _sparse_ucb_pe_flush_program(
             first_has_new, has_completed, count, config, use_trust_region,
         )
         segments = ((batch, aux),)
-    return states, warm_next, data, segments
+    return states, warm_next, data, segments, work
 
 
 def _train_mt_gp(
@@ -1215,7 +1236,7 @@ class VizierGPUCBPEBandit(gp_bandit.VizierGPBandit):
             restarts = max(
                 self._warm_restart_budget() or self.ard_restarts, ensemble
             )
-            states = sparse_bandit._train_sparse_gp(
+            states, work = sparse_bandit._train_sparse_gp(
                 model,
                 self._ard,
                 datas[0],
@@ -1224,6 +1245,7 @@ class VizierGPUCBPEBandit(gp_bandit.VizierGPBandit):
                 ensemble,
                 self._warm_params_me[0],
             )
+            self._unread_train_work = (work,)
             self._record_train()
             states_me, best = _stack_fits((states,))
             self._seed_next_trains(best)
@@ -1465,10 +1487,7 @@ class VizierGPUCBPEBandit(gp_bandit.VizierGPBandit):
             # one spare Nyström slot per pick; the augmented-capacity model
             # re-conditions per pick in O(n·m²) instead of O(n³).
             model = self._sparse_all_model(count)
-            sdata0 = jax.tree_util.tree_map(
-                lambda a: a[0, 0], states_me.sdata
-            )
-            all_data = sparse_gp.with_pending_capacity(sdata0, all_data, count)
+            all_data = _sparse_all_points(states_me, all_data, count)
         else:
             model = self._model
         results: List[Tuple] = []  # [(result, aux, rows)]
@@ -1625,6 +1644,7 @@ class VizierGPUCBPEBandit(gp_bandit.VizierGPBandit):
                     aux["stddev_from_all"],
                     aux["use_ucb"],
                     aux["trust_radius"],
+                    aux.get("nystrom_augments"),  # a sparse sweep's; else None
                 )
                 for result, aux, _ in segments
             ]
@@ -1632,10 +1652,11 @@ class VizierGPUCBPEBandit(gp_bandit.VizierGPBandit):
         # Each sweep's first ``rows`` picks, one after another; a sweep's one
         # trust radius is every one of its picks'.
         columns: List[List[np.ndarray]] = [[] for _ in range(8)]
-        for (*per_pick, radius), (_, _, rows) in zip(fetched, segments):
+        for (*per_pick, radius, augments), (_, _, rows) in zip(fetched, segments):
             for column, values in zip(columns, per_pick):
                 column.append(np.asarray(values)[:rows])
             columns[7].append(np.full(rows, radius))
+            self._surrogate_counts["nystrom_augments"] += int(augments or 0)
         cont, cat, scores, mean, stddev, stddev_all, use_ucb, trust_radius = (
             np.concatenate(column) for column in columns
         )
@@ -1812,8 +1833,7 @@ class _UCBPEFlush(compute_ir.DesignerProgram):
     def _flush(self, *args):
         """The jitted flush program of this family, looked up in its
         module when called (``tests/compute/test_tpu_compile.py`` swaps
-        it there): its outputs, the train's count of its work last (None
-        from a family whose trainer counts nothing)."""
+        it there): its outputs, the train's count of its work last."""
 
     @abc.abstractmethod
     def _keep_fit(self, designer: "VizierGPUCBPEBandit", states) -> None:
@@ -1981,7 +2001,7 @@ class UCBPESparseProgram(_UCBPEFlush):
         return (designer._sparse_model(), designer._sparse_all_model(count))
 
     def _flush(self, *args):
-        return (*_sparse_ucb_pe_flush_program(*args), None)
+        return _sparse_ucb_pe_flush_program(*args)
 
     def _keep_fit(self, designer, states):
         designer._last_predictive = sparse_gp.SparseEnsemblePredictive(states)

@@ -240,6 +240,9 @@ class CachedDesignerStatePolicy(policy_lib.Policy):
         crossed = after.get("crossovers", 0) - before.get("crossovers", 0)
         if sparse > 0:
             stats.increment("sparse_suggests", sparse)
+        joined = after.get("nystrom_augments", 0) - before.get("nystrom_augments", 0)
+        if joined > 0:
+            stats.increment("nystrom_augments", joined)
         if crossed > 0:
             stats.increment("surrogate_crossovers", crossed)
             recorder_lib.get_recorder().record(

@@ -93,6 +93,7 @@ class ServingStats:
         "mesh_suggests",  # GP suggests whose device programs ran on it
         # Scalable surrogates (vizier_tpu.surrogates).
         "sparse_suggests",  # suggests served by the sparse-GP posterior
+        "nystrom_augments",  # picks of sparse UCB-PE suggests that joined the inducing set
         "surrogate_crossovers",  # exact<->sparse auto-switch transitions
         # Speculative pre-compute (vizier_tpu.serving.speculative).
         "speculative_hits",  # suggests served from a parked pre-computed batch

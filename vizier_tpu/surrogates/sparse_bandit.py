@@ -21,7 +21,7 @@ imports it), so it depends only on models/optimizers/acquisitions.
 from __future__ import annotations
 
 import functools
-from typing import Optional
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -37,30 +37,55 @@ from vizier_tpu.surrogates import sparse_gp
 Array = jax.Array
 
 
-def _heuristic_init(coll) -> gp_lib.Params:
-    """A deterministic mid-scale restart seed for the collapsed bound.
+def _heuristic_init(coll, data: gp_lib.GPData) -> gp_lib.Params:
+    """A deterministic restart seed inside the collapsed bound's sound
+    basin: the model's three scales read off the data's own.
 
-    The Titsias trace term 1/(2σ²)·tr(Knn − Qnn) is stiff at small noise:
-    a random init with tiny ``noise_stddev`` sees a huge penalty whose
-    gradient drives the amplitude to its lower clip before the noise can
-    rise, and EVERY random restart can land in that degenerate
-    (amp→min, ls→max, noise→max) corner — measured on a 60×3 study, 8/8
-    random restarts collapsed there while the exact GP trained fine. One
-    always-present init at unit scales (labels are z-scored by the output
-    warper, so amplitude=1 / length-scale=1 / noise=0.1 is the
-    neutral prior) starts inside the well-behaved basin and reliably
-    converges to the non-degenerate optimum; the random restarts keep
-    their full exploration role on top.
+    The Titsias trace term 1/(2σ²)·tr(Knn − Qnn) is stiff wherever the
+    inducing rows explain little of the other rows: its gradient drives the
+    amplitude to its lower clip before the length scales can grow, and a
+    row that starts there ends in the noise-only corner (amplitude at its
+    floor, noise = the labels' stddev, length scales back on the priors'
+    centre). EVERY random restart starts there — they are drawn around the
+    priors' centre, length scale 0.3 — measured on a 60×3 study (8/8) and
+    at 600 × 20-D (PERF.md section 6, PR 41), where a start at unit scales
+    (length scale 1, amplitude 1) collapsed too: the rows of a 20-D unit
+    cube lie ~1.8 apart and their warped labels spread by ~0.16. So this
+    row starts where m rows DO explain the others: every continuous length
+    scale at the data's width (:func:`_data_width`: one length scale spans
+    the study), the amplitude at the labels' stddev and the noise at half
+    of it; both the exact and the sparse fit of such a study end at length
+    scales of that order. Parameters with no such scale start at 1.
     """
+    valid = data.row_mask.astype(jnp.float32)
+    count = jnp.maximum(jnp.sum(valid), 1.0)
+    mean = jnp.sum(valid * data.labels) / count
+    spread = jnp.sqrt(jnp.sum(valid * (data.labels - mean) ** 2) / count)
+    specs = {spec.name: spec for spec in coll.specs}
     constrained = {
-        spec.name: jnp.full(
-            spec.shape,
-            0.1 if spec.name == "noise_stddev" else 1.0,
-            jnp.float32,
-        )
-        for spec in coll.specs
+        name: jnp.full(spec.shape, 1.0, jnp.float32) for name, spec in specs.items()
     }
+    for name, scale in (
+        ("amplitude", spread),
+        ("noise_stddev", 0.5 * spread),
+        ("continuous_length_scales", _data_width(data)),
+    ):
+        spec = specs.get(name)
+        if spec is not None:  # (clipped: a study of equal labels, or of one row)
+            constrained[name] = jnp.full(
+                spec.shape, jnp.clip(scale, spec.init_low, spec.bijector.high), jnp.float32
+            )
     return coll.unconstrain(constrained)
+
+
+def _data_width(data: gp_lib.GPData) -> Array:
+    """The diagonal of the valid rows' bounding box over the live continuous
+    dimensions."""
+    rows = data.row_mask[:, None]
+    high = jnp.max(jnp.where(rows, data.continuous, -jnp.inf), axis=0)
+    low = jnp.min(jnp.where(rows, data.continuous, jnp.inf), axis=0)
+    span = jnp.where(data.cont_dim_mask & jnp.any(data.row_mask), high - low, 0.0)
+    return jnp.sqrt(jnp.sum(span * span))
 
 
 @functools.partial(
@@ -74,19 +99,31 @@ def _train_sparse_gp(
     num_restarts: int,
     ensemble_size: int,
     warm_start: Optional[gp_lib.Params] = None,
-) -> sparse_gp.SparseGPState:
-    """Sparse ARD: k-center inducing selection → restarts → L-BFGS → top-k.
+) -> Tuple[sparse_gp.SparseGPState, Array]:
+    """Sparse ARD: k-center inducing selection → restarts → L-BFGS → top-k,
+    and the optimizer's own count of its work (``OptimizeResult.work``, as
+    ``gp_bandit._train_gp`` hands it out).
 
     The inducing set is selected INSIDE the program (deterministic given
     the data) and shared by every restart; ``warm_start`` is prepended as
     an extra restart row, identical to ``gp_bandit._train_gp``, after the
-    deterministic :func:`_heuristic_init` row that anchors the restart
-    pool outside the collapsed bound's degenerate basin.
+    deterministic :func:`_heuristic_init` row that starts inside the
+    collapsed bound's sound basin.
+
+    The rows' end points are ranked by the BOUND each reaches, not by the
+    regularised loss each was optimised under. Just past the switch the two
+    disagree: at 600 × 20-D the noise-only corner pays nothing to the
+    length scales' log-normal priors (it sits on their centre) while a fit
+    that explains the labels pays ~80 nats for twenty length scales of 3-20,
+    which is about what 128 inducing rows let the bound gain there — so the
+    loss prefers, by a few nats, the row that explains nothing, on about one
+    study in three (PERF.md section 6, PR 41). The priors keep each row's
+    path well-posed; which basin serves the study is the evidence's to say.
     """
     sdata = sparse_gp.select_inducing_kcenter(data, model.num_inducing)
     coll = model.param_collection()
     inits = coll.batch_random_init_unconstrained(rng, num_restarts)
-    rows = [_heuristic_init(coll)]
+    rows = [_heuristic_init(coll, data)]
     if warm_start is not None:
         rows.insert(0, warm_start)
     inits = jax.tree_util.tree_map(
@@ -95,8 +132,13 @@ def _train_sparse_gp(
         inits,
     )
     loss_fn = lambda p: model.neg_log_likelihood(p, sdata)
-    result = optimizer(loss_fn, inits, best_n=ensemble_size)
-    return jax.vmap(lambda p: model.precompute(p, sdata))(result.params)
+    num_rows = num_restarts + len(rows)
+    result = optimizer(loss_fn, inits, best_n=num_rows)  # every row, best loss first
+    pull = jax.vmap(lambda p: coll.regularization(coll.constrain(p)))(result.params)
+    _, keep = jax.lax.top_k(pull - jnp.sort(result.losses), ensemble_size)  # the best bounds
+    params = jax.tree_util.tree_map(lambda a: a[keep], result.params)
+    states = jax.vmap(lambda p: model.precompute(p, sdata))(params)
+    return states, result.work()
 
 
 @functools.partial(jax.jit, static_argnames=("vec_opt", "count"))
@@ -172,11 +214,12 @@ def _sparse_flush_program(
 ):
     """ONE device program per sparse-bucket flush: encode → select inducing
     → train collapsed bound → sweep → warm seed. The sparse twin of
-    ``gp_bandit._gp_bandit_flush_program``; slot i matches study i run
+    ``gp_bandit._gp_bandit_flush_program`` (its fourth result too: the
+    train's own work counts, ``[B, 2, rows]``); slot i matches study i run
     alone through the sequential sparse path.
     """
     data = jax.vmap(lambda m: gp_lib.GPData.from_model_data(m))(md)
-    states = jax.vmap(
+    states, work = jax.vmap(
         lambda d, k, w: _train_sparse_gp(
             model, optimizer, d, k, num_restarts, ensemble_size, w
         )
@@ -186,4 +229,4 @@ def _sparse_flush_program(
             vec_opt, acquisition, s, d, k, count, use_trust_region
         )
     )(states, data, rng_acq)
-    return states, _warm_next_batched(model, states), result
+    return states, _warm_next_batched(model, states), result, work

@@ -212,6 +212,13 @@ class SparseGaussianProcess:
         Padded inducing slots have zero A rows ⇒ unit rows of B ⇒ unit LB
         diagonal and zero c entries; padded data rows have zero A columns
         and zero labels — both drop out of every term below.
+
+        The two products over the data axis run at full f32 precision
+        (``models.gp`` ``POSTERIOR_PRECISION``), like the predictive's own:
+        B's entries reach 1e5 beside a smallest eigenvalue near 1, and at
+        the TPU's default (one bf16 pass) the posterior's stddev came out
+        0.05-1.0 label stddevs off float64 on a v5e where the CPU's f32
+        is 2e-5 off (PERF.md section 6, PR 41).
         """
         kmm = self._masked_kmm(p, sdata)
         knm = self._masked_knm(p, sdata)
@@ -219,10 +226,13 @@ class SparseGaussianProcess:
         sigma2 = p["noise_stddev"] * p["noise_stddev"] + _JITTER
         sigma = jnp.sqrt(sigma2)
         a = jax.scipy.linalg.solve_triangular(chol, knm.T, lower=True) / sigma
-        b = jnp.eye(a.shape[0], dtype=a.dtype) + a @ a.T
+        precision = gp_lib.POSTERIOR_PRECISION
+        b = jnp.eye(a.shape[0], dtype=a.dtype) + jnp.matmul(a, a.T, precision=precision)
         chol_b = jnp.linalg.cholesky(b)
         c = (
-            jax.scipy.linalg.solve_triangular(chol_b, a @ sdata.data.labels, lower=True)
+            jax.scipy.linalg.solve_triangular(
+                chol_b, jnp.matmul(a, sdata.data.labels, precision=precision), lower=True
+            )
             / sigma
         )
         return chol, chol_b, a, c, sigma2
@@ -288,14 +298,19 @@ class SparseGaussianProcess:
         lb_inv = jax.scipy.linalg.solve_triangular(chol_b, eye, lower=True)
         # mean(x*) = k*ᵀ L⁻ᵀ LB⁻ᵀ c — fold the two back-substitutions into
         # one [M] weight vector; var needs both inverses separately.
-        w = linv.T @ (lb_inv.T @ c)
+        # (At full f32 precision too: ``lb_linv`` is a product of two
+        # triangular inverses whose entries the variance squares.)
+        precision = gp_lib.POSTERIOR_PRECISION
+        w = jnp.matmul(
+            linv.T, jnp.matmul(lb_inv.T, c, precision=precision), precision=precision
+        )
         return SparseGPState(
             model=self,
             params=p,
             sdata=sdata,
             w=w,
             linv=linv,
-            lb_linv=lb_inv @ linv,
+            lb_linv=jnp.matmul(lb_inv, linv, precision=precision),
         )
 
 
