@@ -41,6 +41,7 @@ from vizier_tpu.designers.gp import acquisitions
 from vizier_tpu.models import gp as gp_lib
 from vizier_tpu.models import kernels
 from vizier_tpu.surrogates import config as surrogate_config_lib
+from vizier_tpu.surrogates import sparse_gp
 
 DIM = 20
 COUNT = 25
@@ -315,13 +316,24 @@ def test_exact_flush_pad512_compiles(monkeypatch, one_chip):
     )
     assert key.kind == "gp_ucb_pe" and key.pad_trials == 512
     assert designer._vec_opt.max_evaluations == 75_000
-    mem = _fits(lowered.compile())
+    compiled = lowered.compile()
+    mem = _fits(compiled)
     # 1,299 MB while the NLL read diag(L) with ``jnp.diagonal`` (a batch-minor
     # relayout of the [8, 5, 512, 512] factors), 255 MB since (PR 34), 111 MB
     # since the L-BFGS step takes the accepted point's gradient from the
     # search's evaluation (PR 36: the factors it carries, 1.1 MB a row, are
     # less than the second forward pass's temporaries were).
     assert mem.temp_size_in_bytes < 512 * 1024**2, mem
+    # The sweeps' kernel passes never had the sequential program's disease
+    # (``test_eagle_loop_body_launches_what_it_needs``): under the ``vmap``
+    # over slots the slot axis is the second-minor one, eight full sublanes,
+    # with the unit metric and member axes there (PR 41 and before) or not.
+    passes = set(
+        re.findall(r" (f32\[8,(?:1,1,)?50,512,20\]\{[^}]*\})", compiled.as_text())
+    )
+    assert passes
+    thin = [s for s in passes if not re.search(r"\]\{\d,0,[\d,]+:T\(8,128\)", s)]
+    assert not thin, thin
 
 
 def test_sparse_flush_pad1024_compiles(monkeypatch, one_chip):
@@ -334,6 +346,15 @@ def test_sparse_flush_pad1024_compiles(monkeypatch, one_chip):
     assert key.kind == "gp_ucb_pe_sparse" and key.pad_trials == 1024
     assert designer._sparse_model().num_inducing == 128
     _fits(lowered.compile())
+
+
+def _param_shapes(model):
+    """Shapes of one member's unconstrained hyperparameters."""
+    return jax.eval_shape(
+        lambda: model.param_collection().random_init_unconstrained(
+            jax.random.PRNGKey(0)
+        )
+    )
 
 
 def _gp_state_shapes(designer, n_pad: int, sharding):
@@ -349,11 +370,7 @@ def _gp_state_shapes(designer, n_pad: int, sharding):
             cat_dim_mask=jnp.ones((0,), bool),
         )
     )
-    params = jax.eval_shape(
-        lambda: model.param_collection().random_init_unconstrained(
-            jax.random.PRNGKey(0)
-        )
-    )
+    params = _param_shapes(model)
     states = jax.eval_shape(
         lambda p, d: jax.vmap(lambda q: model.precompute(q, d))(
             jax.tree_util.tree_map(lambda a: a[None], p)
@@ -369,14 +386,7 @@ def _lower_train(designer, n_pad: int, restarts: int, sharding):
     model = designer._model
     data, _ = _gp_state_shapes(designer, n_pad, sharding)
     key = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=sharding)
-    warm = _as_shapes(
-        jax.eval_shape(
-            lambda: model.param_collection().random_init_unconstrained(
-                jax.random.PRNGKey(0)
-            )
-        ),
-        sharding,
-    )
+    warm = _as_shapes(_param_shapes(model), sharding)
     return gp_bandit._train_gp.lower(
         model, designer._ard, data, key, restarts, 1, warm
     )
@@ -441,14 +451,7 @@ def test_posterior_ucb_forward_compiles(one_chip):
     designer = _designer(1)
     model = designer._model
     data, _ = _gp_state_shapes(designer, 1024, one_chip)
-    params = _as_shapes(
-        jax.eval_shape(
-            lambda: model.param_collection().random_init_unconstrained(
-                jax.random.PRNGKey(0)
-            )
-        ),
-        one_chip,
-    )
+    params = _as_shapes(_param_shapes(model), one_chip)
     query = kernels.MixedFeatures(
         jax.ShapeDtypeStruct((256, DIM), jnp.float32, sharding=one_chip),
         jax.ShapeDtypeStruct((256, 0), jnp.int32, sharding=one_chip),
@@ -477,16 +480,49 @@ def test_sequential_crossings_compile(one_chip):
     _fits(gp_ucb_pe._append_first_pick.lower(data, picked).compile())
 
 
-def _lower_suggest_batch(designer, n_pad: int, count: int, sharding, mesh=None):
-    """``_suggest_batch`` as the sequential path calls it on the exact GP:
-    one metric, one member, the trained and the all-points data at
-    ``n_pad`` rows, trust region on; with a ``mesh``, the pools of each
-    pick's sweep over its devices."""
-    data, states = _gp_state_shapes(designer, n_pad, sharding)
-    states_me = jax.tree_util.tree_map(
-        lambda a: jax.ShapeDtypeStruct((1,) + a.shape, a.dtype, sharding=sharding),
-        states,
+def _sparse_state_shapes(designer, n_pad: int, count: int, sharding):
+    """Shapes of an [M=1, E=1] trained SGPR ensemble at ``n_pad`` rows over
+    the model's inducing slots, and of its all-points twin with ``count``
+    spare slots (``_sparse_all_points``), no training."""
+    model = designer._sparse_model()
+    data, _ = _gp_state_shapes(designer, n_pad, sharding)
+    params = _param_shapes(model)
+    states_me = jax.eval_shape(
+        lambda p, d: jax.vmap(
+            jax.vmap(
+                lambda q: model.precompute(
+                    q, sparse_gp.select_inducing_kcenter(d, model.num_inducing)
+                )
+            )
+        )(jax.tree_util.tree_map(lambda a: a[None, None], p)),
+        params,
+        data,
     )
+    all_data = jax.eval_shape(
+        lambda s, d: gp_ucb_pe._sparse_all_points(s, d, count), states_me, data
+    )
+    return _as_shapes(states_me, sharding), _as_shapes(all_data, sharding)
+
+
+def _lower_suggest_batch(
+    designer, n_pad: int, count: int, sharding, mesh=None, sparse=False
+):
+    """``_suggest_batch`` as the sequential path calls it: one metric, one
+    member, the trained and the all-points data at ``n_pad`` rows, trust
+    region on; with a ``mesh``, the pools of each pick's sweep over its
+    devices. On the exact GP, or (``sparse``) on the SGPR tier of a
+    ``suggest(COUNT)``: the all-points twin holds ``COUNT`` spare slots for
+    both of its sweeps, the first pick's and the other ``COUNT - 1``'s."""
+    if sparse:
+        model = designer._sparse_all_model(COUNT)
+        states_me, all_data = _sparse_state_shapes(designer, n_pad, COUNT, sharding)
+    else:
+        model = designer._model
+        all_data, states = _gp_state_shapes(designer, n_pad, sharding)
+        states_me = jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct((1,) + a.shape, a.dtype, sharding=sharding),
+            states,
+        )
 
     def shape(dims, dtype):
         return jax.ShapeDtypeStruct(dims, dtype, sharding=sharding)
@@ -495,30 +531,51 @@ def _lower_suggest_batch(designer, n_pad: int, count: int, sharding, mesh=None):
         shape((10, DIM), jnp.float32), shape((10, 0), jnp.int32)
     )
     return gp_ucb_pe._suggest_batch.lower(
-        designer._model, designer._vec_opt, states_me, data,
+        model, designer._vec_opt, states_me, all_data,
         shape((1, n_pad), jnp.float32), shape((n_pad,), bool),
         shape((1,), jnp.float32), prior, shape((2,), jnp.uint32),
         shape((), bool), shape((), bool), count, designer.config, True, mesh, None,
     )
 
 
-def _eagle_body(hlo_text: str) -> list[str]:
+def _eagle_body(hlo_text: str, scores: str = "f32[50,512]") -> list[str]:
     """The launched operations of the eagle loop: the one ``while`` whose
-    body evaluates the candidates against the data (the loop over picks
-    holds it and evaluates one point)."""
+    body evaluates the candidates against the data — a ``scores``-shaped
+    cross-covariance (the loop over picks holds it and evaluates one
+    point)."""
     eagle = [
         ops
         for ops in _launched_by_while(hlo_text).values()
-        if any(" f32[50,512]" in op for op in ops)
+        if any(f" {scores}" in op for op in ops)
     ]
     assert len(eagle) == 1, [len(ops) for ops in eagle]
     return eagle[0]
 
 
-def _assert_launches_what_it_needs(ops: list[str]):
-    assert len(ops) <= 30, "\n".join(ops)
+def _assert_launches_what_it_needs(
+    hlo_text: str, ops: list[str], limit: int, rows: tuple[int, ...]
+):
+    """At most ``limit`` launches an iteration, no sort, one key derivation;
+    and every kernel pass fills its vector registers: an operand
+    ``[..., 50, n, 20]`` of the body (candidates x ``n`` of ``rows`` x
+    features) has no axis in front — a unit metric or member axis that
+    ``vmap`` put there is laid second-minor and tiled ``T(1,128)``, one
+    sublane of a register's eight (PERF.md, PR 42) — and is tiled
+    ``T(8,128)``."""
+    assert len(ops) <= limit, "\n".join(ops)
     assert not [op for op in ops if " sort(" in op]
     assert len([op for op in ops if "_threefry_split" in op]) <= 1
+    inside = "\n".join(ops + _called_by(hlo_text, ops))
+    for n in rows:
+        passes = set(re.findall(rf" (f32\[(?:\d+,)*50,{n},20\]\{{[^}}]*\}})", inside))
+        assert passes, n
+        thin = [
+            s for s in passes if not re.match(rf"f32\[50,{n},20\]\{{[\d,]+:T\(8,128\)", s)
+        ]
+        assert not thin, thin
+
+
+def _assert_one_cross_covariance(ops: list[str]):
     passes = [
         op for op in ops if " f32[50,512]" in op and re.search(r'op_name="[^"]*reduce_sum"', op)
     ]
@@ -533,16 +590,40 @@ def test_eagle_loop_body_launches_what_it_needs(one_chip, count):
     ``default20d.lone25`` runs it (``count`` 1: the first pick's sweep;
     ``count`` 24: the loop over the other picks, the eagle loop inside it).
 
-    The parent's body launched 47: 14 of them key derivations
+    PR 37's body launched 47: 14 of them key derivations
     (``_threefry_split``), a sort for the best of 51, and the candidates'
-    ``[50, 512]`` cross-covariance twice, once a posterior."""
+    ``[50, 512]`` cross-covariance twice, once a posterior. PR 38's launched
+    27, its one cross-covariance pass fused over ``f32[1,1,50,512,20]``
+    tiled ``T(1,128)`` behind a copy of its own: 26 since the unit metric
+    and member axes stay out of it (``gp_ucb_pe._per_member``)."""
     designer = _designer(1)
     assert designer._vec_opt.strategy.batch_size == 50
     assert designer._vec_opt.max_evaluations == 75_000
     text = _lower_suggest_batch(designer, 512, count, one_chip).compile().as_text()
     ops = _eagle_body(text)
     print(f"count {count}: {len(ops)} launched operations an iteration")
-    _assert_launches_what_it_needs(ops)
+    _assert_launches_what_it_needs(text, ops, limit=26, rows=(512,))
+    _assert_one_cross_covariance(ops)
+
+
+@pytest.mark.parametrize("count", [1, 24])
+def test_sparse_eagle_loop_body_launches_what_it_needs(one_chip, count):
+    """The sequential sweep past the sparse switch, as
+    ``default20d-sparse.lone25`` runs it: pad 1,024 over 128 inducing rows,
+    153 with the spare slots of a ``suggest(25)``. Its score predicts each
+    posterior apart (``_mixture_predict``), so an iteration makes two kernel
+    passes, ``[50,128,20]`` and ``[50,153,20]``: both were tiled
+    ``T(1,128)`` under the unit metric and member axes, in a body of 36."""
+    designer = _designer(1, surrogate=surrogate_config_lib.SurrogateConfig())
+    assert designer._sparse_model().num_inducing == 128
+    text = (
+        _lower_suggest_batch(designer, 1024, count, one_chip, sparse=True)
+        .compile()
+        .as_text()
+    )
+    ops = _eagle_body(text, scores="f32[50,153]")
+    print(f"sparse, count {count}: {len(ops)} launched operations an iteration")
+    _assert_launches_what_it_needs(text, ops, limit=34, rows=(128, 153))
 
 
 _COLLECTIVE = re.compile(
@@ -577,7 +658,8 @@ def test_eagle_loop_body_on_a_mesh_is_the_one_chip_body(host4, count):
     one sublane of a register's eight: the pull was fused over
     ``f32[1,50,20,50]{3,0,2,1:T(1,128)}`` at ~15 us for one chip's 0.65, the
     trust region's pass over ``f32[1,50,512,20]`` at ~4.4 for 1.0, and the
-    body launched 33 operations for one chip's 27 (PERF.md, PR 40).
+    body launched 33 operations for one chip's 27 (PERF.md, PR 40; one
+    chip's own unit axes, the metric's and the member's, left with PR 42).
 
     What the compiler puts in for the mesh: nothing inside the eagle loop,
     and two all-reduces a pick — the scores' and the winner's row's."""
@@ -590,16 +672,14 @@ def test_eagle_loop_body_on_a_mesh_is_the_one_chip_body(host4, count):
     )
     ops = _eagle_body(text)
     print(f"count {count}, four chips: {len(ops)} launched operations an iteration")
-    _assert_launches_what_it_needs(ops)
+    _assert_launches_what_it_needs(text, ops, limit=26, rows=(512,))
+    _assert_one_cross_covariance(ops)
     inside = _called_by(text, ops)
     fused = set(re.findall(r" (f32\[[\d,]+\]\{[^}]*\})", "\n".join(inside)))
     pooled = sorted(
         shape
         for shape in fused
         if re.match(r"f32\[(?:1,)+(?:50,20,50|50,512,20|50,50,20)\]", shape)
-        # One chip's own: the cross-covariance pass carries the unit metric
-        # and member axes of ``_exact_posterior_pair``'s vmaps (PERF.md §7).
-        and not shape.startswith("f32[1,1,50,512,20]")
     )
     assert not pooled, pooled
     assert [s for s in fused if re.match(r"f32\[50,20,50\]\{[\d,]+:T\(8,128\)", s)], fused

@@ -17,6 +17,8 @@ from vizier_tpu.algorithms import core as core_lib
 from vizier_tpu.designers import gp_ucb_pe
 from vizier_tpu.models import kernels
 from vizier_tpu.optimizers import lbfgs as lbfgs_lib
+from vizier_tpu.surrogates import SurrogateConfig
+from vizier_tpu.surrogates import sparse_gp
 
 _FAST_ARD = lbfgs_lib.AdamOptimizer(maxiter=20)
 COUNT = 3
@@ -138,6 +140,87 @@ def test_posterior_pair_is_the_two_predicts(case):
     # The pending rows deflate the all-points stddev: the two posteriors differ.
     assert np.all(np.asarray(got[2]) <= np.asarray(got[1]) + 1e-5)
     assert np.any(np.asarray(got[2]) < np.asarray(got[1]) - 1e-4)
+
+
+def _pair_under_vmaps(states_me, states_all, rows_all, query):
+    """``_exact_posterior_pair`` as PR 38 wrote it: a ``vmap`` over metrics
+    of a ``vmap`` over members, whatever their number."""
+    n_completed = states_me.alpha.shape[-1]
+
+    def member(completed, everything, rows):
+        k_all = everything.cross_covariance(query, rows)
+        return (
+            *completed.predict_from_cross(k_all[:, :n_completed]),
+            *everything.predict_from_cross(k_all),
+        )
+
+    mean_c, std_c, mean_all, std_all = jax.vmap(jax.vmap(member))(
+        states_me, states_all, rows_all
+    )
+    return (
+        *gp_ucb_pe._moment_match(mean_c, std_c),
+        gp_ucb_pe._moment_match(mean_all, std_all)[1],
+    )
+
+
+def _mixture_under_vmaps(states, query):
+    return gp_ucb_pe._moment_match(
+        *jax.vmap(jax.vmap(lambda s: s.predict(query)))(states)
+    )
+
+
+_SPARSE = SurrogateConfig(sparse_threshold_trials=1, hysteresis_trials=0, num_inducing=6)
+
+
+@pytest.mark.parametrize(
+    "what,metrics,ensemble",
+    [
+        (what, metrics, ensemble)
+        for what in ("pair-exact", "mixture-exact", "mixture-sparse")
+        for metrics, ensemble in ((1, 1), (2, 1), (1, 2))
+        if (what, metrics) != ("mixture-sparse", 2)  # the sparse tier serves one metric
+    ],
+)
+def test_unit_metric_and_member_axes_stay_out_of_the_posteriors(what, metrics, ensemble):
+    """One metric x one member is evaluated unbatched, the two unit axes put
+    back on the [Q] outputs (``_per_member``): the bits of the double
+    ``vmap``, from a program that holds no ``[1, 1, Q, N]`` operand. Two
+    metrics or two members keep the ``[M, E, Q, N]`` operand of the double
+    ``vmap``."""
+    sparse = what == "mixture-sparse"
+    designer = _designer(
+        metrics, ensemble, 7, 2, **({"surrogate": _SPARSE} if sparse else {})
+    )
+    if sparse:
+        assert designer._refresh_ucb_pe_surrogate_mode() == "sparse"
+        states_me, _ = designer._train_states_me(designer._encode_datas())
+        assert isinstance(states_me, sparse_gp.SparseGPState)
+    else:
+        states_me, states_all, _, _ = _both_posteriors(designer)
+    assert jax.tree_util.tree_leaves(states_me)[0].shape[:2] == (metrics, ensemble)
+    rng = np.random.default_rng(2)
+    query = kernels.MixedFeatures(
+        jnp.asarray(rng.uniform(size=(50, 3)), jnp.float32),
+        jnp.asarray(rng.integers(0, 3, size=(50, 1)), jnp.int32),
+    )
+    if what == "pair-exact":
+        rows_all = jax.vmap(jax.vmap(lambda s: s.kernel_rows()))(states_all)
+        ours, vmapped = gp_ucb_pe._exact_posterior_pair, _pair_under_vmaps
+        arguments = (states_me, states_all, rows_all)
+    else:
+        ours, vmapped = gp_ucb_pe._mixture_predict, _mixture_under_vmaps
+        arguments = (states_me,)
+
+    got = jax.jit(ours)(*arguments, query)
+    want = jax.jit(vmapped)(*arguments, query)
+    for g, w in zip(got, want, strict=True):
+        assert g.shape == (metrics, 50)
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+
+    traced = str(jax.make_jaxpr(ours)(*arguments, query))
+    batched = f"f32[{metrics},{ensemble},50,"
+    assert batched in str(jax.make_jaxpr(vmapped)(*arguments, query))
+    assert (batched in traced) == ((metrics, ensemble) != (1, 1))
 
 
 @pytest.mark.parametrize("metrics,completed,active", [(1, 7, 2), (2, 6, 3), (1, 9, 0)])

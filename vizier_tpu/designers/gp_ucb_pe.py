@@ -127,6 +127,25 @@ class UCBPEConfig:
             )
 
 
+def _per_member(member: Callable, *states):
+    """``member`` of one member's states, over the [M, E] leading axes of
+    the ``states`` pytrees: every output gains them in front.
+
+    One metric × one member — the shipped default — is evaluated unbatched
+    and the two unit axes are put back on the outputs. Under
+    ``vmap(vmap())`` they ride into the fused kernel pass as broadcast axes,
+    and the TPU's compiler lays them second-minor: ``[1, 1, Q, N, D]`` tiled
+    ``T(1,128)``, one sublane of a vector register's eight (PERF.md, PR 42).
+    The choice is made from a static shape, and the arithmetic is the same
+    in the same order; with more metrics or members the batched program is
+    untouched.
+    """
+    if jax.tree_util.tree_leaves(states)[0].shape[:2] != (1, 1):
+        return jax.vmap(jax.vmap(member))(*states)
+    out = member(*jax.tree_util.tree_map(lambda a: a[0, 0], states))
+    return jax.tree_util.tree_map(lambda a: a[None, None], out)
+
+
 def _mixture_predict(
     states, query: kernels.MixedFeatures
 ) -> Tuple[Array, Array]:
@@ -135,7 +154,7 @@ def _mixture_predict(
     ``states``: GPState pytree with leading axes [M, E]. Returns
     ([M, Q] mean, [M, Q] stddev).
     """
-    return _moment_match(*jax.vmap(jax.vmap(lambda s: s.predict(query)))(states))
+    return _moment_match(*_per_member(lambda s: s.predict(query), states))
 
 
 def _moment_match(means: Array, stddevs: Array) -> Tuple[Array, Array]:
@@ -172,8 +191,8 @@ def _exact_posterior_pair(
             *everything.predict_from_cross(k_all),
         )
 
-    mean_c, std_c, mean_all, std_all = jax.vmap(jax.vmap(member))(
-        states_completed, states_all, rows_all
+    mean_c, std_c, mean_all, std_all = _per_member(
+        member, states_completed, states_all, rows_all
     )
     return (*_moment_match(mean_c, std_c), _moment_match(mean_all, std_all)[1])
 
