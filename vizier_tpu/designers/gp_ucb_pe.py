@@ -928,7 +928,11 @@ def _sparse_ucb_pe_flush_program(
     bound ARD → the greedy UCB-PE batch with pending-pick conditioning
     through the inducing posterior (Nyström-augmented) → warm seed, and
     the train's own work counts last, as the exact twin hands them out. A
-    slot matches its study run alone through the sequential sparse path.
+    slot traces what its study runs alone through the sequential sparse
+    path: the same answers to the bit at a small size
+    (tests/compute/test_program_parity.py) and, over a 50-iteration L-BFGS
+    train, up to float32's order of summation under the ``vmap``
+    (tests/chipbench/test_sparse_pool.py).
     """
     data = jax.vmap(lambda m: gp_lib.GPData.from_model_data(m))(md)
     all_gp = jax.vmap(lambda m: gp_lib.GPData.from_model_data(m))(all_md)
