@@ -14,6 +14,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from tests.eager_dispatches import EagerDispatches
 from vizier_tpu import pyvizier as vz
 from vizier_tpu import types
 from vizier_tpu.algorithms import core as core_lib
@@ -396,33 +397,9 @@ def test_base_designer_predicts_after_a_suggest():
 # -- eager dispatches outside the compiled programs --------------------------
 
 
-class _EagerDispatches:
-    """Counts the calls of ``jax._src.dispatch.apply_primitive``: the one
-    road of every eager operation. Primitives hold the function itself, so
-    the count is taken where it looks up its per-primitive callable, once a
-    call."""
-
-    def __enter__(self):
-        from jax._src import dispatch
-
-        self._dispatch = dispatch
-        self._lookup = dispatch.xla_primitive_callable
-        self.count = 0
-
-        def counting(prim, **params):
-            self.count += 1
-            return self._lookup(prim, **params)
-
-        dispatch.xla_primitive_callable = counting
-        return self
-
-    def __exit__(self, *exc):
-        self._dispatch.xla_primitive_callable = self._lookup
-
-
 def test_the_counter_counts_eager_operations():
     x = jnp.arange(4.0)
-    with _EagerDispatches() as eager:
+    with EagerDispatches() as eager:
         jax.lax.add(x, x)
         x[1:3]
     assert eager.count >= 2
@@ -438,7 +415,7 @@ def test_a_suggest_launches_few_programs_of_its_own():
     designer, _, rng = _loaded(problem, 30, seed=8, max_acquisition_evaluations=600)
     suggestions = designer.suggest(25)  # compiles
     designer.suggest(1)
-    with _EagerDispatches() as cached:
+    with EagerDispatches() as cached:
         designer.suggest(1)
     assert cached.count <= 3
     done = [
@@ -447,6 +424,6 @@ def test_a_suggest_launches_few_programs_of_its_own():
     ]
     designer.update(core_lib.CompletedTrials(done), core_lib.ActiveTrials([]))
     assert designer._cached_states is None
-    with _EagerDispatches() as training:
+    with EagerDispatches() as training:
         assert len(designer.suggest(25)) == 25
     assert training.count <= 3

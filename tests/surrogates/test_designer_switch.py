@@ -68,6 +68,7 @@ class TestAutoSwitch:
         assert d.surrogate_mode == config_lib.MODE_EXACT
         assert d.surrogate_counts == {
             "sparse_suggests": 0, "crossovers": 0, "nystrom_augments": 0,
+            "fit_reads": 0,
         }
 
         d.update(core_lib.CompletedTrials(_trials(6, 3, seed=1)))

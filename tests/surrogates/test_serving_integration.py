@@ -122,7 +122,7 @@ class TestServingAutoSwitch:
         assert snap["surrogate_crossovers"] == 0
         entry = runtime.designer_cache.get_or_create(STUDY, lambda: None)
         assert entry.surrogate_mode == "exact"
-        assert entry.sparse_state is None
+        assert entry.designer.sparse_inducing_state() is None
 
         _complete_some_trials(servicer, 4, start=3)
         _suggest(servicer, 1)  # 7 completed trials: sparse
@@ -132,10 +132,11 @@ class TestServingAutoSwitch:
         assert snap["surrogate_crossovers"] == 1
         entry = runtime.designer_cache.get_or_create(STUDY, lambda: None)
         assert entry.surrogate_mode == "sparse"
-        # The cached inducing state (selected set + factorization) is
-        # mirrored for inspection/hand-off.
-        assert entry.sparse_state is not None
-        assert entry.sparse_state.sdata.z_continuous.shape[-2] >= 6
+        # The trained inducing state (selected set + factorization) is the
+        # designer's, read on demand for inspection/hand-off.
+        sparse_state = entry.designer.sparse_inducing_state()
+        assert sparse_state is not None
+        assert sparse_state.sdata.z_continuous.shape[-2] >= 6
 
         _suggest(servicer, 2)  # stays sparse, no second crossover
         snap = pythia.serving_stats()
@@ -151,7 +152,7 @@ class TestServingAutoSwitch:
         _complete_some_trials(servicer, 7)
         _suggest(servicer, 0)
         entry = runtime.designer_cache.get_or_create(STUDY, lambda: None)
-        assert entry.sparse_state is not None
+        assert entry.designer.sparse_inducing_state() is not None
         assert pythia.serving_stats()["cached_studies"] == 1
 
         servicer.DeleteStudy(
@@ -162,14 +163,14 @@ class TestServingAutoSwitch:
         assert snap["cache_invalidations"] == 1
 
         # A recreated same-name study builds a FRESH entry: no mirrored
-        # mode, no sparse state, cold designer.
+        # mode, a cold designer with no sparse state.
         _create_study(servicer)
         _complete_some_trials(servicer, 2)
         _suggest(servicer, 1)
         fresh = runtime.designer_cache.get_or_create(STUDY, lambda: None)
         assert fresh is not entry
         assert fresh.surrogate_mode == "exact"
-        assert fresh.sparse_state is None
+        assert fresh.designer.sparse_inducing_state() is None
 
     def test_sparse_off_runtime_serves_exact_only(self):
         servicer = vizier_service.VizierServicer()

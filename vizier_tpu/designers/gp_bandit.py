@@ -401,8 +401,11 @@ class VizierGPBandit(core_lib.Designer, core_lib.Predictor):
         self._surrogate_mode = surrogate_config_lib.MODE_EXACT
         self._sparse_model_cache: Optional[sparse_gp.SparseGaussianProcess] = None
         self._last_sparse_state: Optional[sparse_gp.SparseGPState] = None
+        # ``fit_reads``: how many times a deferred fit was made a predictive
+        # (``_last_predictive``'s getter): 0 for as long as only suggests run.
         self._surrogate_counts = {
             "sparse_suggests": 0, "crossovers": 0, "nystrom_augments": 0,
+            "fit_reads": 0,
         }
 
     # -- Designer ----------------------------------------------------------
@@ -419,14 +422,18 @@ class VizierGPBandit(core_lib.Designer, core_lib.Predictor):
     @property
     def _last_predictive(self):
         """The last fit's predictive. A fit that needs device work to become
-        one (GP-UCB-PE slices metric 0 out of its per-metric state, a
-        program per leaf) waits in ``_unread_fit`` until somebody reads
-        this: ``predict``/``sample`` and an operator do, a suggest does not.
+        one (GP-UCB-PE slices metric 0 out of its per-metric state, one
+        program) waits in ``_unread_fit`` until somebody reads this: an
+        operator or ``sparse_inducing_state()`` does, a suggest and the
+        serving policy do not (``surrogate_counts["fit_reads"]`` counts the
+        reads; GP-UCB-PE's ``predict``/``sample`` answer from its cached
+        per-metric fit and read nothing here).
         Callers hold the designer as they do for a suggest (the serving
         cache entry's lock)."""
         if self._unread_fit is not None:
             fit, self._unread_fit = self._unread_fit, None
             self._predictive = self._predictive_of(fit)
+            self._surrogate_counts["fit_reads"] += 1
         return self._predictive
 
     @_last_predictive.setter
@@ -552,7 +559,8 @@ class VizierGPBandit(core_lib.Designer, core_lib.Predictor):
 
     @property
     def surrogate_counts(self) -> dict:
-        """Copies of the sparse-suggest / crossover counters (serving stats)."""
+        """Copies of the sparse-suggest / crossover / fit-read counters
+        (serving stats)."""
         return dict(self._surrogate_counts)
 
     def sparse_inducing_state(self) -> Optional[sparse_gp.SparseGPState]:

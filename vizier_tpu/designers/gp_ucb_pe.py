@@ -344,8 +344,8 @@ def _append_row_sparse(
 # The sequential path's crossings between its compiled programs. Each
 # is ONE small program, compiled in set-up with the shapes it serves, where
 # the eager form launched one program per operation and per leaf.
-# ``_stack_fits``, ``_sparse_all_points`` and ``_append_first_pick`` only move
-# and select values.
+# ``_stack_fits``, ``_metric_zero``, ``_sparse_all_points`` and
+# ``_append_first_pick`` only move and select values.
 # ``_sweep_inputs`` computes (the reference point is a min, a max, a multiply
 # and a subtract per metric; the prior features a ``top_k`` and a sum), so
 # that its bits are the eager form's is measured, not given: suggestions and
@@ -364,6 +364,15 @@ def _stack_fits(states_list):
         for states in states_list
     ]
     return states_me, best
+
+
+@jax.jit
+def _metric_zero(states_me):
+    """Metric 0 of a trained per-metric state ([M, E, ...] -> [E, ...]): what
+    ``_last_predictive`` holds. Eager, this was a ``dynamic_slice`` a leaf
+    (12 of an exact state, 16 of a sparse one), each with its start index
+    put on the device first."""
+    return jax.tree_util.tree_map(lambda a: a[0], states_me)
 
 
 @jax.jit
@@ -1578,16 +1587,16 @@ class VizierGPUCBPEBandit(gp_bandit.VizierGPBandit):
 
     def _remember_fit(self, states_me) -> None:
         """Keeps the trained per-metric state; metric 0's predictive is made
-        of it when somebody reads ``_last_predictive`` (a device slice per
-        leaf, which no suggest needs)."""
+        of it when somebody reads ``_last_predictive`` (a device program,
+        which no suggest needs)."""
         self._predictive = None
         self._unread_fit = states_me
 
     def _predictive_of(self, states_me):
-        """Metric 0's trained posterior, as predict()/sample() read it."""
+        """Metric 0's trained posterior (``_last_predictive``)."""
         if isinstance(states_me, mtgp.MultiTaskGPState):
             return _MetricZeroMTPredictive(states_me)
-        member_states = jax.tree_util.tree_map(lambda a: a[0], states_me)
+        member_states = _metric_zero(states_me)
         if isinstance(states_me, sparse_gp.SparseGPState):
             self._last_sparse_state = member_states
             return sparse_gp.SparseEnsemblePredictive(member_states)

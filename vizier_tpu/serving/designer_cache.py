@@ -41,14 +41,13 @@ class CachedDesignerEntry:
         # designer's ``warm_start_state()`` returns); None until the first
         # trained suggest.
         self.warm_params: Any = None
-        # Scalable-surrogate mirrors (vizier_tpu.surrogates): the active
-        # exact/sparse mode and the last trained sparse posterior (inducing
-        # set + factorization) — the inspection/hand-off surface, kept in
-        # lock-step with the live designer by the serving policy. Both die
-        # with the entry: DeleteStudy invalidation drops cached inducing
-        # state along with everything else.
+        # Scalable-surrogate mirror (vizier_tpu.surrogates): the active
+        # exact/sparse mode, kept in lock-step with the live designer by the
+        # serving policy. The last trained sparse posterior (inducing set +
+        # factorization) is the designer's own, read on demand
+        # (``designer.sparse_inducing_state()``), and dies with the entry:
+        # DeleteStudy invalidation drops it along with everything else.
         self.surrogate_mode: Any = None
-        self.sparse_state: Any = None
         # Speculative pre-compute slot (vizier_tpu.serving.speculative): a
         # parked next-suggestion batch for one exact frontier fingerprint,
         # swapped atomically under the engine's serve lock (never under

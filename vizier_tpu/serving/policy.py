@@ -174,12 +174,11 @@ class CachedDesignerStatePolicy(policy_lib.Policy):
         get_state = getattr(designer, "warm_start_state", None)
         if get_state is not None:
             entry.warm_params = get_state()
-        # Scalable-surrogate mirrors: the active mode and the cached
-        # inducing-point state (None on the exact path — a crossover back
-        # to exact clears it here too, so no stale sparse state lingers).
+        # The active surrogate mode, mirrored. The trained inducing-point
+        # state is the designer's, sliced out of its fit when somebody asks
+        # (``designer.sparse_inducing_state()``): a copy here cost every
+        # sparse suggest 16 one-leaf device programs that nobody read.
         entry.surrogate_mode = getattr(designer, "surrogate_mode", None)
-        get_sparse = getattr(designer, "sparse_inducing_state", None)
-        entry.sparse_state = get_sparse() if get_sparse is not None else None
         entry.num_suggests += 1
         return suggestions
 
@@ -243,6 +242,12 @@ class CachedDesignerStatePolicy(policy_lib.Policy):
         joined = after.get("nystrom_augments", 0) - before.get("nystrom_augments", 0)
         if joined > 0:
             stats.increment("nystrom_augments", joined)
+        # A deferred fit made a predictive INSIDE a served suggest: nothing
+        # on the request path reads one, so this stays 0 (a predict/sample
+        # between suggests raises the designer's count, not this).
+        read = after.get("fit_reads", 0) - before.get("fit_reads", 0)
+        if read > 0:
+            stats.increment("fit_reads", read)
         if crossed > 0:
             stats.increment("surrogate_crossovers", crossed)
             recorder_lib.get_recorder().record(

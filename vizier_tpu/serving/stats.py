@@ -95,6 +95,7 @@ class ServingStats:
         "sparse_suggests",  # suggests served by the sparse-GP posterior
         "nystrom_augments",  # picks of sparse UCB-PE suggests that joined the inducing set
         "surrogate_crossovers",  # exact<->sparse auto-switch transitions
+        "fit_reads",  # deferred fits made a predictive inside a served suggest (0: nothing there reads one)
         # Speculative pre-compute (vizier_tpu.serving.speculative).
         "speculative_hits",  # suggests served from a parked pre-computed batch
         "speculative_misses",  # slot empty / frontier moved / count mismatch
