@@ -1,7 +1,8 @@
 """The device half of a suggest, named from inside the program (PR 39):
 ``device.wait`` is observed under a ``phase`` label (train / acquire / flush),
 and a train phase reads, after its block, what the program counted of its own
-work — on the span, and nowhere when the JAX knob is off."""
+work (a copy it asked for when the train was dispatched: PR 45) — on the span,
+and nowhere when the JAX knob is off."""
 
 import collections
 
@@ -122,6 +123,31 @@ def test_the_train_span_carries_what_the_program_counted(fresh_state):
     span.to_dict()
 
 
+class _OnDevice:
+    """Stands for a program's work array on the device: counts the copies
+    to the host it was asked to start."""
+
+    def __init__(self):
+        self.copies_started = 0
+
+    def copy_to_host_async(self):
+        self.copies_started += 1
+
+    def __array__(self, *args, **kwargs):
+        return WORK
+
+
+def test_ahead_starts_the_copy_that_the_read_after_the_block_finds(fresh_state):
+    """``phase.ahead`` at the train's dispatch, ``phase.read`` after its
+    block (PR 45): the counts travel while the train runs."""
+    works = (_OnDevice(), _OnDevice())
+    with jax_timing.device_phase("unit.train", stage="train") as phase:
+        phase.ahead(works)
+        assert [w.copies_started for w in works] == [1, 1]
+        counts = gp_bandit.read_train_work(phase, works)
+    assert counts["programs"] == 2 and counts["loop_trips"] == 10
+
+
 def test_a_phase_that_trained_nothing_says_nothing(fresh_state):
     tracer, _ = fresh_state
     with jax_timing.device_phase("unit.train", stage="train") as phase:
@@ -152,6 +178,7 @@ def test_with_the_knob_off_nothing_blocks_and_nothing_is_read(fresh_state, confi
     with jax_timing.device_phase("unit.train", stage="train") as phase:
         assert not phase.enabled
         assert phase.block(work) is work
+        phase.ahead(work)  # asks the device for nothing
         assert phase.read(work) is None
         phase.set_attributes(loop_trips=1)
         assert gp_bandit.read_train_work(phase, (work,)) is None

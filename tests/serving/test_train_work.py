@@ -1,7 +1,10 @@
 """What the ARD train programs count of their own work reaches
 ``serving_stats()`` (PR 39): once a program — a sequential training suggest,
 a fused flush whatever its members — by the arrays the program returned,
-and not at all from a suggest that found its fit cached."""
+and not at all from a suggest that found its fit cached. Beside them (PR 45)
+the sequential trains that enqueued their sweeps while they still ran
+(``sweeps_ahead`` of ``sequential_trains``), on two ``device.wait`` spans that
+stay disjoint and in order."""
 
 import threading
 
@@ -53,7 +56,8 @@ def fetched(monkeypatch):
 def _gained(runtime, before):
     after = runtime.stats.snapshot()
     return {name: after[name] - before[name] for name in (*COUNTERS, "cached_fit_suggests",
-                                                          "warm_trains", "cold_trains", "batch_flushes")}
+                                                          "warm_trains", "cold_trains", "batch_flushes",
+                                                          "sequential_trains", "sweeps_ahead")}
 
 
 def _train_spans():
@@ -61,6 +65,17 @@ def _train_spans():
         s for s in tracing_lib.get_tracer().finished_spans()
         if s.name == "device.wait" and "loop_trips" in s.attributes
     ]
+
+
+def _last_suggest_spans():
+    """(designer.prepare, device.wait train, device.wait acquire) of the
+    last sequential suggest, as (start, end) on the spans' own clock."""
+    spans = tracing_lib.get_tracer().finished_spans()
+    prepare = [s for s in spans if s.name == "designer.prepare"][-1]
+    train, acquire = [s for s in spans if s.name == "device.wait"][-2:]
+    assert (train.attributes["stage"], acquire.attributes["stage"]) == ("train", "acquire")
+    assert prepare.span_id not in (train.parent_id, acquire.parent_id)
+    return [(s._t0, s._t0 + s.duration_secs) for s in (prepare, train, acquire)]
 
 
 def test_a_sequential_suggest_counts_its_one_train_program(served_gp_stack, fetched):
@@ -83,6 +98,13 @@ def test_a_sequential_suggest_counts_its_one_train_program(served_gp_stack, fetc
     assert span.attributes["rows"] == iterations.size
     assert span.attributes["row_iterations"] == gained["train_row_iterations"]
     assert span.attributes["evaluations"] == gained["train_evaluations"]
+    # The sweeps went out under the train: the span says whether the train
+    # still ran then (polled), and the two waits stay disjoint and in order,
+    # after the host's prepare.
+    assert gained["sequential_trains"] == 1
+    assert span.attributes["sweeps_ahead"] == gained["sweeps_ahead"] in (0, 1)
+    prepare, train, acquire = _last_suggest_spans()
+    assert prepare[0] <= prepare[1] <= train[0] <= train[1] <= acquire[0] <= acquire[1]
 
     # Asked again with nothing completed: the fit is cached, nothing trains,
     # nothing is read and no counter moves.
@@ -93,6 +115,11 @@ def test_a_sequential_suggest_counts_its_one_train_program(served_gp_stack, fetc
     assert gained["cached_fit_suggests"] == 1
     assert [gained[name] for name in COUNTERS] == [0] * 5
     assert len(fetched) == 1 and len(_train_spans()) == 1
+    assert (gained["sequential_trains"], gained["sweeps_ahead"]) == (0, 0)
+    prepare, train, acquire = _last_suggest_spans()  # the older order, the same spans
+    assert prepare[1] <= train[0] <= train[1] <= acquire[0] <= acquire[1]
+    cached_train = [s for s in tracing_lib.get_tracer().finished_spans() if s.name == "device.wait"][-2]
+    assert "sweeps_ahead" not in cached_train.attributes
 
     # A completion later the next suggest trains warm: one more program.
     client.complete_trial(trials[0].id, vz.Measurement(metrics={"obj": 0.25}))
@@ -101,6 +128,7 @@ def test_a_sequential_suggest_counts_its_one_train_program(served_gp_stack, fetc
     assert len(client_three.get_suggestions(1)) == 1
     gained = _gained(runtime, before)
     assert gained["train_programs"] == 1 == gained["warm_trains"]
+    assert gained["sweeps_ahead"] <= gained["sequential_trains"] == 1
     assert fetched[1].shape == (2, 4) and len(_train_spans()) == 2
     assert gained["train_row_iterations"] == fetched[1][0].sum()
 
@@ -142,6 +170,9 @@ def test_a_fused_flush_is_one_train_program_whatever_its_members(served_gp_stack
     assert gained["train_evaluations"] == evaluations.sum()
     (span,) = _train_spans()
     assert span.attributes["per"] == "flush" and span.attributes["rows"] == 8
+    # A fused flush is one program: no sweeps to send ahead of its train.
+    assert (gained["sequential_trains"], gained["sweeps_ahead"]) == (0, 0)
+    assert "sweeps_ahead" not in span.attributes
 
 
 def test_with_the_jax_knob_off_no_train_counter_moves(served_gp_stack, fetched):
@@ -153,3 +184,6 @@ def test_with_the_jax_knob_off_no_train_counter_moves(served_gp_stack, fetched):
     assert gained["cold_trains"] == 1  # it trained ...
     assert [gained[name] for name in COUNTERS] == [0] * 5  # ... and nothing was read
     assert fetched == [] and _train_spans() == []
+    # The order does not hang on the knob: the sweeps still went out under
+    # the train, which costs a poll and no read.
+    assert gained["sweeps_ahead"] <= gained["sequential_trains"] == 1

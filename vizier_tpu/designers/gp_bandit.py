@@ -390,8 +390,12 @@ class VizierGPBandit(core_lib.Designer, core_lib.Predictor):
         # random placeholder above) — gates the reduced warm restart budget
         # and the warm/cold accounting below.
         self._warm_is_trained = False
+        # ``sequential_trains``: training suggests that enqueued their sweeps
+        # under the train (``gp_ucb_pe.suggest``); ``sweeps_ahead``: those
+        # whose train was still running when the last sweep was enqueued.
         self._ard_train_counts = {
             "warm": 0, "cold": 0, "cached": 0,
+            "sequential_trains": 0, "sweeps_ahead": 0,
             **{f"train_{name}": 0 for name in _WORK_COUNTERS},
         }
         # Sparse-surrogate auto-switch state (vizier_tpu.surrogates): the

@@ -1076,6 +1076,9 @@ class VizierGPUCBPEBandit(gp_bandit.VizierGPBandit):
         # (datas, _sweep_inputs(datas)) of the last fit's datas: made once
         # a fit, found again by the identity of the list.
         self._sweep_inputs_of: Optional[tuple] = None
+        # Each metric's best constrained params of the last train, until
+        # ``_seed_next_trains`` has mapped them back for the next one.
+        self._unseeded_best: Optional[list] = None
         # Joint set-PE optimizers are built lazily per batch size.
         self._set_opt_cache: dict = {}
         # Per-pick sweep optimizers under the per_batch budget policy, keyed
@@ -1242,13 +1245,15 @@ class VizierGPUCBPEBandit(gp_bandit.VizierGPBandit):
         return datas
 
     def _train_states_me(
-        self, datas: Optional[List[gp_lib.GPData]] = None
+        self, datas: Optional[List[gp_lib.GPData]] = None, seed_next: bool = True
     ) -> Tuple[gp_lib.GPState, List[gp_lib.GPData]]:
         """Per-metric GP training: GPState with leading [M, E] + the datas
         (``_encode_datas()``'s, handed in by a caller that already has them).
 
         Cached between calls until update() delivers new completed trials —
         predict()/sample() right after a suggest() reuse the same fit.
+        ``seed_next=False`` leaves ``_seed_next_trains()`` to the caller
+        (``suggest``, which has the sweeps to enqueue first).
         """
         if self._cached_states is not None:
             return self._cached_states
@@ -1279,8 +1284,9 @@ class VizierGPUCBPEBandit(gp_bandit.VizierGPBandit):
             )
             self._unread_train_work = (work,)
             self._record_train()
-            states_me, best = _stack_fits((states,))
-            self._seed_next_trains(best)
+            states_me, self._unseeded_best = _stack_fits((states,))
+            if seed_next:
+                self._seed_next_trains()
             self._cached_states = (states_me, datas)
             return self._cached_states
         if self._use_multitask(len(datas)):
@@ -1328,15 +1334,18 @@ class VizierGPUCBPEBandit(gp_bandit.VizierGPBandit):
             )
         )
         self._record_train()
-        states_me, best = _stack_fits(states_list)
-        self._seed_next_trains(best)
+        states_me, self._unseeded_best = _stack_fits(states_list)
+        if seed_next:
+            self._seed_next_trains()
         self._cached_states = (states_me, datas)
         return self._cached_states
 
-    def _seed_next_trains(self, best: List[gp_lib.Params]) -> None:
-        """Each metric's best member seeds its next train (constrained
-        params mapped back through the bijectors), once the floor is met."""
-        if self._warm_update_allowed():
+    def _seed_next_trains(self) -> None:
+        """Each metric's best member of the last train seeds its next one
+        (constrained params mapped back through the bijectors), once the
+        floor is met. Eager: ~8 one-operation programs a hyperparameter."""
+        best, self._unseeded_best = self._unseeded_best, None
+        if best is not None and self._warm_update_allowed():
             coll = self._model.param_collection()
             self._warm_params_me = [coll.unconstrain(p) for p in best]
             self._warm_is_trained = True
@@ -1440,72 +1449,30 @@ class VizierGPUCBPEBandit(gp_bandit.VizierGPBandit):
             held = self._sweep_inputs_of = (datas, _sweep_inputs(tuple(datas)))
         return held[1]
 
-    def suggest(self, count: Optional[int] = None) -> List[trial_.TrialSuggestion]:
-        count = count or 1
-        if len(self._trials) + len(self._active_trials) < self.num_seed_trials:
-            return self._seed_suggestions(count)
-        if getattr(self, "_priors", None):
-            return self._suggest_with_priors(count)
+    def _sweep_operands(self, count: int, datas: List[gp_lib.GPData]) -> tuple:
+        """What the sweeps read beside the trained states, made on the host
+        (NumPy, but for the fit's ``_sweep_inputs``): ``(all_data,
+        labels_mn, labels_mask, ref_point, prior_feats, first_has_new,
+        has_completed)``. No train reads any of it."""
+        return (
+            self._all_points_data(count),
+            *self._sweep_inputs(datas),
+            np.asarray(self._has_new_completed_trials()),
+            np.asarray(bool(self._trials)),
+        )
 
-        self._mesh_suggests += self._mesh is not None
-        tracer = tracing_lib.get_tracer()
-        # What the host does before the device can start: the completed
-        # trials' encode (skipped when the fit is cached) and everything of
-        # the sweeps' inputs that does not wait for the trained states.
-        with tracer.span("designer.prepare"):
-            # The surrogate auto-switch decides the device-phase family up
-            # front (idempotent; ineligible designers always report exact).
-            sparse_mode = (
-                self._refresh_ucb_pe_surrogate_mode()
-                == surrogate_config_lib.MODE_SPARSE
-            )
-            if self._cached_states is None:
-                datas = self._encode_datas()
-            else:
-                self._ard_train_counts["cached"] += 1  # this suggest trains nothing
-                datas = self._cached_states[1]
-            all_data = self._all_points_data(count)
-            labels_mn, labels_mask, ref_point, prior_feats = self._sweep_inputs(
-                datas
-            )
-            first_has_new = np.asarray(self._has_new_completed_trials())
-            has_completed = np.asarray(bool(self._trials))
-        with profiler.timeit("train_gp"):
-            # Device-attributed ARD timing (compile vs. steady-state): see
-            # gp_bandit.suggest for the rationale; no-op + no device sync
-            # when observability is off.
-            with jax_timing.device_phase(
-                "sparse_gp.ucb_pe_train_gp" if sparse_mode else "gp_ucb_pe.train_gp",
-                stage="train",
-                devices=self._mesh_size(),
-            ) as phase:
-                states_me, datas = self._train_states_me(datas)
-                phase.block(states_me)
-                if phase.enabled:
-                    works, self._unread_train_work = self._unread_train_work, ()
-                    self._record_train_work(
-                        gp_bandit.read_train_work(phase, works)
-                    )
-        is_mt = isinstance(states_me, mtgp.MultiTaskGPState)
+    def _dispatch_sweeps(self, count: int, states_me, operands: tuple) -> List[Tuple]:
+        """Enqueues a suggest's sweep programs behind whatever computes
+        ``states_me`` and waits for none of them: ``[(result, aux, rows)]``,
+        the last entry's ``result.scores`` being the last thing the device
+        finishes."""
+        (
+            all_data, labels_mn, labels_mask, ref_point, prior_feats,
+            first_has_new, has_completed,
+        ) = operands
         is_sparse = isinstance(states_me, sparse_gp.SparseGPState)
-        num_metrics = len(datas)
-        if num_metrics > 1 and self.config.optimize_set_acquisition_for_exploration:
-            raise ValueError(
-                "optimize_set_acquisition_for_exploration supports exactly "
-                "one objective metric."
-            )
-
-        if (
-            self.config.optimize_set_acquisition_for_exploration
-            and count > 1
-        ):
-            self._remember_fit(states_me)
-            return self._suggest_with_set_acquisition(
-                count, states_me, all_data, labels_mn, labels_mask, ref_point,
-                first_has_new, has_completed, datas,
-            )
-
-        if is_mt:
+        if isinstance(states_me, mtgp.MultiTaskGPState):
+            num_metrics = labels_mn.shape[0]
             model = self._mt_model(num_metrics)
             all_data = mtgp.MultiTaskData(
                 features_data=all_data,
@@ -1522,9 +1489,141 @@ class VizierGPUCBPEBandit(gp_bandit.VizierGPBandit):
             all_data = _sparse_all_points(states_me, all_data, count)
         else:
             model = self._model
-        results: List[Tuple] = []  # [(result, aux, rows)]
-        # Device-attributed sweep timing; the block_until_ready calls on the
-        # batch scores below already pin device time inside this phase.
+        if self.acquisition_budget_policy == "first_pick_full" and count > 1:
+            # Full budget on the exploitation-critical first pick; one
+            # further full budget split across the remaining picks.
+            first, aux1 = _suggest_batch(
+                model, self._vec_opt, states_me, all_data,
+                labels_mn, labels_mask, ref_point, prior_feats,
+                self._next_rng(), first_has_new, has_completed, 1,
+                self.config, self.use_trust_region, self._mesh,
+                self.prior_acquisition,
+            )
+            all_data = _append_first_pick(
+                all_data, first.features, states_me if is_sparse else None
+            )
+            # _pick_vec_opt(count) is the ONE budget-dispatch point: under
+            # first_pick_full it returns the (count-1)-way split sweep.
+            rest, aux2 = _suggest_batch(
+                model, self._pick_vec_opt(count), states_me,
+                all_data, labels_mn, labels_mask, ref_point, prior_feats,
+                self._next_rng(), np.asarray(False), has_completed,
+                count - 1, self.config, self.use_trust_region,
+                self._mesh, self.prior_acquisition,
+            )
+            return [(first, aux1, 1), (rest, aux2, count - 1)]
+        batch, aux = _suggest_batch(
+            model, self._pick_vec_opt(count), states_me, all_data,
+            labels_mn, labels_mask, ref_point, prior_feats,
+            self._next_rng(), first_has_new, has_completed, count,
+            self.config, self.use_trust_region, self._mesh,
+            self.prior_acquisition,
+        )
+        return [(batch, aux, count)]
+
+    def suggest(self, count: Optional[int] = None) -> List[trial_.TrialSuggestion]:
+        """Seed trials, then GP-UCB-PE: train (unless the fit is cached),
+        sweep, decode.
+
+        Every device program of the suggest is enqueued before the host
+        waits for any of them. A suggest that trains makes the sweeps'
+        host inputs and dispatches the sweeps right after the train's
+        dispatch, INSIDE the train's ``device.wait`` phase, and only then
+        blocks on the trained states: the launches (and their host→device
+        copies) run under the train instead of between two programs with
+        the chip idle. The train's span so ends when the train ends, and
+        the acquire span that follows holds only the wait for the sweeps'
+        end (``observability/jax_timing.py``). On a process's first such
+        call the sweeps' trace and compile are therefore inside the
+        train's span (``mode="compile"`` there already) and the first
+        acquire span is the wait alone. A suggest on a cached fit has no
+        train to hide behind and keeps the older order: inputs in
+        ``designer.prepare``, sweeps dispatched under the acquire phase.
+        """
+        count = count or 1
+        if len(self._trials) + len(self._active_trials) < self.num_seed_trials:
+            return self._seed_suggestions(count)
+        if getattr(self, "_priors", None):
+            return self._suggest_with_priors(count)
+
+        if (
+            self.config.optimize_set_acquisition_for_exploration
+            and len(self._objective_indices()) > 1
+        ):
+            raise ValueError(
+                "optimize_set_acquisition_for_exploration supports exactly "
+                "one objective metric."
+            )
+        self._mesh_suggests += self._mesh is not None
+        tracer = tracing_lib.get_tracer()
+        set_acquisition = (
+            self.config.optimize_set_acquisition_for_exploration and count > 1
+        )
+        # The sweeps go out under the train when there is a train to go out
+        # under (the set acquisition, which no served path runs, keeps its
+        # sweeps after the block).
+        trains = self._cached_states is None
+        sweeps_under_train = trains and not set_acquisition
+        # What the host does before the device can start: the completed
+        # trials' encode (skipped when the fit is cached) and, when no train
+        # will hide them, the sweeps' inputs.
+        with tracer.span("designer.prepare"):
+            # The surrogate auto-switch decides the device-phase family up
+            # front (idempotent; ineligible designers always report exact).
+            sparse_mode = (
+                self._refresh_ucb_pe_surrogate_mode()
+                == surrogate_config_lib.MODE_SPARSE
+            )
+            if trains:
+                datas = self._encode_datas()
+            else:
+                self._ard_train_counts["cached"] += 1  # this suggest trains nothing
+                datas = self._cached_states[1]
+            operands = (
+                None if sweeps_under_train else self._sweep_operands(count, datas)
+            )
+        results: Optional[List[Tuple]] = None  # [(result, aux, rows)]
+        with profiler.timeit("train_gp"):
+            # Device-attributed ARD timing (compile vs. steady-state): see
+            # gp_bandit.suggest for the rationale; no-op + no device sync
+            # when observability is off.
+            with jax_timing.device_phase(
+                "sparse_gp.ucb_pe_train_gp" if sparse_mode else "gp_ucb_pe.train_gp",
+                stage="train",
+                devices=self._mesh_size(),
+            ) as phase:
+                states_me, datas = self._train_states_me(
+                    datas, seed_next=not sweeps_under_train
+                )
+                phase.ahead(self._unread_train_work)
+                if sweeps_under_train:
+                    operands = self._sweep_operands(count, datas)
+                    results = self._dispatch_sweeps(count, states_me, operands)
+                    # Polled, not waited for: were the sweeps enqueued while
+                    # the train still ran?
+                    ahead = not jax.tree_util.tree_leaves(states_me)[0].is_ready()
+                    self._ard_train_counts["sequential_trains"] += 1
+                    self._ard_train_counts["sweeps_ahead"] += ahead
+                    phase.set_attributes(sweeps_ahead=int(ahead))
+                phase.block(states_me)
+                if phase.enabled:
+                    works, self._unread_train_work = self._unread_train_work, ()
+                    self._record_train_work(
+                        gp_bandit.read_train_work(phase, works)
+                    )
+        is_sparse = isinstance(states_me, sparse_gp.SparseGPState)
+        if set_acquisition:
+            self._remember_fit(states_me)
+            all_data, labels_mn, labels_mask, ref_point, _, first_has_new, has_completed = (
+                operands
+            )
+            return self._suggest_with_set_acquisition(
+                count, states_me, all_data, labels_mn, labels_mask, ref_point,
+                first_has_new, has_completed, datas,
+            )
+
+        # Device-attributed sweep timing: from here (the train's end, when
+        # the sweeps went out under it) to the sweeps' end.
         with profiler.timeit("acquisition_optimizer"), jax_timing.device_phase(
             "sparse_gp.ucb_pe_acquisition"
             if is_sparse
@@ -1532,51 +1631,15 @@ class VizierGPUCBPEBandit(gp_bandit.VizierGPBandit):
             stage="acquire",
             devices=self._mesh_size(),
         ):
-            if self.acquisition_budget_policy == "first_pick_full" and count > 1:
-                # Full budget on the exploitation-critical first pick; one
-                # further full budget split across the remaining picks.
-                first, aux1 = _suggest_batch(
-                    model, self._vec_opt, states_me, all_data,
-                    labels_mn, labels_mask, ref_point, prior_feats,
-                    self._next_rng(), first_has_new, has_completed, 1,
-                    self.config, self.use_trust_region, self._mesh,
-                    self.prior_acquisition,
-                )
-                all_data = _append_first_pick(
-                    all_data, first.features, states_me if is_sparse else None
-                )
-                # _pick_vec_opt(count) is the ONE budget-dispatch point: under
-                # first_pick_full it returns the (count-1)-way split sweep.
-                rest, aux2 = _suggest_batch(
-                    model, self._pick_vec_opt(count), states_me,
-                    all_data, labels_mn, labels_mask, ref_point, prior_feats,
-                    self._next_rng(), np.asarray(False), has_completed,
-                    count - 1, self.config, self.use_trust_region,
-                    self._mesh, self.prior_acquisition,
-                )
-                jax.block_until_ready(rest.scores)
-                results = [(first, aux1, 1), (rest, aux2, count - 1)]
-            else:
-                batch, aux = _suggest_batch(
-                    model,
-                    self._pick_vec_opt(count),
-                    states_me,
-                    all_data,
-                    labels_mn,
-                    labels_mask,
-                    ref_point,
-                    prior_feats,
-                    self._next_rng(),
-                    first_has_new,
-                    has_completed,
-                    count,
-                    self.config,
-                    self.use_trust_region,
-                    self._mesh,
-                    self.prior_acquisition,
-                )
-                jax.block_until_ready(batch.scores)
-                results = [(batch, aux, count)]
+            if results is None:
+                results = self._dispatch_sweeps(count, states_me, operands)
+            # The next train's seeds go out BEHIND the sweeps. Between the
+            # train and the sweeps their ~30 one-operation programs filled
+            # the device's queue of programs in flight (32 on a TPU v5e),
+            # and the host's next launch, the second sweep's, waited for
+            # the train's end (PERF.md section 6, PR 45).
+            self._seed_next_trains()
+            jax.block_until_ready(results[-1][0].scores)
         if is_sparse:
             self._surrogate_counts["sparse_suggests"] += 1
         with profiler.timeit("best_candidates_to_trials"), tracer.span(

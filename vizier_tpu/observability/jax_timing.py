@@ -7,9 +7,25 @@ the ``device.wait`` stage span (``observability/tracing.py``) and has the
 caller ``block()`` the stage's outputs *inside* it, so the time the host
 spends blocked on the chip is attributed to the right phase:
 
-    with jax_timing.device_phase("gp_bandit.train_gp", stage="train") as phase:
-        states = self._train(...)
+    with jax_timing.device_phase("gp_ucb_pe.train_gp", stage="train") as phase:
+        states, work = self._train(...)
+        phase.ahead(work)        # its counts start for the host now
+        sweeps = self._dispatch_sweeps(states, ...)  # enqueued behind the train
         phase.block(states)
+        counts = phase.read(work)  # a copy that has already arrived
+    with jax_timing.device_phase("gp_ucb_pe.acquisition", stage="acquire"):
+        jax.block_until_ready(sweeps)
+
+**Every program of a suggest is enqueued before the host waits for any of
+them** (``designers/gp_ucb_pe.py`` ``suggest``, PR 45): a block only reads a
+clock, it never stands between two launches. So a train's span runs from the
+train's dispatch to the train's END and covers the sweeps' launches (their
+host-to-device copies, and on a process's first call their trace and compile),
+which cost the chip nothing while the train runs; the acquire span that follows
+is what is left of the sweeps on the chip after the train has ended — not the
+sweeps' launches, and not a measure of how fast a sweep is by itself. A suggest
+that trains nothing (a cached fit) has no train to hide its launches behind:
+its acquire span holds them, as every acquire span did before PR 45.
 
 The span's attributes say which phase it was: ``phase`` (the name given
 here — for a flush program, its ``DesignerProgram.device_phase``), ``stage``
@@ -25,7 +41,9 @@ this span's ``stage`` (``train`` / ``acquire``) or ``flush`` for a fused
 flush's one wait — and annotates the ``jax.profiler`` trace. A train phase
 also carries what its program counted of its own work (``phase.read`` after
 the block, ``phase.set_attributes``): ``loop_trips``, ``rows``,
-``row_iterations``, ``evaluations``.
+``row_iterations``, ``evaluations``; and, where it sent its sweeps ahead,
+``sweeps_ahead`` (1: the train was still running when the last sweep was
+enqueued — ``is_ready()``, a poll).
 
 With observability (or the JAX knob) off, the phase object is inert and —
 deliberately — does NOT ``block_until_ready`` and reads nothing: the
@@ -93,6 +111,16 @@ class _Phase:
 
             jax.block_until_ready(outputs)
         return outputs
+
+    def ahead(self, small: Any) -> None:
+        """Starts the device-to-host copy of what ``read`` will fetch, when
+        its program is dispatched: the read after the block then waits for
+        nothing. Asks for nothing when profiling is off."""
+        if self.enabled:
+            import jax
+
+            for leaf in jax.tree_util.tree_leaves(small):
+                leaf.copy_to_host_async()
 
     def read(self, small: Any) -> Any:
         """ONE device-to-host read of a small output the phase has blocked

@@ -270,9 +270,10 @@ class CachedDesignerStatePolicy(policy_lib.Policy):
             stats.increment("cached_fit_suggests", cached)
         # What the timed train programs counted of their own work, under
         # the serving counters' own names (``train_programs`` ...; a fused
-        # flush's comes through its first member, once a flush).
+        # flush's comes through its first member, once a flush), and the
+        # sequential trains that enqueued their sweeps while they still ran.
         for field in after:
-            if field.startswith("train_"):
+            if field.startswith("train_") or field in ("sequential_trains", "sweeps_ahead"):
                 gained = after[field] - before.get(field, 0)
                 if gained > 0:
                     stats.increment(field, gained)

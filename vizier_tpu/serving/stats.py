@@ -49,6 +49,13 @@ class ServingStats:
         "train_row_trips",  # rows x trips: what lockstep ran, padded slots too
         "train_row_iterations",  # iterations the rows needed
         "train_evaluations",  # loss evaluations of the rows (a Cholesky each)
+        # A sequential training suggest enqueues its sweeps under its train:
+        # sweeps_ahead / sequential_trains is the share whose train was still
+        # running when the last sweep was enqueued (polled, never waited for;
+        # counted whatever the knobs; a cached fit or a fused flush counts
+        # in neither).
+        "sequential_trains",
+        "sweeps_ahead",
         # The policy's delta trial read (serving.policy): reused / (reused +
         # fetched) is the share of a study a suggest did not re-read.
         "trials_fetched",  # trial protos converted to pyvizier for an update
