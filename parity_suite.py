@@ -17,7 +17,7 @@ additionally gated on dominating the reference's runnable baselines.
 
 Usage:
   bash tools/build_reference_copy.sh        # once per machine
-  python parity_suite.py [--scale 1.0] [--out regret_report_r2.json]
+  python parity_suite.py [--scale 1.0] [--out regret_report_r5.json]
 """
 
 from __future__ import annotations

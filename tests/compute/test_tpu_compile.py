@@ -10,7 +10,8 @@ exactly the executor's, at the reference's default widths: 20-D,
 50, padded to the executor's batch of 8.
 
 A compile that passes is not a chip run: nothing executes, so this says
-nothing about results or times. ``chip_smoke.py`` is the chip run.
+nothing about results or times. The benchmark's cells (``chipbench/run.py``)
+are the chip run.
 
 The topology is described inside a module-scoped fixture and nowhere else:
 only one process may hold the TPU library, so nothing here touches
@@ -447,7 +448,7 @@ def test_sweep_75k_evaluations_compiles(one_chip):
 
 def test_posterior_ucb_forward_compiles(one_chip):
     """Precompute (Cholesky + L⁻¹) → posterior → UCB at 1024×20, 256 queries
-    — the forward step ``chip_smoke.py`` checks against float64."""
+    — the forward step the benchmark's ``correct`` checks against float64."""
     designer = _designer(1)
     model = designer._model
     data, _ = _gp_state_shapes(designer, 1024, one_chip)

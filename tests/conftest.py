@@ -3,8 +3,9 @@
 Multi-chip sharding paths are exercised on CPU via
 ``--xla_force_host_platform_device_count``. The suite runs on the CPU
 everywhere, a machine with a TPU included: several xdist workers cannot
-share one chip, and ``chip_smoke.py`` is the chip run. ``JAX_PLATFORMS`` is
-set here before jax is imported; JAX reads it itself.
+share one chip, and the benchmark's cells (``chipbench/run.py``) are the
+chip run. ``JAX_PLATFORMS`` is set here before jax is imported; JAX reads it
+itself.
 """
 
 import os

@@ -40,7 +40,7 @@ def test_multi_client_suggest_complete_over_grpc(
             owner="perf",
             study_id=f"stress-{num_clients}x{num_trials_each}",
         )
-        # ONE shared topology with tools/service_throughput.py.
+        # Upstream's topology (the cell perftest2d.shared50x5 times it on the chip).
         elapsed, completed, per_worker = stress.run_stress_round(
             study, num_clients, num_trials_each
         )

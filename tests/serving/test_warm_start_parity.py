@@ -1,10 +1,10 @@
 """Warm-started ARD must not change regret: rank-sum parity at 5 seeds.
 
-A cheap CI-scale version of the full A/B in ``tools/warm_start_ab.py``
-(WARM_START_AB.json): the warm arm trains with 1 warm-seeded restart after
-the first suggest, the cold arm always runs the full restart budget from
-random inits, on the same shifted-sphere instances. Deterministic given
-the pinned seeds, so the gate is stable.
+The warm arm trains with 1 warm-seeded restart after the first suggest,
+the cold arm always runs the full restart budget from random inits, on the
+same shifted-sphere instances. Deterministic given the pinned seeds, so the
+gate is stable. What a warm train takes on the chip is every benchmark
+cell's ``train_wait_ms.*`` (all of them serve warm trains).
 """
 
 import numpy as np

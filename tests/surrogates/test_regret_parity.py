@@ -1,18 +1,21 @@
 """Sparse surrogate must not change regret: rank-sum parity at 5 seeds.
 
-A cheap CI-scale version of the full A/B in ``tools/surrogate_ab.py``
-(SPARSE_AB.json): the sparse arm runs the SGPR collapsed-bound posterior
-from the first post-seed suggest (threshold 1), the exact arm the seed
-O(n³) path, on the same shifted-sphere instances. Deterministic given the
-pinned seeds, so the gate is stable.
+For each GP designer (the plain bandit and the service DEFAULT, UCB-PE) the
+sparse arm runs the SGPR collapsed-bound posterior from the first post-seed
+suggest (threshold 1), the exact arm the seed O(n³) path, on the same
+shifted-sphere instances. Deterministic given the pinned seeds, so the gate
+is stable. What the sparse tier costs or saves is the chip's to say: the
+``default20d-sparse`` cells of ``BENCHMARK.json``.
 """
 
 import numpy as np
+import pytest
 
 from vizier_tpu import pyvizier as vz
 from vizier_tpu.algorithms import core as core_lib
 from vizier_tpu.benchmarks.experimenters import experimenter_factory
 from vizier_tpu.designers.gp_bandit import VizierGPBandit
+from vizier_tpu.designers.gp_ucb_pe import VizierGPUCBPEBandit
 from vizier_tpu.optimizers import lbfgs as lbfgs_lib
 from vizier_tpu.surrogates import SurrogateConfig
 
@@ -34,7 +37,7 @@ def _rank_sum_p(a, b) -> float:
     return float(2.0 * (1.0 - stats.norm.cdf(abs(u - mu) / max(sigma, 1e-9))))
 
 
-def _run_arm(seed: int, sparse: bool) -> float:
+def _run_arm(designer_cls, seed: int, sparse: bool) -> float:
     exp = experimenter_factory.shifted_bbob_instance("Sphere", seed, dim=DIM)
     surrogate = (
         SurrogateConfig(
@@ -43,7 +46,7 @@ def _run_arm(seed: int, sparse: bool) -> float:
         if sparse
         else None
     )
-    designer = VizierGPBandit(
+    designer = designer_cls(
         exp.problem_statement(),
         rng_seed=seed,
         num_seed_trials=4,
@@ -68,9 +71,10 @@ def _run_arm(seed: int, sparse: bool) -> float:
     return best
 
 
-def test_sparse_vs_exact_regret_parity():
-    sparse_finals = [_run_arm(s, sparse=True) for s in SEEDS]
-    exact_finals = [_run_arm(s, sparse=False) for s in SEEDS]
+@pytest.mark.parametrize("designer_cls", [VizierGPBandit, VizierGPUCBPEBandit])
+def test_sparse_vs_exact_regret_parity(designer_cls):
+    sparse_finals = [_run_arm(designer_cls, s, sparse=True) for s in SEEDS]
+    exact_finals = [_run_arm(designer_cls, s, sparse=False) for s in SEEDS]
     p = _rank_sum_p(sparse_finals, exact_finals)
     # Parity: the sparse arm's final regrets must be statistically
     # indistinguishable from the exact arm's (deterministic given SEEDS).

@@ -253,7 +253,7 @@ class TestTraining:
 class TestPosteriorMatmulPrecision:
     """On a TPU the default matmul precision is one bf16 pass, and the
     posterior variance — a difference of near-equal terms through L⁻¹ —
-    came out negative there (chip_smoke.py, PR 21). The CPU cannot show
+    came out negative there (PR 21's chip run). The CPU cannot show
     that, so this pins what fixed it: every matmul traced from a posterior
     query carries ``POSTERIOR_PRECISION``."""
 

@@ -624,7 +624,7 @@ def run_mesh_executor_arm(
             problem(),
             rng_seed=study_seed,
             # Distinct acquisition budgets -> distinct jit statics ->
-            # distinct buckets (mirrors tools/batching_ab.py --devices).
+            # distinct buckets.
             max_acquisition_evaluations=200 + 8 * bucket_index,
             ard_restarts=2,
             ard_optimizer=lbfgs_lib.AdamOptimizer(maxiter=10),

@@ -1,8 +1,9 @@
 #!/bin/bash
-# Reproduces every round-5 evidence artifact from a clean checkout.
-# Everything runs on CPU (JAX_PLATFORMS=cpu is honored via the shared
-# config-level pin); on a live TPU drop the env prefix. Approximate
-# runtimes are from the quiet 8-core container this round ran in.
+# Reproduces the regret and robustness artifacts at the repo root from a
+# clean checkout. Everything here runs on the CPU and states no speed:
+# rates and latencies are measured on the chip by the benchmark's cells
+# (BENCHMARK.json, chipbench/README.md; the driver's record is
+# PERF_LEDGER.jsonl). Approximate runtimes are from a quiet 8-core container.
 set -e
 cd "$(dirname "$0")/.."
 
@@ -10,14 +11,10 @@ echo "== 0. static analysis: lock order / JAX discipline / env registry (~2 s) =
 #    zero unbaselined violations (docs/guides/static_analysis.md)
 python tools/check_analysis.py
 
-echo "== 1. full test suite (CPU; ~6 min with six xdist workers) =="
-python -m pytest tests/ -q -p xdist -n 6 --dist loadfile
-
-echo "== 3. service throughput head-to-head + sharded-tier A/B (~8 min) =="
-#    -> SERVICE_THROUGHPUT.json (builds /tmp/refvizier on first run);
-#    --replicas adds the "distributed" section: 4 routed replicas vs one
-#    gRPC server on the same 8-study workload (target >= 5x)
-JAX_PLATFORMS=cpu python tools/service_throughput.py --replicas 4 --out /tmp/st.json
+echo "== 1. tier-1 tests, the driver's flags (CPU; ~10 min with six xdist workers) =="
+JAX_PLATFORMS=cpu python -m pytest tests/ -q -m 'not slow' \
+  --continue-on-collection-errors -p no:cacheprovider -p xdist -n 6 \
+  --dist loadfile -p no:randomly
 
 echo "== 3b. failover chaos: kill one replica mid-study (~1 min) =="
 #    -> CHAOS_AB.json gains the distributed_failover arm (50/50 trials
@@ -77,34 +74,6 @@ echo "== 3b6. disaggregated compute tier A/B (~1 min) =="
 #    remote-hop spans (docs/guides/running_the_service.md
 #    "Disaggregated compute tier")
 JAX_PLATFORMS=cpu python tools/compute_tier_ab.py
-
-echo "== 3b2. mesh-sharded batch execution A/B (~4 min) =="
-#    -> MESH_AB.json: 8 distinct concurrent shape buckets through the
-#    single-device executor vs an 8-placement mesh executor on 8
-#    simulated devices (target >= 2x aggregate flush throughput), plus
-#    the VIZIER_MESH=0 bit-identity check against the seed executor
-JAX_PLATFORMS=cpu python tools/batching_ab.py --devices 8
-
-echo "== 3c. sparse-surrogate A/B at the north-star scale (~10 min) =="
-#    -> SPARSE_AB.json: sparse SGPR vs exact O(n^3) device-side suggest
-#    p50 at 1000x20-D (target >= 10x), rank-sum regret parity at 5
-#    seeds, and the VIZIER_SPARSE=0 bit-identity check
-JAX_PLATFORMS=cpu python tools/surrogate_ab.py
-
-echo "== 3c2. sparse UCB-PE A/B — the service DEFAULT (~45 min) =="
-#    -> SPARSE_UCB_PE_AB.json: sparse UCB-PE (pending-pick conditioning
-#    through the Nystrom-augmented inducing posterior, compute-IR kind
-#    gp_ucb_pe_sparse) vs exact UCB-PE full-designer suggest p50 at
-#    1000x20-D (target >= 5x), rank-sum regret parity at 5 seeds, and
-#    the VIZIER_SPARSE_UCB_PE=0 bit-identity check
-JAX_PLATFORMS=cpu python tools/surrogate_ab.py --designer ucb_pe
-
-echo "== 3d. speculative pre-compute A/B (~4 min) =="
-#    -> SPECULATIVE_AB.json: sequential complete->suggest loop, 5 seeds;
-#    speculative-hit suggest p50 < 10 ms vs the full-GP baseline,
-#    hit rate >= 80%, and bit-identical trajectories (a hit is the live
-#    compute run early; VIZIER_SPECULATIVE=0 stays the seed path)
-JAX_PLATFORMS=cpu python tools/speculative_ab.py --trials 25 --seeds 5 --acquisition-evals 0
 
 echo "== 4. budget-policy A/B, 5 seeds x 3 families (~45 min) =="
 #    -> budget_ab_r5.json

@@ -293,7 +293,8 @@ class VizierGPBandit(core_lib.Designer, core_lib.Predictor):
     # Restart budget for a WARM train (one with trained seed params). None
     # keeps the full ``ard_restarts`` budget; the serving runtime sets 1 so
     # steady-state suggests pay one early-exiting L-BFGS run instead of
-    # ``ard_restarts`` full cold starts (A/B: WARM_START_AB.json).
+    # ``ard_restarts`` full cold starts (regret parity:
+    # tests/serving/test_warm_start_parity.py).
     warm_ard_restarts: Optional[int] = None
     # Multi-chip data plane: None = auto (build a mesh over all devices when
     # more than one exists and route ARD restarts + acquisition pools through
@@ -357,10 +358,11 @@ class VizierGPBandit(core_lib.Designer, core_lib.Predictor):
         # Multi-chip path (SURVEY §2.10): when more than one device is
         # visible, suggest() shards the ARD restarts over a mesh of all of
         # them and runs one full acquisition sweep a device, unasked. That
-        # is more work, not less time: on a 4-chip v5e host a lone 20-D
-        # suggest(25) takes about 1.3 times what it takes on one chip
-        # (PERF.md §5, `default20d-host4.lone25` beside `default20d.lone25`),
-        # and such a designer is never batched and never sparse.
+        # is more work for the same answer, not a shorter suggest: every
+        # device trains its own restarts and runs its own 75,000
+        # evaluations, and such a designer is never batched and never
+        # sparse. What it costs beside one chip is the benchmark's to say
+        # (PERF.md §5, `default20d-host4.lone25` beside `default20d.lone25`).
         self._mesh = None
         self._mesh_suggests = 0  # suggests whose sweeps ran on it (serving stats)
         if self.use_mesh is not None:

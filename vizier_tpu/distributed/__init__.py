@@ -18,8 +18,8 @@ deployment"):
 - **in-process** — N ``VizierServicer`` replicas behind one
   ``ReplicaManager``, all feeding ONE shared Pythia (designer cache,
   coalescer, cross-study batch executor). No transport hop: the router IS
-  the channel. This is the tier ``tools/service_throughput.py --replicas``
-  measures and ``tools/chaos_ab.py --distributed`` kills replicas in.
+  the channel. This is the tier ``tools/chaos_ab.py --distributed`` kills
+  replicas in.
 - **subprocess / multi-host** — N ``DefaultVizierServer`` processes
   (``python -m vizier_tpu.distributed.replica_main``), routed over real
   gRPC channels; each process hosts its own Pythia, persists epoch-fenced

@@ -5,9 +5,8 @@
 ``DefaultVizierServer`` (Vizier + its own Pythia) whose datastore is a
 snapshot+WAL ``PersistentDataStore`` when ``--wal-dir`` is given — the
 process restarts warm from its directory. It prints ``READY <endpoint>``
-on stdout once serving, which is what ``tools/service_throughput.py
---replica-mode subprocess`` (and the lease-based
-``distributed.subprocess_fleet.SubprocessReplicaManager``) waits for.
+on stdout once serving, which is what the lease-based
+``distributed.subprocess_fleet.SubprocessReplicaManager`` waits for.
 
 With ``--peers replica-1=host:port,...`` (and a WAL dir) the replica
 joins the **cross-process replication plane**: it hosts the

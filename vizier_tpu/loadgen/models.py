@@ -1,9 +1,8 @@
 """Seeded, deterministic traffic models for the loadgen engine.
 
-Every evidence file before this subsystem (SPARSE_AB, SPECULATIVE_AB,
-MESH_AB, SERVICE_THROUGHPUT) is a point A/B of one subsystem in isolation.
-The loadgen engine instead drives the FULL stack with production-shaped
-mixed traffic, and this module is its workload description language —
+A point A/B exercises one subsystem in isolation. The loadgen engine
+instead drives the FULL stack with production-shaped mixed traffic, and
+this module is its workload description language —
 everything here is a pure function of the scenario seed, so the same
 :class:`ScenarioConfig` always expands to the same :class:`Scenario`:
 

@@ -38,7 +38,7 @@ REPORT_VERSION = 2  # v2: admission section + per-tenant latency/sheds
 
 def ranksum_p(a, b) -> float:
     """Two-sided rank-sum p-value (scipy when present, else normal
-    approximation — same shape as tools/speculative_ab.py)."""
+    approximation)."""
     if not a or not b:
         return 1.0
     try:

@@ -39,7 +39,7 @@ _JITTER = 1e-5
 # The posterior's matmuls run at full f32 precision. At the TPU's default
 # (one bf16 pass) the variance — a difference of near-equal terms through
 # L⁻¹, whose entries grow with the Gram's condition number — came out
-# NEGATIVE on a v5e at 400×20-D (chip_smoke.py, PR 21): the clamp then
+# NEGATIVE on a v5e at 400×20-D (PR 21's chip run): the clamp then
 # zeroes the stddev and the UCB/PE terms with it. Whether a cheaper
 # precision suffices per matmul is ROADMAP S2's measurement to make.
 POSTERIOR_PRECISION = jax.lax.Precision.HIGHEST

@@ -47,14 +47,17 @@ class ServingConfig:
     cache_max_entries: int = 64
     cache_ttl_seconds: float = 3600.0
     # Restart budget for a warm-started ARD train (cold trains keep the
-    # designer's full ``ard_restarts``). The A/B evidence for 1 restart is
-    # WARM_START_AB.json (latency + regret parity).
+    # designer's full ``ard_restarts``). Regret parity of 1 restart is held
+    # by tests/serving/test_warm_start_parity.py; every benchmark cell serves
+    # warm trains (``cache_warm_share.*``, ``train_wait_ms.*``).
     warm_ard_restarts: int = 1
 
     # -- cross-study batching (vizier_tpu.parallel.batch_executor) ----------
     # Collect concurrent designer computations from different studies into
     # shape-bucket queues and run each bucket as ONE vmapped device program.
-    # The A/B evidence is BATCHING_AB.json (tools/batching_ab.py).
+    # Measured on the chip by the cell ``default20d.tenants16``
+    # (``suggestions_per_s``, ``batched_share``); parity with the sequential
+    # path: tests/parallel/test_batch_executor.py.
     batching: bool = True
     # Flush a bucket at this many studies ("full") ...
     batch_max_size: int = 8

@@ -16,8 +16,10 @@ Modules:
 - ``sparse_bandit`` — the jitted train/sweep/flush programs the GP-bandit
   designer and the cross-study batch executor consume.
 
-Evidence: SPARSE_AB.json (tools/surrogate_ab.py) — device-side suggest
-latency at the north-star scale plus rank-sum regret parity vs exact.
+Evidence: the cells ``default20d-sparse.lone25`` / ``.tenants16`` of
+``BENCHMARK.json`` (the served sparse tier on the chip, ``correct`` against
+a float64 SGPR reference); rank-sum regret parity vs exact:
+``tests/surrogates/test_regret_parity.py``.
 """
 
 from vizier_tpu.surrogates.config import SurrogateConfig  # noqa: F401

@@ -2,9 +2,9 @@
 
 One implementation of the reference ``performance_test.py:44-89`` load
 shape — a 2-D RANDOM_SEARCH study, N thread-pool clients each running
-their own suggest→complete loop — used by both the CI stress test
-(``tests/service/test_performance.py``) and the throughput measurement
-tool (``tools/service_throughput.py``) so the two cannot drift apart.
+their own suggest→complete loop — driven by the CI stress test
+(``tests/service/test_performance.py``). Its rate on the chip is the cell
+``perftest2d.shared50x5``'s to measure (``BENCHMARK.json``).
 """
 
 from __future__ import annotations
